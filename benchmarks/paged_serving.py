@@ -144,6 +144,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.salpim import SalPimConfig, SalPimEngine
 from repro.models import api
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving import (EngineConfig, FifoScheduler, GenConfig,
                            ServingEngine, SloScheduler, SpecConfig,
                            Telemetry)
@@ -1614,6 +1615,7 @@ def main():
         args.roofline_out = ("roofline_smoke.json" if args.smoke
                              else "roofline_part10.json")
     parts = tuple(int(p) for p in args.parts.split(",") if p)
+    enable_compile_cache()
     run(arch=args.arch, slots=args.slots, max_len=args.max_len,
         requests=args.requests, page_size=args.page_size, seed=args.seed,
         max_steps=args.max_steps, smoke=args.smoke, json_path=args.json,

@@ -12,6 +12,7 @@ import jax
 from repro.configs import get_config
 from repro.core.salpim import SalPimConfig, SalPimEngine
 from repro.models import api
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving.engine import GenConfig, generate
 
 
@@ -22,6 +23,7 @@ def main():
     ap.add_argument("--full", dest="smoke", action="store_false")
     ap.add_argument("--out-sizes", default="4,16,64")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     params = api.init_params(jax.random.PRNGKey(0), cfg)

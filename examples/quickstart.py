@@ -11,11 +11,13 @@ from repro.configs import get_config
 from repro.core.salpim import SalPimConfig, SalPimEngine
 from repro.data import tokens as data_lib
 from repro.runtime import optimizer as opt
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.train_loop import TrainConfig, run_training
 from repro.serving.engine import GenConfig, generate
 
 
 def main():
+    enable_compile_cache()
     cfg = get_config("gpt2-medium", smoke=True)
     engine = SalPimEngine.create(SalPimConfig(nonlinear_mode="lut"))
     print(f"model: {cfg.name}  params={cfg.param_count():,}  "

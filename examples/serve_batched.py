@@ -20,6 +20,7 @@ import jax
 from repro.configs import get_config
 from repro.core.salpim import SalPimConfig, SalPimEngine
 from repro.models import api
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving import (EngineConfig, FifoScheduler, GenConfig,
                            ServingEngine, SloScheduler, Telemetry)
 
@@ -110,6 +111,7 @@ def main():
     if args.priority:
         args.telemetry = True
 
+    enable_compile_cache()
     cfg = get_config("qwen2-1.5b", smoke=True)
     engine = SalPimEngine.create(SalPimConfig(nonlinear_mode="lut"))
     params = api.init_params(jax.random.PRNGKey(0), cfg)
@@ -187,7 +189,8 @@ def main():
     dt = time.perf_counter() - t0
     toks = sum(len(r.generated) for r in eng.finished)
     print(f"drained in {steps} decode steps, {dt:.2f}s "
-          f"({steps/dt:.1f} steps/s on CPU)")
+          f"({steps/dt:.1f} steps/s on {jax.devices()[0].device_kind}, "
+          "compiles included)")
     print(f"finished {len(eng.finished)}/{len(uids)} requests, "
           f"{toks} tokens generated")
     if args.paged:

@@ -22,11 +22,26 @@ import jax.numpy as jnp
 
 from repro.core.nonlinear import Nonlinear
 from repro.core import quant as quant_lib
+from repro.distributed import api as dist_api
 from repro.kernels import ops
-from repro.kernels import paged_attention as paged_k
-from repro.kernels import ref as ref_k
 
 Array = jax.Array
+
+
+def _replicated_on_mesh(fn, *args):
+    """`fn(*args)`, run whole on every device of the active mesh.
+
+    XLA cannot partition a Mosaic kernel itself, so under a mesh
+    (`ServingEngine(mesh=...)` shards only the page pools) a kernel
+    outside the paged-attention shard_map runs in a shard_map of its own
+    with every operand replicated: each device computes the full result
+    from its replicated weights, as the one-device engine would."""
+    mesh = dist_api.current_mesh()
+    if mesh is None:
+        return fn(*args)
+    from jax.sharding import PartitionSpec as P
+    return jax.shard_map(fn, mesh=mesh, in_specs=(P(),) * len(args),
+                         out_specs=P(), check_vma=False)(*args)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -39,7 +54,10 @@ class SalPimConfig:
     fixed_frac_w: int = 12          # Q-format fraction bits (weights)
     fixed_frac_x: int = 10          # Q-format fraction bits (activations)
     use_fused_attention: bool = True
-    impl: str = "reference"         # kernels impl: reference|pallas|interpret
+    # Kernel implementation (kernels/ops.py). None = derived from the
+    # backend by SalPimEngine.create ("pallas" on TPU, the jnp oracles
+    # elsewhere); "interpret"/"reference" are for tests.
+    impl: Optional[str] = None
     # KV-split (flash-decode) autotune knob for paged decode attention:
     # None/1 = single page walk; K > 1 = K online-softmax partials merged
     # by merge_partial_softmax_stacked, engaged only above
@@ -55,6 +73,11 @@ class SalPimEngine:
     @classmethod
     def create(cls, config: SalPimConfig | None = None) -> "SalPimEngine":
         config = config or SalPimConfig()
+        if config.impl is None:
+            config = dataclasses.replace(config, impl=ops.default_impl())
+        if config.impl not in ops.IMPLS:
+            raise ValueError(f"unknown kernel impl {config.impl!r}; "
+                             f"expected one of {ops.IMPLS}")
         nl = Nonlinear.create(config.nonlinear_mode, config.lut_sections)
         return cls(config=config, nl=nl)
 
@@ -115,7 +138,10 @@ class SalPimEngine:
             act_table = None
             if act is not None and self.nl.mode == "lut" and cfg.impl != "reference":
                 act_table = getattr(self.nl.bank, act, None)
-            out = ops.pim_linear(x2, w, b, act_table=act_table, impl=cfg.impl)
+            out = _replicated_on_mesh(
+                lambda x2, w, *b: ops.pim_linear(
+                    x2, w, *b, act_table=act_table, impl=cfg.impl),
+                x2, w, *([] if b is None else [b]))
             if act_table is not None:
                 return out.reshape(*lead, -1)
         out = out.reshape(*lead, -1)
@@ -124,19 +150,17 @@ class SalPimEngine:
         return out
 
     # -- C3: fused decode attention -------------------------------------------
+    @property
+    def _exp_table(self):
+        """The attention kernels' exp LUT (None = exact exp)."""
+        return self.nl.bank.exp if self.nl.mode == "lut" else None
+
     def decode_attention(self, q: Array, k: Array, v: Array, length: Array,
                          *, scale: Optional[float] = None,
                          softcap: Optional[float] = None,
                          window=None) -> Array:
-        exp_table = self.nl.bank.exp if self.nl.mode == "lut" else None
-        if self.config.impl == "reference":
-            # Direct oracle call: stays in the caller's trace, so `window`
-            # may be a traced per-layer scalar (scan over layers).
-            return ref_k.decode_attention_ref(
-                q, k, v, length, scale=scale, exp_table=exp_table,
-                softcap=softcap, window=window)
         return ops.pim_decode_attention(
-            q, k, v, length, scale=scale, exp_table=exp_table,
+            q, k, v, length, scale=scale, exp_table=self._exp_table,
             softcap=softcap, window=window, impl=self.config.impl)
 
     def paged_decode_attention(self, q: Array, k_pages: Array,
@@ -152,25 +176,9 @@ class SalPimEngine:
         scale rows; the kernel dequantizes (int4: unpacks) in VMEM.
         `config.kv_splits` > 1 engages the KV-split (flash-decode) path
         above KV_SPLIT_MIN_CONTEXT resident tokens."""
-        exp_table = self.nl.bank.exp if self.nl.mode == "lut" else None
-        splits = paged_k.effective_kv_splits(
-            self.config.kv_splits, block_tables.shape[1],
-            k_pages.shape[2])
-        if self.config.impl == "reference":
-            # Direct oracle calls: stay in the caller's trace, so
-            # `window` may be a traced per-layer scalar.
-            if splits is not None:
-                return ref_k.paged_attention_split_ref(
-                    q, k_pages, v_pages, block_tables, length,
-                    k_scales, v_scales, kv_splits=splits, scale=scale,
-                    exp_table=exp_table, softcap=softcap, window=window)
-            return ref_k.paged_attention_ref(
-                q, k_pages, v_pages, block_tables, length,
-                k_scales, v_scales, scale=scale,
-                exp_table=exp_table, softcap=softcap, window=window)
         return ops.pim_paged_attention(
             q, k_pages, v_pages, block_tables, length, k_scales, v_scales,
-            scale=scale, exp_table=exp_table, softcap=softcap,
+            scale=scale, exp_table=self._exp_table, softcap=softcap,
             window=window, kv_splits=self.config.kv_splits,
             impl=self.config.impl)
 
@@ -187,15 +195,9 @@ class SalPimEngine:
         prompt chunk per sequence at absolute positions start..start+Sq-1;
         the chunk's own K/V must already be resident in the pool (int8
         mode: quantized, with its scale rows written)."""
-        exp_table = self.nl.bank.exp if self.nl.mode == "lut" else None
-        if self.config.impl == "reference":
-            return ref_k.paged_prefill_attention_ref(
-                q, k_pages, v_pages, block_tables, length, start,
-                k_scales, v_scales, scale=scale, exp_table=exp_table,
-                softcap=softcap, window=window)
         return ops.pim_paged_prefill_attention(
             q, k_pages, v_pages, block_tables, length, start,
-            k_scales, v_scales, scale=scale, exp_table=exp_table,
+            k_scales, v_scales, scale=scale, exp_table=self._exp_table,
             softcap=softcap, window=window, impl=self.config.impl)
 
     # -- C2: norms -------------------------------------------------------------

@@ -79,8 +79,6 @@ def make_pipelined_fn(stage_fn: Callable, mesh: Mesh, axis_name: str,
     stage_params must be stacked (P, ...); x must be (B, ...) with
     B % n_micro == 0.
     """
-    from jax.experimental.shard_map import shard_map
-
     def fn(stage_params, x):
         B = x.shape[0]
         mb = B // n_micro
@@ -92,7 +90,7 @@ def make_pipelined_fn(stage_fn: Callable, mesh: Mesh, axis_name: str,
             sp = jax.tree.map(lambda a: a[0], sp)  # this stage's slice
             return pipeline_forward(stage_fn, sp, xm, axis_name=axis_name)
 
-        y = shard_map(
+        y = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: spec_p, stage_params), spec_x),
             out_specs=spec_x,

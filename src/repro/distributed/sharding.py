@@ -231,7 +231,7 @@ def paged_pool_pspecs(mesh: Mesh, *, quantized: bool = False,
                       rules: Optional[dict] = None) -> dict:
     """PartitionSpecs for the paged serving cache (`kvcache.PagedCache`).
 
-    Payload pools (L, P, Hkv, page, Dh) and scale pools (L, P, Hkv,
+    Payload pools (L, P, Hkv, page, Dh) and scale pools (L, P, Hkv, 1,
     page) shard their KV-head axis over the mesh axis behind the
     logical "model" name (tensor parallel within a replica), so each
     device holds 1/tp of every page — decode streams the pool from
@@ -252,7 +252,7 @@ def paged_pool_pspecs(mesh: Mesh, *, quantized: bool = False,
         "lengths": P(),
         "block_tables": P(),
     }
-    specs["scales"] = P(*pool[:4]) if quantized else None
+    specs["scales"] = pool if quantized else None
     return specs
 
 

@@ -30,31 +30,59 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.lut import LutTable
-from repro.kernels.lut_interp import TABLE_PAD
+from repro.kernels.lut_interp import SMEM_SPEC, lut_eval, lut_operand
 
 NEG_INF = -1e30
+# Window operand value meaning "attend globally": every kernel takes the
+# sliding window as a scalar-prefetched int32 (a per-layer value traced
+# through the model's layer scan), and `pos >= length - GLOBAL_WINDOW`
+# holds for every real position.
+GLOBAL_WINDOW = 1 << 30
 
 
-def _lut_eval(x, wb_ref, *, lo, inv_step, sections):
-    """In-kernel LUT interpolation via one-hot MXU matmul (see lut_interp)."""
-    idx = jnp.floor((x - lo) * inv_step).astype(jnp.int32) + 1
-    idx = jnp.clip(idx, 0, sections + 1)
-    rows, lanes = x.shape
-    onehot = (
-        idx.reshape(rows * lanes, 1)
-        == jax.lax.broadcasted_iota(jnp.int32, (rows * lanes, TABLE_PAD), 1)
-    ).astype(jnp.float32)
-    wb = jnp.dot(onehot, wb_ref[...].astype(jnp.float32),
-                 preferred_element_type=jnp.float32)
-    return wb[:, 0].reshape(rows, lanes) * x + wb[:, 1].reshape(rows, lanes)
+def window_operand(window) -> jax.Array:
+    """(1,) int32 scalar-prefetch operand for a static, traced or absent
+    (None = global) sliding window."""
+    if window is None:
+        window = GLOBAL_WINDOW
+    return jnp.asarray(window, jnp.int32).reshape(1)
+
+
+def online_softmax_update(scores, mask, v, m_ref, l_ref, acc_ref, *,
+                          use_lut, expwb_ref, lut, v_scale=None):
+    """One page/block of the online softmax shared by the attention
+    kernels: S-ALU max, exp (64-section LUT or exact), running (m, l,
+    acc) rescale — the C-ALU merge of per-bank partials. `scores` are
+    already NEG_INF where `mask` is False; `v` is the f32 (block, D)
+    value tile, and `v_scale` (1, block) its per-position dequant scales
+    (int8/int4 pools), folded into the probabilities before the second
+    contraction so the page itself is never rescaled."""
+    m_prev = m_ref[...]
+    m_cur = jnp.max(scores, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    if use_lut:
+        # exp of (x - m) <= 0: the LUT's calibrated negative domain.
+        p = lut_eval(scores - m_new, expwb_ref, **lut)
+        corr = lut_eval(jnp.maximum(m_prev - m_new, lut["lo"]), expwb_ref,
+                        **lut)
+    else:
+        p = jnp.exp(scores - m_new)
+        corr = jnp.exp(m_prev - m_new)
+    p = jnp.where(mask, p, 0.0)
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    # Direction 2: contract seq (S x V) over the same V tile.
+    pv = p if v_scale is None else p * v_scale
+    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+        pv, v, preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
 
 
 def _decode_attn_kernel(
     len_ref,  # scalar prefetch: (B*Hkv,) int32 valid lengths
+    win_ref,  # scalar prefetch: (1,) int32 sliding window
     q_ref, k_ref, v_ref, expwb_ref, o_ref,
     m_ref, l_ref, acc_ref,
-    *, n_s, block_s, scale, use_lut, lo, inv_step, sections,
-    softcap, window,
+    *, n_s, block_s, scale, use_lut, lut, softcap,
 ):
     s_idx = pl.program_id(1)
 
@@ -75,33 +103,11 @@ def _decode_attn_kernel(
         scores = softcap * jnp.tanh(scores / softcap)
 
     pos = s_idx * block_s + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    mask = pos < length
-    if window is not None:
-        mask = jnp.logical_and(mask, pos >= length - window)
+    mask = jnp.logical_and(pos < length, pos >= length - win_ref[0])
     scores = jnp.where(mask, scores, NEG_INF)
-
-    # Online softmax: S-ALU max op + exp LUT + running rescale.
-    m_prev = m_ref[...]                           # (g, 1)
-    m_cur = jnp.max(scores, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    if use_lut:
-        # exp of (x - m) <= 0: the LUT's calibrated negative domain.
-        p = _lut_eval(scores - m_new, expwb_ref, lo=lo, inv_step=inv_step,
-                      sections=sections)
-        corr = _lut_eval(jnp.maximum(m_prev - m_new, lo), expwb_ref,
-                         lo=lo, inv_step=inv_step, sections=sections)
-    else:
-        p = jnp.exp(scores - m_new)
-        corr = jnp.exp(m_prev - m_new)
-    p = jnp.where(mask, p, 0.0)
-
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    # Direction 2: contract seq (S x V) — same V tile, no transpose.
-    v = v_ref[0].astype(jnp.float32)              # (block_s, D)
-    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-        p, v, preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
+    online_softmax_update(scores, mask, v_ref[0].astype(jnp.float32),
+                          m_ref, l_ref, acc_ref, use_lut=use_lut,
+                          expwb_ref=expwb_ref, lut=lut)
 
     @pl.when(s_idx == n_s - 1)
     def _writeback():
@@ -118,7 +124,7 @@ def decode_attention(
     scale: float | None = None,
     exp_table: LutTable | None = None,
     softcap: float | None = None,
-    window: int | None = None,
+    window=None,             # int, traced int32 scalar, or None (global)
     block_s: int = 256,
     interpret: bool = False,
 ) -> jax.Array:
@@ -127,17 +133,10 @@ def decode_attention(
     g = H // Hkv
     scale = scale if scale is not None else 1.0 / (D**0.5)
     block_s = min(block_s, S)
-    assert S % block_s == 0
+    if S % block_s:
+        raise ValueError(f"cache length {S} does not tile by {block_s}")
     n_s = S // block_s
-
-    use_lut = exp_table is not None
-    if use_lut:
-        wb = exp_table.wb.astype(jnp.float32)
-        wb = jnp.pad(wb, ((0, TABLE_PAD - wb.shape[0]), (0, 0)))
-        lo, inv_step, sections = exp_table.lo, exp_table.inv_step, exp_table.sections
-    else:
-        wb = jnp.zeros((TABLE_PAD, 2), jnp.float32)
-        lo, inv_step, sections = -1.0, 1.0, 1
+    wb, lut = lut_operand(exp_table)
 
     qg = q.reshape(B * Hkv, g, D)
     kf = k.reshape(B * Hkv, S, D)
@@ -146,17 +145,16 @@ def decode_attention(
 
     kernel = functools.partial(
         _decode_attn_kernel, n_s=n_s, block_s=block_s, scale=scale,
-        use_lut=use_lut, lo=lo, inv_step=inv_step, sections=sections,
-        softcap=softcap, window=window,
+        use_lut=exp_table is not None, lut=lut, softcap=softcap,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B * Hkv, n_s),
         in_specs=[
             pl.BlockSpec((1, g, D), lambda bh, s, *_: (bh, 0, 0)),
             pl.BlockSpec((1, block_s, D), lambda bh, s, *_: (bh, s, 0)),
             pl.BlockSpec((1, block_s, D), lambda bh, s, *_: (bh, s, 0)),
-            pl.BlockSpec((TABLE_PAD, 2), lambda bh, s, *_: (0, 0)),
+            SMEM_SPEC,
         ],
         out_specs=pl.BlockSpec((1, g, D), lambda bh, s, *_: (bh, 0, 0)),
         scratch_shapes=[
@@ -171,5 +169,5 @@ def decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * Hkv, g, D), q.dtype),
         interpret=interpret,
-    )(lens, qg, kf, vf, wb)
+    )(lens, window_operand(window), qg, kf, vf, wb)
     return out.reshape(B, H, D)

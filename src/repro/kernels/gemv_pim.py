@@ -12,10 +12,14 @@ The S-ALU datapath, re-tiled for the TPU memory hierarchy:
     (jax.lax.psum over the `model` axis) — same split as the paper.
 
 Three datapaths, matching DESIGN.md:
-  * float (bf16/f32 weights, fp32 accum),
+  * float (bf16/f32 weights, fp32 accum) — the serving path's kernel,
+    compiled for TPU v5e;
   * int8 x int8 -> int32 MXU-native (per-row weight scales),
   * int16 Q-format -> int32 with shift/saturate writeback (faithful S-ALU;
-    validated in interpret mode — TPU MXU has no int16 mode).
+    TPU MXU has no int16 mode).
+The int8 and int16 paths are validated in interpret mode only: Mosaic
+refuses their int32 dots on v5e (docs/kernels.md), and int8 serving
+weights go through `serving/quantize.QTensor` instead.
 
 An optional fused LUT epilogue applies the activation before writeback —
 the paper's 'nonlinearity rides the same datapath' fusion.
@@ -30,24 +34,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.lut import LutTable
-from repro.kernels.lut_interp import TABLE_PAD
+from repro.kernels.lut_interp import SMEM_SPEC, lut_eval, lut_operand
 
 
-def _epilogue_lut(acc, wb_ref, *, lo, inv_step, sections):
-    idx = jnp.floor((acc - lo) * inv_step).astype(jnp.int32) + 1
-    idx = jnp.clip(idx, 0, sections + 1)
-    rows, lanes = acc.shape
-    onehot = (
-        idx.reshape(rows * lanes, 1)
-        == jax.lax.broadcasted_iota(jnp.int32, (rows * lanes, TABLE_PAD), 1)
-    ).astype(jnp.float32)
-    wb = jnp.dot(onehot, wb_ref[...].astype(jnp.float32),
-                 preferred_element_type=jnp.float32)
-    return wb[:, 0].reshape(rows, lanes) * acc + wb[:, 1].reshape(rows, lanes)
+def _divisor_block(n: int, pref: int) -> int:
+    """Contraction block: `pref` if it divides n, else the largest
+    multiple of 128 below it that does, else n whole (a full-extent block
+    is always a legal BlockSpec). The contraction axis must tile
+    exactly — a ragged block would add garbage into every output."""
+    for blk in range(pref, 0, -128):
+        if blk % 128 == 0 and n % blk == 0:
+            return blk
+    return n
 
 
 def _gemv_float_kernel(x_ref, w_ref, b_ref, wb_ref, o_ref, acc_ref, *,
-                       n_c, lo, inv_step, sections, fuse_act, has_bias):
+                       n_c, fuse_act, has_bias, lut):
     c = pl.program_id(1)
 
     @pl.when(c == 0)
@@ -64,8 +66,7 @@ def _gemv_float_kernel(x_ref, w_ref, b_ref, wb_ref, o_ref, acc_ref, *,
         if has_bias:
             acc = acc + b_ref[...].astype(jnp.float32)
         if fuse_act:
-            acc = _epilogue_lut(acc, wb_ref, lo=lo, inv_step=inv_step,
-                                sections=sections)
+            acc = lut_eval(acc, wb_ref, **lut)
         o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -77,7 +78,7 @@ def gemv_pim_float(
     act_table: LutTable | None = None,
     block_r: int = 256,
     block_c: int = 512,
-    block_b: int | None = None,
+    block_b: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
     """x (B, C) @ w (R, C)^T -> (B, R), optional bias + fused LUT activation.
@@ -85,31 +86,26 @@ def gemv_pim_float(
     Block sizes follow the shared-MAC balance: block_c spans the streamed
     contraction (the subarray row burst), block_r the parallel output rows
     (the S-ALU lanes). fp32 accumulation across the contraction grid.
+    Output rows and batch rows need not tile: the grid rounds up and the
+    edge block's out-of-range rows are computed from padding and never
+    written back (the LM head's vocab 50257 has no 128-multiple divisor).
     """
     B, C = x.shape
     R = w.shape[0]
     block_r = min(block_r, R)
-    block_c = min(block_c, C)
-    block_b = B if block_b is None else min(block_b, B)
-    assert R % block_r == 0 and C % block_c == 0 and B % block_b == 0
-    n_r, n_c, n_b = R // block_r, C // block_c, B // block_b
+    block_b = min(block_b, B)
+    block_c = _divisor_block(C, block_c)
+    n_r, n_c, n_b = pl.cdiv(R, block_r), C // block_c, pl.cdiv(B, block_b)
 
     fuse_act = act_table is not None
-    if fuse_act:
-        wb = act_table.wb.astype(jnp.float32)
-        wb = jnp.pad(wb, ((0, TABLE_PAD - wb.shape[0]), (0, 0)))
-        lo, inv_step, sections = act_table.lo, act_table.inv_step, act_table.sections
-    else:
-        wb = jnp.zeros((TABLE_PAD, 2), jnp.float32)
-        lo, inv_step, sections = 0.0, 1.0, 1
+    wb, lut = lut_operand(act_table)
     has_bias = b is not None
     b_arr = b if has_bias else jnp.zeros((R,), jnp.float32)
-    b2 = jnp.broadcast_to(b_arr.reshape(1, R), (1, R))
+    b2 = b_arr.reshape(1, R)
 
     kernel = functools.partial(
-        _gemv_float_kernel, n_c=n_c, lo=lo, inv_step=inv_step,
-        sections=sections, fuse_act=fuse_act, has_bias=has_bias,
-    )
+        _gemv_float_kernel, n_c=n_c, fuse_act=fuse_act, has_bias=has_bias,
+        lut=lut)
     return pl.pallas_call(
         kernel,
         grid=(n_b * n_r, n_c),
@@ -117,7 +113,7 @@ def gemv_pim_float(
             pl.BlockSpec((block_b, block_c), lambda i, c, n_r=n_r: (i // n_r, c)),
             pl.BlockSpec((block_r, block_c), lambda i, c, n_r=n_r: (i % n_r, c)),
             pl.BlockSpec((1, block_r), lambda i, c, n_r=n_r: (0, i % n_r)),
-            pl.BlockSpec((TABLE_PAD, 2), lambda i, c: (0, 0)),
+            SMEM_SPEC,
         ],
         out_specs=pl.BlockSpec((block_b, block_r),
                                lambda i, c, n_r=n_r: (i // n_r, i % n_r)),

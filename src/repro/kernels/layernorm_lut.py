@@ -15,20 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.lut import LutTable
-from repro.kernels.lut_interp import TABLE_PAD
-
-
-def _lut_eval(x, wb_ref, *, lo, inv_step, sections):
-    idx = jnp.floor((x - lo) * inv_step).astype(jnp.int32) + 1
-    idx = jnp.clip(idx, 0, sections + 1)
-    rows, lanes = x.shape
-    onehot = (
-        idx.reshape(rows * lanes, 1)
-        == jax.lax.broadcasted_iota(jnp.int32, (rows * lanes, TABLE_PAD), 1)
-    ).astype(jnp.float32)
-    wb = jnp.dot(onehot, wb_ref[...].astype(jnp.float32),
-                 preferred_element_type=jnp.float32)
-    return wb[:, 0].reshape(rows, lanes) * x + wb[:, 1].reshape(rows, lanes)
+from repro.kernels.lut_interp import SMEM_SPEC, lut_eval, lut_operand
 
 
 def _rsqrt_range_reduced(x, wb_ref, *, lo, inv_step, sections):
@@ -41,7 +28,7 @@ def _rsqrt_range_reduced(x, wb_ref, *, lo, inv_step, sections):
     odd = (e & 1) == 1
     m2 = jnp.where(odd, m * 0.5, m)
     e2 = jnp.where(odd, e + 1, e)
-    r = _lut_eval(m2, wb_ref, lo=lo, inv_step=inv_step, sections=sections)
+    r = lut_eval(m2, wb_ref, lo=lo, inv_step=inv_step, sections=sections)
     return r * jnp.exp2(-(e2 // 2).astype(jnp.float32))
 
 
@@ -86,21 +73,13 @@ def layernorm_lut(
     block_rows = min(block_rows, n)
     assert n % block_rows == 0
     use_lut = rsqrt_table is not None
-    if use_lut:
-        wb = rsqrt_table.wb.astype(jnp.float32)
-        wb = jnp.pad(wb, ((0, TABLE_PAD - wb.shape[0]), (0, 0)))
-        lo, inv_step, sections = (rsqrt_table.lo, rsqrt_table.inv_step,
-                                  rsqrt_table.sections)
-    else:
-        wb = jnp.zeros((TABLE_PAD, 2), jnp.float32)
-        lo, inv_step, sections = 0.25, 1.0, 1
+    wb, lut = lut_operand(rsqrt_table)
     has_beta = beta is not None
     b = beta if has_beta else jnp.zeros((d,), jnp.float32)
 
     kernel = functools.partial(
-        _ln_kernel, eps=eps, use_lut=use_lut, lo=lo, inv_step=inv_step,
-        sections=sections, rms=rms, has_beta=has_beta, plus_one=plus_one,
-    )
+        _ln_kernel, eps=eps, use_lut=use_lut, rms=rms, has_beta=has_beta,
+        plus_one=plus_one, **lut)
     return pl.pallas_call(
         kernel,
         grid=(n // block_rows,),
@@ -108,7 +87,7 @@ def layernorm_lut(
             pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
             pl.BlockSpec((1, d), lambda i: (0, 0)),
             pl.BlockSpec((1, d), lambda i: (0, 0)),
-            pl.BlockSpec((TABLE_PAD, 2), lambda i: (0, 0)),
+            SMEM_SPEC,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),

@@ -1,14 +1,17 @@
 """Pallas TPU kernel: LUT-based linear interpolation (SAL-PIM C2).
 
-TPU adaptation of the LUT-embedded subarray: the per-MAT column-select
-that fetches 16 (slope, intercept) pairs per cycle becomes a one-hot
-matmul on the MXU — `onehot(sec(x)) @ wb` — which fetches a pair for
-*every lane of the block* in one systolic pass. The table (<=128 rows x 2)
-lives in VMEM for the whole kernel, mirroring the activated LUT rows held
-in the bit-line sense amps of the LUT-embedded subarray.
+TPU adaptation of the LUT-embedded subarray: the (slope, intercept)
+table lives in SMEM for the whole kernel, mirroring the activated LUT
+rows held in the bit-line sense amps, and the per-MAT column-select
+becomes a chain of vector selects — one compare-and-select per table
+row, broadcasting that row's scalar pair to every lane whose section
+index matches. The chain is `sections + 2` VPU ops deep and works on
+any (rows, lanes) tile, so every kernel that interpolates (the fused
+attention exp, the GEMV activation epilogue, LUT softmax/layernorm)
+shares `lut_eval` below.
 
-Layout: x is processed in (block_rows, 128) VMEM tiles; the table block
-is broadcast to every grid step (index_map -> (0, 0)).
+Layout: x is processed in (block_rows, 128) VMEM tiles; the flattened
+table rides along as one SMEM operand (`lut_operand`).
 """
 from __future__ import annotations
 
@@ -17,29 +20,49 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.lut import LutTable
 
 LANE = 128
-TABLE_PAD = 128  # wb padded to the MXU-aligned 128 rows
+
+# Whole-array SMEM operand: the flattened (slope, intercept) table.
+SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _lut_interp_kernel(x_ref, wb_ref, o_ref, *, lo, inv_step, sections):
-    x = x_ref[...].astype(jnp.float32)
-    # Decoding unit: clamp((x - lo) * S / (hi - lo)) + 1 guard offset.
+def lut_operand(table: LutTable | None) -> tuple[jax.Array, dict]:
+    """(flat table, static interpolation params) for `lut_eval`.
+
+    The table is flattened row-major to (2 * (sections + 2),) f32 — slope
+    then intercept per row — for its SMEM operand. With no table a
+    one-row placeholder keeps the kernels' operand lists fixed."""
+    if table is None:
+        return (jnp.zeros((2,), jnp.float32),
+                {"lo": 0.0, "inv_step": 1.0, "sections": -1})
+    return (table.wb.astype(jnp.float32).reshape(-1),
+            {"lo": table.lo, "inv_step": table.inv_step,
+             "sections": table.sections})
+
+
+def lut_eval(x, wb_ref, *, lo, inv_step, sections):
+    """In-kernel y = W[sec(x)] * x + B[sec(x)] over an f32 tile of any
+    shape, reading the table from the SMEM ref `wb_ref` (`lut_operand`).
+    Section decode matches `core.lut.section_index`; the selects fetch
+    the exact f32 table entries, so results equal `apply_table`."""
     idx = jnp.floor((x - lo) * inv_step).astype(jnp.int32) + 1
     idx = jnp.clip(idx, 0, sections + 1)
-    # LUT fetch as a one-hot MXU matmul: (rows*LANE, TABLE_PAD) @ (TABLE_PAD, 2).
-    rows, lanes = x.shape
-    onehot = (
-        idx.reshape(rows * lanes, 1)
-        == jax.lax.broadcasted_iota(jnp.int32, (rows * lanes, TABLE_PAD), 1)
-    ).astype(jnp.float32)
-    wb = jnp.dot(onehot, wb_ref[...].astype(jnp.float32),
-                 preferred_element_type=jnp.float32)
-    w = wb[:, 0].reshape(rows, lanes)
-    b = wb[:, 1].reshape(rows, lanes)
-    o_ref[...] = (w * x + b).astype(o_ref.dtype)
+    w = jnp.zeros_like(x)
+    b = jnp.zeros_like(x)
+    for s in range(sections + 2):
+        hit = idx == s
+        w = jnp.where(hit, wb_ref[2 * s], w)
+        b = jnp.where(hit, wb_ref[2 * s + 1], b)
+    return w * x + b
+
+
+def _lut_interp_kernel(x_ref, wb_ref, o_ref, **lut):
+    x = x_ref[...].astype(jnp.float32)
+    o_ref[...] = lut_eval(x, wb_ref, **lut).astype(o_ref.dtype)
 
 
 def lut_interp_2d(x: jax.Array, table: LutTable, *, block_rows: int = 256,
@@ -50,23 +73,18 @@ def lut_interp_2d(x: jax.Array, table: LutTable, *, block_rows: int = 256,
     padding/reshaping into this layout.
     """
     m, n = x.shape
-    assert n % LANE == 0, n
+    if n % LANE:
+        raise ValueError(f"lut_interp_2d needs 128-lane rows, got {n}")
     block_rows = min(block_rows, m)
-    assert m % block_rows == 0, (m, block_rows)
-    wb = table.wb.astype(jnp.float32)
-    wb = jnp.pad(wb, ((0, TABLE_PAD - wb.shape[0]), (0, 0)))
-    kernel = functools.partial(
-        _lut_interp_kernel,
-        lo=table.lo,
-        inv_step=table.inv_step,
-        sections=table.sections,
-    )
+    if m % block_rows:
+        raise ValueError(f"{m} rows do not tile by {block_rows}")
+    wb, lut = lut_operand(table)
     return pl.pallas_call(
-        kernel,
+        functools.partial(_lut_interp_kernel, **lut),
         grid=(m // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
-            pl.BlockSpec((TABLE_PAD, 2), lambda i: (0, 0)),
+            SMEM_SPEC,
         ],
         out_specs=pl.BlockSpec((block_rows, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),

@@ -1,13 +1,15 @@
 """Public jit'd wrappers for the Pallas kernels, with impl dispatch.
 
-impl:
-  "reference" — pure-jnp oracle (used on CPU / in the dry-run: pallas_call
-                does not lower on the CPU backend),
-  "pallas"    — compiled TPU kernel,
+impl (required — `SalPimEngine.create` resolves it once from the backend
+via `default_impl()`, so the serving path never picks it by hand):
+  "pallas"    — compiled TPU kernel (the only choice on a TPU backend),
+  "reference" — pure-jnp oracle (`kernels/ref.py`): the CPU backend,
+                where pallas_call does not lower, and tests,
   "interpret" — Pallas interpret mode (CPU correctness checks in tests).
 
-`default_impl()` picks "pallas" on TPU backends and "reference" elsewhere,
-so models call these ops unconditionally.
+A shape a kernel cannot take raises; no op falls back to the oracle.
+The sliding window is a traced operand (the layer scan carries one per
+layer), never a static argument.
 """
 from __future__ import annotations
 
@@ -29,7 +31,11 @@ from repro.kernels import softmax_lut as sm_k
 LANE = 128
 
 
+IMPLS = ("pallas", "reference", "interpret")
+
+
 def default_impl() -> str:
+    """The kernel implementation for the running backend."""
     return "pallas" if jax.default_backend() == "tpu" else "reference"
 
 
@@ -46,7 +52,7 @@ def _pad_to_2d(x: jax.Array) -> tuple[jax.Array, tuple, int]:
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "block_rows"))
-def lut_apply(x: jax.Array, table: LutTable, *, impl: str = "reference",
+def lut_apply(x: jax.Array, table: LutTable, *, impl: str,
               block_rows: int = 256) -> jax.Array:
     """Apply a LUT table elementwise to any-shape x."""
     if impl == "reference":
@@ -63,7 +69,7 @@ def lut_apply(x: jax.Array, table: LutTable, *, impl: str = "reference",
 
 @functools.partial(jax.jit, static_argnames=("impl", "block_r", "block_c"))
 def pim_linear(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,
-               act_table: LutTable | None = None, impl: str = "reference",
+               act_table: LutTable | None = None, impl: str,
                block_r: int = 256, block_c: int = 512) -> jax.Array:
     """(B, C) @ (R, C)^T with optional bias + fused LUT activation."""
     if impl == "reference":
@@ -75,7 +81,7 @@ def pim_linear(x: jax.Array, w: jax.Array, b: jax.Array | None = None, *,
 
 @functools.partial(jax.jit, static_argnames=("impl", "block_r", "block_c"))
 def pim_linear_int8(x_i8: jax.Array, x_scale: jax.Array, w_i8: jax.Array,
-                    w_scale: jax.Array, *, impl: str = "reference",
+                    w_scale: jax.Array, *, impl: str,
                     block_r: int = 256, block_c: int = 512) -> jax.Array:
     if impl == "reference":
         return ref_k.gemv_pim_int8_ref(x_i8, x_scale, w_i8, w_scale)
@@ -86,7 +92,7 @@ def pim_linear_int8(x_i8: jax.Array, x_scale: jax.Array, w_i8: jax.Array,
 
 @functools.partial(jax.jit, static_argnames=("impl", "shift", "block_r", "block_c"))
 def pim_linear_fixed(x_q: jax.Array, w_q: jax.Array, *, shift: int,
-                     impl: str = "reference", block_r: int = 256,
+                     impl: str, block_r: int = 256,
                      block_c: int = 512) -> jax.Array:
     if impl == "reference":
         return ref_k.gemv_pim_fixed_ref(x_q, w_q, shift=shift)
@@ -96,11 +102,11 @@ def pim_linear_fixed(x_q: jax.Array, w_q: jax.Array, *, shift: int,
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "scale", "softcap",
-                                             "window", "block_s"))
+                                             "block_s"))
 def pim_decode_attention(q, k, v, length, *, scale=None,
                          exp_table: LutTable | None = None,
                          softcap=None, window=None,
-                         impl: str = "reference",
+                         impl: str,
                          block_s: int = 256) -> jax.Array:
     if impl == "reference":
         return ref_k.decode_attention_ref(
@@ -112,14 +118,14 @@ def pim_decode_attention(q, k, v, length, *, scale=None,
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "scale", "softcap",
-                                             "window", "kv_splits"))
+                                             "kv_splits"))
 def pim_paged_attention(q, k_pages, v_pages, block_tables, length,
                         k_scales=None, v_scales=None, *,
                         scale=None, exp_table: LutTable | None = None,
                         softcap=None, window=None, kv_splits=None,
-                        impl: str = "reference") -> jax.Array:
+                        impl: str) -> jax.Array:
     """Decode attention over a paged KV pool (see serving/kvcache.py).
-    int8/int4 pools pass their (P, Hkv, page) scale rows as
+    int8/int4 pools pass their (P, Hkv, 1, page) scale rows as
     k_scales/v_scales; the kernel dequantizes (int4: unpacks) in VMEM,
     the oracle after the gather. `kv_splits` > 1 engages the KV-split
     (flash-decode) path above KV_SPLIT_MIN_CONTEXT resident tokens:
@@ -144,14 +150,13 @@ def pim_paged_attention(q, k_pages, v_pages, block_tables, length,
         kv_splits=kv_splits, interpret=(impl == "interpret"))
 
 
-@functools.partial(jax.jit, static_argnames=("impl", "scale", "softcap",
-                                             "window"))
+@functools.partial(jax.jit, static_argnames=("impl", "scale", "softcap"))
 def pim_paged_prefill_attention(q, k_pages, v_pages, block_tables, length,
                                 start, k_scales=None, v_scales=None, *,
                                 scale=None,
                                 exp_table: LutTable | None = None,
                                 softcap=None, window=None,
-                                impl: str = "reference") -> jax.Array:
+                                impl: str) -> jax.Array:
     """Chunked prefill attention over a paged KV pool: q (B, Sq, H, D) at
     absolute positions start..start+Sq-1 (see serving/kvcache.py).
     int8 pools pass scale rows as k_scales/v_scales. The speculative
@@ -173,7 +178,7 @@ def pim_paged_prefill_attention(q, k_pages, v_pages, block_tables, length,
                                              "block_rows"))
 def pim_layernorm(x, gamma, beta=None, *, eps: float = 1e-5,
                   rsqrt_table: LutTable | None = None, rms: bool = False,
-                  plus_one: bool = False, impl: str = "reference",
+                  plus_one: bool = False, impl: str,
                   block_rows: int = 256) -> jax.Array:
     """LayerNorm/RMSNorm over the last dim of any-rank x."""
     if impl == "reference":
@@ -194,7 +199,7 @@ def pim_layernorm(x, gamma, beta=None, *, eps: float = 1e-5,
 
 @functools.partial(jax.jit, static_argnames=("impl", "block_rows"))
 def pim_softmax(x: jax.Array, exp_table: LutTable, recip_table: LutTable,
-                *, impl: str = "reference", block_rows: int = 128) -> jax.Array:
+                *, impl: str, block_rows: int = 128) -> jax.Array:
     """Row softmax over the last dim via the paper's LUT flow."""
     if impl == "reference":
         from repro.core import lut as lut_lib

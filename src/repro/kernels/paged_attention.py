@@ -6,26 +6,31 @@ dense per-sequence arena. Each sequence's pages are found through a
 scalar-prefetched block table — SAL-PIM's bank-sequential K/V placement
 (`serving/kvcache.py`) read back bank-by-bank:
 
-  * the block table is `num_scalar_prefetch` input #2, so the BlockSpec
-    index map can compute the physical page DMA address *before* the
-    kernel body runs (the C3 hierarchy mapping: logical page -> bank);
+  * the block table is the last `num_scalar_prefetch` input (after the
+    lengths and the sliding-window scalar), so the BlockSpec index map
+    can compute the physical page DMA address *before* the kernel body
+    runs (the C3 hierarchy mapping: logical page -> bank);
   * inside the body the math is identical to the dense kernel — the
     online-softmax (m, l, acc) merge across pages is the C-ALU merge of
     per-bank partials, and exp optionally routes through the same
-    64-section LUT (`_lut_eval`).
+    64-section LUT (`decode_attention.online_softmax_update`).
 
 int8 pools (`k_scales`/`v_scales` given): the page DMA moves int8
 payload plus one f32 scale row per (page, head) — (Dh + 4) bytes per
-vector instead of 2*Dh for bf16 — and the kernel dequantizes *in VMEM*
-(payload * scale row) before the existing fp32 online-softmax math, so
-the ~2x HBM traffic cut is real while the merge machinery is untouched.
+vector instead of 2*Dh for bf16. Scale pools are laid out
+(P, Hkv, 1, page_size), so each (page, head) scale row is its own
+(1, page_size) block — a block shape Mosaic accepts, and exactly the
+bytes `kv_vector_bytes` counts. The kernel dequantizes *in VMEM* by
+folding the scale row into the contraction it feeds: K scales multiply
+the (g, page) score tile column-wise, V scales the probability tile, so
+neither page is rescaled elementwise and the ~2x HBM traffic cut is real.
 
 int4 pools pack two values per byte, so the page payload block is
 (page_size, Dh/2) and the DMA moves (Dh/2 + 2) bytes per vector (bf16
 scale rows). Packing is detected structurally (payload axis is half the
 query head_dim) and the kernel unpacks in VMEM with two arithmetic
-shifts plus a halves concat (`serving/quantize.unpack_int4`'s
-convention) before the same dequant multiply.
+shifts on the int32-widened bytes plus a halves concat
+(`serving/quantize.unpack_int4`'s convention).
 
 Grid: (B, Hkv, n_pages); q block (group, D) where group = H // Hkv (GQA
 groups share one K/V page stream). Unmapped table entries point at the
@@ -62,8 +67,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.lut import LutTable
-from repro.kernels.decode_attention import NEG_INF, _lut_eval
-from repro.kernels.lut_interp import TABLE_PAD
+from repro.kernels.decode_attention import (NEG_INF, online_softmax_update,
+                                           window_operand)
+from repro.kernels.lut_interp import SMEM_SPEC, lut_operand
 
 # Below this many resident tokens the split path's partials traffic
 # outweighs the parallelism win; effective_kv_splits disables it.
@@ -111,34 +117,70 @@ def kv_vector_bytes(head_dim: int, kv_dtype: str = "model",
     return head_dim * jnp.dtype(payload_dtype).itemsize
 
 
-def _dequant_page(x_ref, sc_ref, packed):
-    """One page payload block -> f32 (page_size, D): int4 nibble unpack
-    (arithmetic shifts sign-extend; halves concat, no stride-2 shuffle)
-    then the scale-row dequant multiply, all in VMEM after the DMA."""
+def _page_tile(x_ref, packed):
+    """One page payload block -> f32 (page_size, D). int4 pools unpack
+    in VMEM: the bytes widen to int32 first (Mosaic has no 8-bit
+    shifts), then arithmetic shifts sign-extend the low and high nibbles
+    and a halves concat restores head_dim order."""
     x = x_ref[0, 0]
     if packed:
+        x = x.astype(jnp.int32)
         x = jnp.concatenate(
-            [jnp.right_shift(jnp.left_shift(x, 4), 4),
+            [jnp.right_shift(jnp.left_shift(x, 28), 28),
              jnp.right_shift(x, 4)], axis=-1)
-    x = x.astype(jnp.float32)
-    if sc_ref is not None:
-        x = x * sc_ref[0, 0].astype(jnp.float32)[:, None]
-    return x
+    return x.astype(jnp.float32)
+
+
+def _scale_row(sc_ref):
+    """The (1, page_size) f32 dequant scales of this (page, head), or
+    None for fp pools; DMA'd in storage dtype, widened in VMEM."""
+    return None if sc_ref is None else sc_ref[0, 0].astype(jnp.float32)
+
+
+def attend_page(q, k_ref, v_ref, ksc_ref, vsc_ref, expwb_ref,
+                m_ref, l_ref, acc_ref, *, mask_fn, scale, softcap,
+                packed, use_lut, lut):
+    """Fold one K/V page into the running (m, l, acc) of `q` (rows, D):
+    the body shared by the paged decode (single-walk and split) and
+    paged prefill kernels, which differ only in `mask_fn(shape)` — the
+    validity mask of the (rows, page_size) score tile."""
+    # Direction 1: contract head_dim (Q x K^T) — same layout, no transpose.
+    scores = jnp.dot(q, _page_tile(k_ref, packed).T,
+                     preferred_element_type=jnp.float32) * scale
+    k_scale = _scale_row(ksc_ref)
+    if k_scale is not None:
+        scores = scores * k_scale
+    if softcap is not None:
+        scores = softcap * jnp.tanh(scores / softcap)
+    mask = mask_fn(scores.shape)
+    scores = jnp.where(mask, scores, NEG_INF)
+    online_softmax_update(scores, mask, _page_tile(v_ref, packed),
+                          m_ref, l_ref, acc_ref, use_lut=use_lut,
+                          expwb_ref=expwb_ref, lut=lut,
+                          v_scale=_scale_row(vsc_ref))
+
+
+def split_refs(refs, quantized: bool, n_out: int):
+    """(q, k, v, ksc, vsc, expwb, outs, scratch) from a kernel's refs;
+    ksc/vsc are None for fp pools."""
+    if quantized:
+        q_ref, k_ref, v_ref, ksc_ref, vsc_ref, expwb_ref, *rest = refs
+    else:
+        q_ref, k_ref, v_ref, expwb_ref, *rest = refs
+        ksc_ref = vsc_ref = None
+    return (q_ref, k_ref, v_ref, ksc_ref, vsc_ref, expwb_ref,
+            rest[:n_out], rest[n_out:])
 
 
 def _paged_attn_kernel(
     len_ref,   # scalar prefetch: (B,) int32 valid lengths
+    win_ref,   # scalar prefetch: (1,) int32 sliding window
     tbl_ref,   # scalar prefetch: (B, n_pages) int32 physical page ids
     *refs,     # q, k, v, [ksc, vsc,] expwb, o, then m/l/acc scratch
-    n_pages, page_size, scale, use_lut, lo, inv_step, sections,
-    softcap, window, quantized, packed,
+    n_pages, page_size, quantized, **page_kw,
 ):
-    if quantized:
-        (q_ref, k_ref, v_ref, ksc_ref, vsc_ref, expwb_ref, o_ref,
-         m_ref, l_ref, acc_ref) = refs
-    else:
-        q_ref, k_ref, v_ref, expwb_ref, o_ref, m_ref, l_ref, acc_ref = refs
-        ksc_ref = vsc_ref = None
+    (q_ref, k_ref, v_ref, ksc_ref, vsc_ref, expwb_ref, (o_ref,),
+     (m_ref, l_ref, acc_ref)) = split_refs(refs, quantized, 1)
     b = pl.program_id(0)
     s_idx = pl.program_id(2)
 
@@ -150,44 +192,13 @@ def _paged_attn_kernel(
 
     length = len_ref[b]
 
-    q = q_ref[0, 0].astype(jnp.float32)          # (g, D)
-    # In-kernel dequant: the page arrived narrow (int8, or nibble-packed
-    # int4); the scale row is DMA'd in its storage dtype (f32 or bf16)
-    # and widened in VMEM.
-    k = _dequant_page(k_ref, ksc_ref, packed)    # (page_size, D)
-    # Direction 1: contract head_dim (Q x K^T) — same layout, no transpose.
-    scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    if softcap is not None:
-        scores = softcap * jnp.tanh(scores / softcap)
+    def mask_fn(shape):
+        pos = s_idx * page_size + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        return jnp.logical_and(pos < length, pos >= length - win_ref[0])
 
-    pos = (s_idx * page_size
-           + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1))
-    mask = pos < length
-    if window is not None:
-        mask = jnp.logical_and(mask, pos >= length - window)
-    scores = jnp.where(mask, scores, NEG_INF)
-
-    # Online softmax across pages: the C-ALU merge of per-bank partials.
-    m_prev = m_ref[...]                           # (g, 1)
-    m_cur = jnp.max(scores, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    if use_lut:
-        p = _lut_eval(scores - m_new, expwb_ref, lo=lo, inv_step=inv_step,
-                      sections=sections)
-        corr = _lut_eval(jnp.maximum(m_prev - m_new, lo), expwb_ref,
-                         lo=lo, inv_step=inv_step, sections=sections)
-    else:
-        p = jnp.exp(scores - m_new)
-        corr = jnp.exp(m_prev - m_new)
-    p = jnp.where(mask, p, 0.0)
-
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    # Direction 2: contract seq (S x V) over the same V page.
-    v = _dequant_page(v_ref, vsc_ref, packed)     # (page_size, D)
-    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-        p, v, preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
+    attend_page(q_ref[0, 0].astype(jnp.float32), k_ref, v_ref, ksc_ref,
+                vsc_ref, expwb_ref, m_ref, l_ref, acc_ref, mask_fn=mask_fn,
+                **page_kw)
 
     @pl.when(s_idx == n_pages - 1)
     def _writeback():
@@ -197,11 +208,11 @@ def _paged_attn_kernel(
 
 def _paged_attn_split_kernel(
     len_ref,   # scalar prefetch: (B,) int32 valid lengths
+    win_ref,   # scalar prefetch: (1,) int32 sliding window
     tbl_ref,   # scalar prefetch: (B, kv_splits * pps) int32, trash-padded
     *refs,     # q, k, v, [ksc, vsc,] expwb, m_out, l_out, acc_out,
                # then m/l/acc scratch
-    pps, page_size, scale, use_lut, lo, inv_step, sections,
-    softcap, window, quantized, packed,
+    pps, page_size, quantized, **page_kw,
 ):
     """KV-split body: identical page math as `_paged_attn_kernel`, but
     the page walk covers only this split's `pps` pages and the writeback
@@ -209,13 +220,9 @@ def _paged_attn_split_kernel(
     `merge_partial_softmax_stacked` combine. A split whose pages are all
     past `length` (trash-padded tail) emits the empty partial
     (m=NEG_INF, l=0, acc=0), which the merge's finite guard absorbs."""
-    if quantized:
-        (q_ref, k_ref, v_ref, ksc_ref, vsc_ref, expwb_ref,
-         mo_ref, lo_ref, ao_ref, m_ref, l_ref, acc_ref) = refs
-    else:
-        (q_ref, k_ref, v_ref, expwb_ref,
-         mo_ref, lo_ref, ao_ref, m_ref, l_ref, acc_ref) = refs
-        ksc_ref = vsc_ref = None
+    (q_ref, k_ref, v_ref, ksc_ref, vsc_ref, expwb_ref,
+     (mo_ref, lo_ref, ao_ref), (m_ref, l_ref, acc_ref)) = split_refs(
+        refs, quantized, 3)
     b = pl.program_id(0)
     sp_idx = pl.program_id(2)
     p_idx = pl.program_id(3)
@@ -228,39 +235,15 @@ def _paged_attn_split_kernel(
 
     length = len_ref[b]
 
-    q = q_ref[0, 0].astype(jnp.float32)          # (g, D)
-    k = _dequant_page(k_ref, ksc_ref, packed)    # (page_size, D)
-    scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    if softcap is not None:
-        scores = softcap * jnp.tanh(scores / softcap)
+    def mask_fn(shape):
+        # Absolute position: this split's run starts sp_idx * pps pages in.
+        pos = ((sp_idx * pps + p_idx) * page_size
+               + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+        return jnp.logical_and(pos < length, pos >= length - win_ref[0])
 
-    # Absolute position: this split's run starts sp_idx * pps pages in.
-    pos = ((sp_idx * pps + p_idx) * page_size
-           + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1))
-    mask = pos < length
-    if window is not None:
-        mask = jnp.logical_and(mask, pos >= length - window)
-    scores = jnp.where(mask, scores, NEG_INF)
-
-    m_prev = m_ref[...]                           # (g, 1)
-    m_cur = jnp.max(scores, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    if use_lut:
-        p = _lut_eval(scores - m_new, expwb_ref, lo=lo, inv_step=inv_step,
-                      sections=sections)
-        corr = _lut_eval(jnp.maximum(m_prev - m_new, lo), expwb_ref,
-                         lo=lo, inv_step=inv_step, sections=sections)
-    else:
-        p = jnp.exp(scores - m_new)
-        corr = jnp.exp(m_prev - m_new)
-    p = jnp.where(mask, p, 0.0)
-
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    v = _dequant_page(v_ref, vsc_ref, packed)     # (page_size, D)
-    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-        p, v, preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
+    attend_page(q_ref[0, 0].astype(jnp.float32), k_ref, v_ref, ksc_ref,
+                vsc_ref, expwb_ref, m_ref, l_ref, acc_ref, mask_fn=mask_fn,
+                **page_kw)
 
     @pl.when(p_idx == pps - 1)
     def _writeback():
@@ -269,19 +252,34 @@ def _paged_attn_split_kernel(
         ao_ref[0, 0, 0] = acc_ref[...]
 
 
+def check_scale_rows(k_pages, k_scales, v_scales):
+    """Validate the optional scale pools of one layer's page pool:
+    both or neither, laid out (P, Hkv, 1, page_size). True = quantized."""
+    if (k_scales is None) != (v_scales is None):
+        raise ValueError("pass both k_scales and v_scales or neither")
+    if k_scales is None:
+        return False
+    want = k_pages.shape[:2] + (1, k_pages.shape[2])
+    if k_scales.shape != want or v_scales.shape != want:
+        raise ValueError(
+            f"scale rows must be laid out (P, Hkv, 1, page_size) = {want}, "
+            f"got {k_scales.shape} / {v_scales.shape}")
+    return True
+
+
 def paged_attention(
     q: jax.Array,             # (B, H, D)
     k_pages: jax.Array,       # (P, Hkv, page_size, D) shared pool
     v_pages: jax.Array,       # (P, Hkv, page_size, D)
     block_tables: jax.Array,  # (B, n_pages) int32 physical page ids
     length: jax.Array,        # (B,) int32 valid cache lengths
-    k_scales: jax.Array | None = None,  # (P, Hkv, page_size) int8 mode
+    k_scales: jax.Array | None = None,  # (P, Hkv, 1, page_size) int8/int4
     v_scales: jax.Array | None = None,
     *,
     scale: float | None = None,
     exp_table: LutTable | None = None,
     softcap: float | None = None,
-    window: int | None = None,
+    window=None,              # int, traced int32 scalar, or None (global)
     kv_splits: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
@@ -291,49 +289,36 @@ def paged_attention(
     g = H // Hkv
     scale = scale if scale is not None else 1.0 / (D**0.5)
 
-    use_lut = exp_table is not None
-    if use_lut:
-        wb = exp_table.wb.astype(jnp.float32)
-        wb = jnp.pad(wb, ((0, TABLE_PAD - wb.shape[0]), (0, 0)))
-        lo, inv_step, sections = (exp_table.lo, exp_table.inv_step,
-                                  exp_table.sections)
-    else:
-        wb = jnp.zeros((TABLE_PAD, 2), jnp.float32)
-        lo, inv_step, sections = -1.0, 1.0, 1
-
-    if (k_scales is None) != (v_scales is None):
-        raise ValueError("pass both k_scales and v_scales or neither")
+    wb, lut = lut_operand(exp_table)
     qg = q.reshape(B, Hkv, g, D)
     lens = length.astype(jnp.int32)
     tables = block_tables.astype(jnp.int32)
-    quantized = k_scales is not None
+    quantized = check_scale_rows(k_pages, k_scales, v_scales)
     packed = 2 * k_pages.shape[-1] == D    # nibble-packed int4 payload
     Dp = k_pages.shape[-1]                 # payload axis (D, or D/2 packed)
     if packed and not quantized:
         raise ValueError("packed int4 pools require scale rows")
+    page_kw = dict(page_size=page_size, quantized=quantized, scale=scale,
+                   softcap=softcap, packed=packed,
+                   use_lut=exp_table is not None, lut=lut)
+    scalars = (lens, window_operand(window), tables)
 
     splits = effective_kv_splits(kv_splits, n_pages, page_size)
     if splits is not None:
         return _paged_attention_split(
-            qg, k_pages, v_pages, tables, lens, k_scales, v_scales,
-            splits=splits, scale=scale, wb=wb, use_lut=use_lut, lo=lo,
-            inv_step=inv_step, sections=sections, softcap=softcap,
-            window=window, quantized=quantized, packed=packed,
-            interpret=interpret, out_dtype=q.dtype)
+            qg, k_pages, v_pages, scalars, k_scales, v_scales, wb,
+            splits=splits, page_kw=page_kw, interpret=interpret,
+            out_dtype=q.dtype)
 
-    kernel = functools.partial(
-        _paged_attn_kernel, n_pages=n_pages, page_size=page_size,
-        scale=scale, use_lut=use_lut, lo=lo, inv_step=inv_step,
-        sections=sections, softcap=softcap, window=window,
-        quantized=quantized, packed=packed,
-    )
+    kernel = functools.partial(_paged_attn_kernel, n_pages=n_pages,
+                               **page_kw)
     # Physical page addresses come from the prefetched block table.
     page_spec = pl.BlockSpec((1, 1, page_size, Dp),
-                             lambda b, h, s, lens_ref, tbl_ref:
+                             lambda b, h, s, lens_ref, win_ref, tbl_ref:
                              (tbl_ref[b, s], h, 0, 0))
-    scale_spec = pl.BlockSpec((1, 1, page_size),
-                              lambda b, h, s, lens_ref, tbl_ref:
-                              (tbl_ref[b, s], h, 0))
+    scale_spec = pl.BlockSpec((1, 1, 1, page_size),
+                              lambda b, h, s, lens_ref, win_ref, tbl_ref:
+                              (tbl_ref[b, s], h, 0, 0))
     in_specs = [
         pl.BlockSpec((1, 1, g, D), lambda b, h, s, *_: (b, h, 0, 0)),
         page_spec,
@@ -346,10 +331,10 @@ def paged_attention(
         # host-side; the kernel widens after the DMA.
         in_specs += [scale_spec, scale_spec]
         inputs += [k_scales, v_scales]
-    in_specs.append(pl.BlockSpec((TABLE_PAD, 2), lambda b, h, s, *_: (0, 0)))
+    in_specs.append(SMEM_SPEC)
     inputs.append(wb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, Hkv, n_pages),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, g, D), lambda b, h, s, *_: (b, h, 0, 0)),
@@ -365,20 +350,20 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, D), q.dtype),
         interpret=interpret,
-    )(lens, tables, *inputs)
+    )(*scalars, *inputs)
     return out.reshape(B, H, D)
 
 
 def _paged_attention_split(
-    qg, k_pages, v_pages, tables, lens, k_scales, v_scales, *,
-    splits, scale, wb, use_lut, lo, inv_step, sections, softcap,
-    window, quantized, packed, interpret, out_dtype,
+    qg, k_pages, v_pages, scalars, k_scales, v_scales, wb, *,
+    splits, page_kw, interpret, out_dtype,
 ):
     """KV-split pallas_call: 4D grid + host-side partials combine."""
     from repro.distributed.collectives import merge_partial_softmax_stacked
 
     B, Hkv, g, D = qg.shape
     page_size, Dp = k_pages.shape[2], k_pages.shape[-1]
+    lens, win, tables = scalars
     n_pages = tables.shape[1]
     pps = -(-n_pages // splits)            # pages per split
     pad = pps * splits - n_pages
@@ -386,29 +371,23 @@ def _paged_attention_split(
         # Trash-page padding: positions land >= length, so masked out.
         tables = jnp.pad(tables, ((0, 0), (0, pad)))
 
-    kernel = functools.partial(
-        _paged_attn_split_kernel, pps=pps, page_size=page_size,
-        scale=scale, use_lut=use_lut, lo=lo, inv_step=inv_step,
-        sections=sections, softcap=softcap, window=window,
-        quantized=quantized, packed=packed,
-    )
+    kernel = functools.partial(_paged_attn_split_kernel, pps=pps, **page_kw)
     page_spec = pl.BlockSpec((1, 1, page_size, Dp),
-                             lambda b, h, sp, p, lens_ref, tbl_ref:
+                             lambda b, h, sp, p, lens_ref, win_ref, tbl_ref:
                              (tbl_ref[b, sp * pps + p], h, 0, 0))
-    scale_spec = pl.BlockSpec((1, 1, page_size),
-                              lambda b, h, sp, p, lens_ref, tbl_ref:
-                              (tbl_ref[b, sp * pps + p], h, 0))
+    scale_spec = pl.BlockSpec((1, 1, 1, page_size),
+                              lambda b, h, sp, p, lens_ref, win_ref, tbl_ref:
+                              (tbl_ref[b, sp * pps + p], h, 0, 0))
     in_specs = [
         pl.BlockSpec((1, 1, g, D), lambda b, h, sp, p, *_: (b, h, 0, 0)),
         page_spec,
         page_spec,
     ]
     inputs = [qg, k_pages, v_pages]
-    if quantized:
+    if page_kw["quantized"]:
         in_specs += [scale_spec, scale_spec]
         inputs += [k_scales, v_scales]
-    in_specs.append(pl.BlockSpec((TABLE_PAD, 2),
-                                 lambda b, h, sp, p, *_: (0, 0)))
+    in_specs.append(SMEM_SPEC)
     inputs.append(wb)
 
     part_spec = pl.BlockSpec((1, 1, 1, g, 1),
@@ -416,7 +395,7 @@ def _paged_attention_split(
     acc_spec = pl.BlockSpec((1, 1, 1, g, D),
                             lambda b, h, sp, p, *_: (b, h, sp, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, Hkv, splits, pps),
         in_specs=in_specs,
         out_specs=[part_spec, part_spec, acc_spec],
@@ -436,6 +415,6 @@ def _paged_attention_split(
             jax.ShapeDtypeStruct((B, Hkv, splits, g, D), jnp.float32),
         ],
         interpret=interpret,
-    )(lens, tables, *inputs)
+    )(lens, win, tables, *inputs)
     out = merge_partial_softmax_stacked(m, l, acc, axis=2)
     return out.reshape(B, Hkv * g, D).astype(out_dtype)

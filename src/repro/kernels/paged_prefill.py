@@ -10,20 +10,21 @@ chunk's own KV has already been written into its pages by the caller
 summarization stage run on the same bank-sequential placement the
 generation stage uses: no dense per-slot prefill arena, no scatter pass.
 
-  * block table + per-sequence lengths + per-sequence chunk starts are
-    `num_scalar_prefetch` inputs, so the BlockSpec index map computes
-    each physical page's DMA address before the body runs;
+  * per-sequence lengths + chunk starts + the sliding-window scalar +
+    block table are `num_scalar_prefetch` inputs, so the BlockSpec index
+    map computes each physical page's DMA address before the body runs;
   * the body is the decode kernel's online-softmax (m, l, acc) merge
     across pages — the C-ALU merge of per-bank partials — widened to
     Sq*g query rows, with a causal mask at absolute positions
     (key <= start + row//g) on top of the length mask;
   * exp optionally routes through the same 64-section LUT;
   * int8 pools (`k_scales`/`v_scales` given) dequantize in VMEM right
-    after the page DMA (payload * per-(page, head) scale row), the
-    same in-kernel dequant as `kernels/paged_attention.py` — the chunk's
-    own K/V was already amax-quantized at write time by the caller;
-    int4 pools (payload axis Dh/2, detected structurally) additionally
-    nibble-unpack in VMEM first, via the shared `_dequant_page`.
+    after the page DMA, folding each (page, head) scale row into the
+    score and probability tiles — the page body is
+    `kernels/paged_attention.attend_page`, shared with decode; the
+    chunk's own K/V was already amax-quantized at write time by the
+    caller; int4 pools (payload axis Dh/2, detected structurally)
+    additionally nibble-unpack in VMEM first.
 
 Grid: (B, Hkv, n_pages); q block (Sq*g, D) where g = H // Hkv (GQA
 groups share one K/V page stream; row r is query r//g, group r%g).
@@ -45,25 +46,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.lut import LutTable
-from repro.kernels.decode_attention import NEG_INF, _lut_eval
-from repro.kernels.lut_interp import TABLE_PAD
-from repro.kernels.paged_attention import _dequant_page
+from repro.kernels.decode_attention import NEG_INF, window_operand
+from repro.kernels.lut_interp import SMEM_SPEC, lut_operand
+from repro.kernels.paged_attention import (attend_page, check_scale_rows,
+                                           split_refs)
 
 
 def _paged_prefill_kernel(
     len_ref,    # scalar prefetch: (B,) int32 valid KV lengths (incl. chunk)
     start_ref,  # scalar prefetch: (B,) int32 absolute first query position
+    win_ref,    # scalar prefetch: (1,) int32 sliding window
     tbl_ref,    # scalar prefetch: (B, n_pages) int32 physical page ids
     *refs,      # q, k, v, [ksc, vsc,] expwb, o, then m/l/acc scratch
-    n_pages, page_size, g, scale, use_lut, lo, inv_step, sections,
-    softcap, window, quantized, packed,
+    n_pages, page_size, g, quantized, **page_kw,
 ):
-    if quantized:
-        (q_ref, k_ref, v_ref, ksc_ref, vsc_ref, expwb_ref, o_ref,
-         m_ref, l_ref, acc_ref) = refs
-    else:
-        q_ref, k_ref, v_ref, expwb_ref, o_ref, m_ref, l_ref, acc_ref = refs
-        ksc_ref = vsc_ref = None
+    (q_ref, k_ref, v_ref, ksc_ref, vsc_ref, expwb_ref, (o_ref,),
+     (m_ref, l_ref, acc_ref)) = split_refs(refs, quantized, 1)
     b = pl.program_id(0)
     s_idx = pl.program_id(2)
 
@@ -76,46 +74,17 @@ def _paged_prefill_kernel(
     length = len_ref[b]
     start = start_ref[b]
 
-    q = q_ref[0, 0].astype(jnp.float32)          # (Sq*g, D)
-    # In-kernel dequant: the page arrived narrow (int8, or nibble-packed
-    # int4); the scale row is DMA'd in its storage dtype (f32 or bf16)
-    # and widened in VMEM.
-    k = _dequant_page(k_ref, ksc_ref, packed)    # (page_size, D)
-    # Direction 1: contract head_dim (Q x K^T) — same layout, no transpose.
-    scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    if softcap is not None:
-        scores = softcap * jnp.tanh(scores / softcap)
+    def mask_fn(shape):
+        k_pos = (s_idx * page_size
+                 + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        q_pos = start + row // g                 # absolute query positions
+        causal = jnp.logical_and(k_pos < length, k_pos <= q_pos)
+        return jnp.logical_and(causal, k_pos > q_pos - win_ref[0])
 
-    k_pos = (s_idx * page_size
-             + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1))
-    row = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
-    q_pos = start + row // g                     # absolute query positions
-    mask = jnp.logical_and(k_pos < length, k_pos <= q_pos)
-    if window is not None:
-        mask = jnp.logical_and(mask, k_pos > q_pos - window)
-    scores = jnp.where(mask, scores, NEG_INF)
-
-    # Online softmax across pages: the C-ALU merge of per-bank partials.
-    m_prev = m_ref[...]                          # (Sq*g, 1)
-    m_cur = jnp.max(scores, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    if use_lut:
-        p = _lut_eval(scores - m_new, expwb_ref, lo=lo, inv_step=inv_step,
-                      sections=sections)
-        corr = _lut_eval(jnp.maximum(m_prev - m_new, lo), expwb_ref,
-                         lo=lo, inv_step=inv_step, sections=sections)
-    else:
-        p = jnp.exp(scores - m_new)
-        corr = jnp.exp(m_prev - m_new)
-    p = jnp.where(mask, p, 0.0)
-
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    # Direction 2: contract seq (S x V) over the same V page.
-    v = _dequant_page(v_ref, vsc_ref, packed)    # (page_size, D)
-    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-        p, v, preferred_element_type=jnp.float32
-    )
-    m_ref[...] = m_new
+    attend_page(q_ref[0, 0].astype(jnp.float32), k_ref, v_ref, ksc_ref,
+                vsc_ref, expwb_ref, m_ref, l_ref, acc_ref, mask_fn=mask_fn,
+                **page_kw)
 
     @pl.when(s_idx == n_pages - 1)
     def _writeback():
@@ -130,13 +99,13 @@ def paged_prefill_attention(
     block_tables: jax.Array,  # (B, n_pages) int32 physical page ids
     length: jax.Array,        # (B,) int32 valid KV lengths (start + Sq)
     start: jax.Array,         # (B,) int32 absolute position of query 0
-    k_scales: jax.Array | None = None,  # (P, Hkv, page_size) int8 mode
+    k_scales: jax.Array | None = None,  # (P, Hkv, 1, page_size) int8/int4
     v_scales: jax.Array | None = None,
     *,
     scale: float | None = None,
     exp_table: LutTable | None = None,
     softcap: float | None = None,
-    window: int | None = None,
+    window=None,              # int, traced int32 scalar, or None (global)
     interpret: bool = False,
 ) -> jax.Array:
     B, Sq, H, D = q.shape
@@ -144,27 +113,13 @@ def paged_prefill_attention(
     n_pages = block_tables.shape[1]
     g = H // Hkv
     scale = scale if scale is not None else 1.0 / (D**0.5)
-
-    use_lut = exp_table is not None
-    if use_lut:
-        wb = exp_table.wb.astype(jnp.float32)
-        wb = jnp.pad(wb, ((0, TABLE_PAD - wb.shape[0]), (0, 0)))
-        lo, inv_step, sections = (exp_table.lo, exp_table.inv_step,
-                                  exp_table.sections)
-    else:
-        wb = jnp.zeros((TABLE_PAD, 2), jnp.float32)
-        lo, inv_step, sections = -1.0, 1.0, 1
+    wb, lut = lut_operand(exp_table)
 
     # (B, Sq, H, D) -> (B, Hkv, Sq*g, D): row r is query r//g, group r%g.
     qg = (q.reshape(B, Sq, Hkv, g, D)
           .transpose(0, 2, 1, 3, 4)
           .reshape(B, Hkv, Sq * g, D))
-    if (k_scales is None) != (v_scales is None):
-        raise ValueError("pass both k_scales and v_scales or neither")
-    lens = length.astype(jnp.int32)
-    starts = start.astype(jnp.int32)
-    tables = block_tables.astype(jnp.int32)
-    quantized = k_scales is not None
+    quantized = check_scale_rows(k_pages, k_scales, v_scales)
     packed = 2 * k_pages.shape[-1] == D    # nibble-packed int4 payload
     Dp = k_pages.shape[-1]                 # payload axis (D, or D/2 packed)
     if packed and not quantized:
@@ -172,17 +127,15 @@ def paged_prefill_attention(
 
     kernel = functools.partial(
         _paged_prefill_kernel, n_pages=n_pages, page_size=page_size, g=g,
-        scale=scale, use_lut=use_lut, lo=lo, inv_step=inv_step,
-        sections=sections, softcap=softcap, window=window,
-        quantized=quantized, packed=packed,
-    )
+        quantized=quantized, scale=scale, softcap=softcap, packed=packed,
+        use_lut=exp_table is not None, lut=lut)
     # Physical page addresses come from the prefetched block table.
     page_spec = pl.BlockSpec((1, 1, page_size, Dp),
-                             lambda b, h, s, lens_ref, start_ref, tbl_ref:
-                             (tbl_ref[b, s], h, 0, 0))
-    scale_spec = pl.BlockSpec((1, 1, page_size),
-                              lambda b, h, s, lens_ref, start_ref, tbl_ref:
-                              (tbl_ref[b, s], h, 0))
+                             lambda b, h, s, lens_ref, start_ref, win_ref,
+                             tbl_ref: (tbl_ref[b, s], h, 0, 0))
+    scale_spec = pl.BlockSpec((1, 1, 1, page_size),
+                              lambda b, h, s, lens_ref, start_ref, win_ref,
+                              tbl_ref: (tbl_ref[b, s], h, 0, 0))
     in_specs = [
         pl.BlockSpec((1, 1, Sq * g, D), lambda b, h, s, *_: (b, h, 0, 0)),
         page_spec,
@@ -195,10 +148,10 @@ def paged_prefill_attention(
         # host-side; the kernel widens after the DMA.
         in_specs += [scale_spec, scale_spec]
         inputs += [k_scales, v_scales]
-    in_specs.append(pl.BlockSpec((TABLE_PAD, 2), lambda b, h, s, *_: (0, 0)))
+    in_specs.append(SMEM_SPEC)
     inputs.append(wb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(B, Hkv, n_pages),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, Sq * g, D),
@@ -215,7 +168,8 @@ def paged_prefill_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, Sq * g, D), q.dtype),
         interpret=interpret,
-    )(lens, starts, tables, *inputs)
+    )(length.astype(jnp.int32), start.astype(jnp.int32),
+      window_operand(window), block_tables.astype(jnp.int32), *inputs)
     return (out.reshape(B, Hkv, Sq, g, D)
             .transpose(0, 2, 1, 3, 4)
             .reshape(B, Sq, H, D))

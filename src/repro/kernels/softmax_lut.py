@@ -21,40 +21,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.lut import LutTable
-from repro.kernels.lut_interp import TABLE_PAD
+from repro.kernels.lut_interp import SMEM_SPEC, lut_eval, lut_operand
 
 
-def _lut_eval(x, wb_ref, *, lo, inv_step, sections):
-    idx = jnp.floor((x - lo) * inv_step).astype(jnp.int32) + 1
-    idx = jnp.clip(idx, 0, sections + 1)
-    rows, lanes = x.shape
-    onehot = (
-        idx.reshape(rows * lanes, 1)
-        == jax.lax.broadcasted_iota(jnp.int32, (rows * lanes, TABLE_PAD), 1)
-    ).astype(jnp.float32)
-    wb = jnp.dot(onehot, wb_ref[...].astype(jnp.float32),
-                 preferred_element_type=jnp.float32)
-    return wb[:, 0].reshape(rows, lanes) * x + wb[:, 1].reshape(rows, lanes)
-
-
-def _recip_range_reduced(x, wb_ref, *, lo, inv_step, sections):
+def _recip_range_reduced(x, wb_ref, lut):
     """1/x for x > 0: LUT on the mantissa, exponent negated (bit shift)."""
     bits = jax.lax.bitcast_convert_type(x, jnp.int32)
     e = ((bits >> 23) & 0xFF) - 126
     m = jax.lax.bitcast_convert_type(
         (bits & jnp.int32(0x007FFFFF)) | jnp.int32(0x3F000000), jnp.float32)
-    r = _lut_eval(m, wb_ref, lo=lo, inv_step=inv_step, sections=sections)
-    return r * jnp.exp2(-e.astype(jnp.float32))
+    return lut_eval(m, wb_ref, **lut) * jnp.exp2(-e.astype(jnp.float32))
 
 
-def _softmax_kernel(x_ref, expwb_ref, recipwb_ref, o_ref, *,
-                    e_lo, e_inv, e_sec, r_lo, r_inv, r_sec):
+def _softmax_kernel(x_ref, expwb_ref, recipwb_ref, o_ref, *, exp_lut,
+                    recip_lut):
     x = x_ref[...].astype(jnp.float32)                    # (rows, S)
     m = jnp.max(x, axis=-1, keepdims=True)                # S-ALU max
-    p = _lut_eval(x - m, expwb_ref, lo=e_lo, inv_step=e_inv, sections=e_sec)
+    p = lut_eval(x - m, expwb_ref, **exp_lut)
     s = jnp.sum(p, axis=-1, keepdims=True)                # C-ALU reduce
-    inv = _recip_range_reduced(jnp.maximum(s, 1e-9), recipwb_ref,
-                               lo=r_lo, inv_step=r_inv, sections=r_sec)
+    inv = _recip_range_reduced(jnp.maximum(s, 1e-9), recipwb_ref, recip_lut)
     o_ref[...] = (p * inv).astype(o_ref.dtype)            # S-ALU multiply
 
 
@@ -66,22 +51,16 @@ def softmax_lut(x: jax.Array, exp_table: LutTable, recip_table: LutTable,
     block_rows = min(block_rows, n)
     while n % block_rows:
         block_rows -= 1
-    ewb = jnp.pad(exp_table.wb.astype(jnp.float32),
-                  ((0, TABLE_PAD - exp_table.wb.shape[0]), (0, 0)))
-    rwb = jnp.pad(recip_table.wb.astype(jnp.float32),
-                  ((0, TABLE_PAD - recip_table.wb.shape[0]), (0, 0)))
-    kernel = functools.partial(
-        _softmax_kernel,
-        e_lo=exp_table.lo, e_inv=exp_table.inv_step, e_sec=exp_table.sections,
-        r_lo=recip_table.lo, r_inv=recip_table.inv_step,
-        r_sec=recip_table.sections)
+    ewb, exp_lut = lut_operand(exp_table)
+    rwb, recip_lut = lut_operand(recip_table)
     return pl.pallas_call(
-        kernel,
+        functools.partial(_softmax_kernel, exp_lut=exp_lut,
+                          recip_lut=recip_lut),
         grid=(n // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, s), lambda i: (i, 0)),
-            pl.BlockSpec((TABLE_PAD, 2), lambda i: (0, 0)),
-            pl.BlockSpec((TABLE_PAD, 2), lambda i: (0, 0)),
+            SMEM_SPEC,
+            SMEM_SPEC,
         ],
         out_specs=pl.BlockSpec((block_rows, s), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, s), x.dtype),

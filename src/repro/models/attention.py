@@ -185,7 +185,7 @@ def attention_prefill_chunk_paged(
     cos: Array | None,             # rope at positions start .. start+S-1
     sin: Array | None,
     window,
-    k_scale: Array | None = None,  # (P, Hkv, page) int8-pool scale rows
+    k_scale: Array | None = None,  # (P, Hkv, 1, page) int8/int4 scale rows
     v_scale: Array | None = None,
 ):
     """Chunked paged prefill attention: write the chunk's K/V directly
@@ -273,12 +273,11 @@ def _shard_map_paged(write_and_attend, mesh, h_axis, *, head_axis,
     `start` is None for the decode step (no chunk offset); `window`
     is None when the layer attends globally with no window scalar.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     rep = P()
-    pool = P(None, h_axis, None, None)        # (P, Hkv, page, Dh)
-    scrow = P(None, h_axis, None)             # (P, Hkv, page)
+    pool = P(None, h_axis, None, None)  # (P, Hkv, page, Dh); scale rows
+                                        # (P, Hkv, 1, page) shard alike
     heads = P(*([None] * head_axis), h_axis, None)  # q/k/v, head axis sharded
 
     has = {"start": start is not None, "window": window is not None,
@@ -294,8 +293,8 @@ def _shard_map_paged(write_and_attend, mesh, h_axis, *, head_axis,
         in_specs.append(rep)
     if has["scales"]:
         args += [k_scale, v_scale]
-        in_specs += [scrow, scrow]
-        out_specs += [scrow, scrow]
+        in_specs += [pool, pool]
+        out_specs += [pool, pool]
 
     def region(q, k, v, kp, vp, bt, ln, *rest):
         rest = list(rest)
@@ -315,11 +314,11 @@ def _shard_map_paged(write_and_attend, mesh, h_axis, *, head_axis,
         return tuple(out)
 
     # Replicated out_spec for the merged heads: gather_heads already
-    # made every shard's copy identical (check_rep=False because the
+    # made every shard's copy identical (check_vma=False because the
     # region may contain a pallas_call, which has no replication rule).
     out_specs[0] = rep
-    res = shard_map(region, mesh=mesh, in_specs=tuple(in_specs),
-                    out_specs=tuple(out_specs), check_rep=False)(*args)
+    res = jax.shard_map(region, mesh=mesh, in_specs=tuple(in_specs),
+                        out_specs=tuple(out_specs), check_vma=False)(*args)
     if has["scales"]:
         att, kp, vp, ksc, vsc = res
     else:
@@ -361,7 +360,7 @@ def attention_decode_paged(
     cos: Array | None,
     sin: Array | None,
     window: Optional[int] = None,
-    k_scale: Array | None = None,  # (P, Hkv, page) int8-pool scale rows
+    k_scale: Array | None = None,  # (P, Hkv, 1, page) int8/int4 scale rows
     v_scale: Array | None = None,
 ):
     """One decode step against a paged cache; returns (out, k', v').

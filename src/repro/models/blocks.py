@@ -12,10 +12,6 @@ from repro.models.config import ModelConfig
 
 Array = jax.Array
 
-# Sentinel window width meaning "global attention" when windows are traced
-# per-layer scalars inside a scan over layers.
-GLOBAL_WINDOW = 1 << 30
-
 
 def init_norm(cfg: ModelConfig, d: int | None = None) -> dict:
     d = d or cfg.d_model

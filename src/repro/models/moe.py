@@ -121,7 +121,6 @@ def _apply_moe_shardmap(p: dict, x: Array, cfg: ModelConfig,
     outputs. Cross-pod: the batch axis includes 'pod', handled by the
     in_specs; no pod collective is introduced.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     lead = x.shape[:-1]
@@ -162,11 +161,11 @@ def _apply_moe_shardmap(p: dict, x: Array, cfg: ModelConfig,
     xg = xt.reshape(G, Tg, d)
     gspec = P(dp_axes if len(dp_axes) > 1 else (dp_axes[0] if dp_axes else None))
     espec = P("model")
-    out = shard_map(
+    out = jax.shard_map(
         local, mesh=mesh,
         in_specs=(gspec, P(), espec, espec, espec),
         out_specs=gspec,
-        check_rep=False,
+        check_vma=False,
     )(xg, p["router"],
       p.get("w_gate", p["w_up"]), p["w_up"], p["w_down"])
     return out.reshape(*lead, d)

@@ -27,7 +27,7 @@ from repro.core.salpim import SalPimEngine
 from repro.distributed.api import constrain
 from repro.models import blocks as blk
 from repro.models import mamba2 as m2
-from repro.models.blocks import GLOBAL_WINDOW
+from repro.kernels.decode_attention import GLOBAL_WINDOW
 from repro.models.config import ModelConfig
 from repro.models.rope import mrope_cos_sin, rope_cos_sin
 
@@ -449,7 +449,7 @@ def prefill_chunk(params: dict, tokens: Array, block_tables: Array,
     running a prompt in any chunk split reproduces the one-shot logits.
 
     Returns (last-position logits (B, V), k_pages', v_pages').
-    int8 pools (k_scales/v_scales (L, P, Hkv, page) given) quantize each
+    int8/int4 pools (k_scales/v_scales (L, P, Hkv, 1, page) given) quantize each
     chunk at write time and return the 5-tuple with the updated scale
     pools. Prefix sharing composes: a shared prompt simply starts its
     first chunk at the shared offset (the caller COW-forks any shared

@@ -39,7 +39,13 @@ class TrainConfig:
 def make_train_step(model_cfg: ModelConfig, engine: SalPimEngine,
                     opt_cfg: opt_lib.AdamWConfig,
                     *, n_micro: int = 1) -> Callable:
-    """Pure (params, opt_state, batch) -> (params, opt_state, metrics)."""
+    """Pure (params, opt_state, batch) -> (params, opt_state, metrics).
+
+    The step runs the jnp path (impl="reference") whatever `engine`
+    resolved to: the Pallas kernels carry no gradient rule, and XLA
+    cannot partition them over a training mesh."""
+    engine = dataclasses.replace(
+        engine, config=dataclasses.replace(engine.config, impl="reference"))
 
     def loss_fn(params, batch):
         return model_api.loss_fn(params, batch, model_cfg, engine)

@@ -105,8 +105,8 @@ class EngineConfig:
     kv_splits: Optional[int] = None
     # Roofline target for the cost model's memory/compute-bound
     # classification: a key of costmodel.HARDWARE_SPECS ("hbm2",
-    # "salpim-hbm2", "tpu-v4", ...). None = detect from the jax
-    # backend. Purely observational — never changes what runs.
+    # "salpim-hbm2", "TPU v4", ...). None = the running device's
+    # device_kind. Purely observational — never changes what runs.
     hardware: Optional[str] = None
 
     @classmethod

@@ -75,14 +75,13 @@ class HardwareSpec:
 
     `ridge` (FLOP/byte) is the arithmetic intensity where the two roofs
     cross; phases below it are memory-bound, above it compute-bound.
-    The specs below are public datasheet numbers, coarse on purpose —
-    the classification only needs the right order of magnitude (decode
-    intensity is ~1 FLOP/byte, ridges are 10-300).
+    Every spec names the source of its peaks (`source`).
     """
 
     name: str
     peak_flops: float            # FLOP/s
     peak_bytes_per_sec: float    # B/s
+    source: str = ""
 
     @property
     def ridge(self) -> float:
@@ -97,36 +96,46 @@ class HardwareSpec:
                 "ridge_flops_per_byte": self.ridge}
 
 
-HARDWARE_SPECS: dict[str, HardwareSpec] = {
-    # An HBM2 stack as the SAL-PIM paper baselines against: 307.2 GB/s
-    # per stack, paired with a ~100 TFLOP/s-class accelerator.
-    "hbm2": HardwareSpec("hbm2", 100e12, 307.2e9),
-    # SAL-PIM's subarray-level PIM: the paper's 8x internal-bandwidth
-    # multiplier over the HBM2 interface, compute sized to the in-DRAM
-    # ALUs (the point is the ridge moves *left*).
-    "salpim-hbm2": HardwareSpec("salpim-hbm2", 4.9e12, 2457.6e9),
-    # TPU v4 datasheet: 275 TFLOP/s bf16, 1.2 TB/s HBM2e.
-    "tpu-v4": HardwareSpec("tpu-v4", 275e12, 1.2e12),
-    # TPU v5e: 197 TFLOP/s bf16, 819 GB/s.
-    "tpu-v5e": HardwareSpec("tpu-v5e", 197e12, 819e9),
-    # A generous host CPU (AVX-class vector units, ~100 GB/s DDR) for
-    # the CPU-backend runs this repo's CI does.
-    "cpu": HardwareSpec("cpu", 1e12, 100e9),
-}
+def _spec(name, peak_flops, peak_bytes_per_sec, source):
+    return name, HardwareSpec(name, peak_flops, peak_bytes_per_sec, source)
+
+
+# Real devices are keyed by their jax `device_kind`; the paper's modeled
+# memory systems by name (EngineConfig.hardware selects either).
+HARDWARE_SPECS: dict[str, HardwareSpec] = dict([
+    _spec("TPU v5 lite", 197e12, 819e9,
+          "Google Cloud TPU v5e documentation: 197 TFLOP/s bf16, "
+          "819 GB/s HBM2"),
+    _spec("TPU v4", 275e12, 1.2e12,
+          "Google Cloud TPU v4 documentation: 275 TFLOP/s bf16, "
+          "1200 GB/s HBM2"),
+    # The CPU backend runs correctness tests only; this roof exists so
+    # its cost-model output stays defined, and is never a device claim.
+    _spec("cpu", 1e12, 100e9,
+          "nominal host CPU (AVX-class, ~100 GB/s DDR); not measured"),
+    _spec("hbm2", 100e12, 307.2e9,
+          "SAL-PIM paper baseline: one HBM2 stack at 307.2 GB/s paired "
+          "with a ~100 TFLOP/s-class accelerator"),
+    _spec("salpim-hbm2", 4.9e12, 2457.6e9,
+          "SAL-PIM paper: 8x internal bandwidth over the HBM2 interface, "
+          "compute sized to the in-DRAM ALUs"),
+])
 
 
 def detect_hardware() -> HardwareSpec:
-    """Pick a spec from the jax backend; coarse but always defined."""
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-        platform = jax.devices()[0].platform
-    except Exception:      # pragma: no cover - jax is a hard dep in-tree
+    """The spec of `jax.devices()[0]`, by `device_kind`. The nominal CPU
+    roof applies only on the CPU platform; an accelerator whose kind has
+    no table entry is an error, never a default."""
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
         return HARDWARE_SPECS["cpu"]
-    if platform == "tpu":
-        if "v5" in kind and ("lite" in kind or "v5e" in kind):
-            return HARDWARE_SPECS["tpu-v5e"]
-        return HARDWARE_SPECS["tpu-v4"]
-    return HARDWARE_SPECS["cpu"]
+    spec = HARDWARE_SPECS.get(dev.device_kind)
+    if spec is None:
+        raise ValueError(
+            f"no roofline peaks for {dev.platform} device_kind "
+            f"{dev.device_kind!r}; add it to costmodel.HARDWARE_SPECS "
+            "with the source of its peaks")
+    return spec
 
 
 @dataclasses.dataclass

@@ -152,8 +152,10 @@ class PagedCache:
     block_tables: (B, max_pages) int32 physical page per logical page
     k_pages:      (L, P, Hkv, page_size, Dh) shared K pool
     v_pages:      (L, P, Hkv, page_size, Dh) shared V pool
-    k_scale:      (L, P, Hkv, page_size) int8 mode dequant scales
-    v_scale:      (L, P, Hkv, page_size) (f32 or bf16; None in fp mode)
+    k_scale:      (L, P, Hkv, 1, page_size) int8/int4 dequant scales
+    v_scale:      (L, P, Hkv, 1, page_size) (f32 or bf16; None in fp
+                  mode) — the unit axis makes each (page, head) scale row
+                  its own (1, page_size) kernel block
     """
 
     lengths: Array
@@ -230,8 +232,8 @@ def init_paged_cache(cfg, batch: int, num_pages: int, page_size: int,
             block_tables=tables,
             k_pages=jnp.zeros(shape, jnp.int8),
             v_pages=jnp.zeros(shape, jnp.int8),
-            k_scale=jnp.zeros(shape[:-1], sdt),
-            v_scale=jnp.zeros(shape[:-1], sdt),
+            k_scale=jnp.zeros(shape[:3] + (1, page_size), sdt),
+            v_scale=jnp.zeros(shape[:3] + (1, page_size), sdt),
         )
     if kv_dtype != "model":
         raise ValueError(f"unknown kv_dtype {kv_dtype!r}")
@@ -291,7 +293,7 @@ def append_kv_pages(k_pages: Array, v_pages: Array, block_tables: Array,
     k_new/v_new: (B, Hkv, Dh). Slots whose logical page is unmapped hit
     the trash page (block tables default to 0 there).
 
-    With scale pools (k_scale/v_scale (P, Hkv, page), int8/int4 mode)
+    With scale pools (k_scale/v_scale (P, Hkv, 1, page), int8/int4 mode)
     the new vectors are amax-quantized here — at write time — and the
     narrow payload plus its scale land in the same (page, offset);
     returns (k_pages, v_pages, k_scale, v_scale). Without, returns the
@@ -310,8 +312,8 @@ def append_kv_pages(k_pages: Array, v_pages: Array, block_tables: Array,
         v_q, v_sc = quant(v_new, scale_dtype=v_scale.dtype)
         k_pages = k_pages.at[phys, :, off].set(k_q)
         v_pages = v_pages.at[phys, :, off].set(v_q)
-        k_scale = k_scale.at[phys, :, off].set(k_sc)
-        v_scale = v_scale.at[phys, :, off].set(v_sc)
+        k_scale = k_scale.at[phys, :, 0, off].set(k_sc)
+        v_scale = v_scale.at[phys, :, 0, off].set(v_sc)
         return k_pages, v_pages, k_scale, v_scale
     k_pages = k_pages.at[phys, :, off].set(k_new.astype(k_pages.dtype))
     v_pages = v_pages.at[phys, :, off].set(v_new.astype(v_pages.dtype))
@@ -408,8 +410,8 @@ def append_chunk_kv_pages(k_pages: Array, v_pages: Array,
         v_q, v_sc = quant(v_new, scale_dtype=v_scale.dtype)
         k_pages = k_pages.at[phys, :, off].set(k_q)
         v_pages = v_pages.at[phys, :, off].set(v_q)
-        k_scale = k_scale.at[phys, :, off].set(k_sc)
-        v_scale = v_scale.at[phys, :, off].set(v_sc)
+        k_scale = k_scale.at[phys, :, 0, off].set(k_sc)
+        v_scale = v_scale.at[phys, :, 0, off].set(v_sc)
         return k_pages, v_pages, k_scale, v_scale
     k_pages = k_pages.at[phys, :, off].set(k_new.astype(k_pages.dtype))
     v_pages = v_pages.at[phys, :, off].set(v_new.astype(v_pages.dtype))
@@ -462,7 +464,7 @@ class SwappedKV:
 
     n_tokens: valid tokens the pages held at swap-out
     k, v:     (L, n_pages, Hkv, page_size, Dh) numpy, pool dtype
-    k_scale, v_scale: (L, n_pages, Hkv, page_size) or None (fp mode)
+    k_scale, v_scale: (L, n_pages, Hkv, 1, page_size) or None (fp mode)
     """
 
     n_tokens: int

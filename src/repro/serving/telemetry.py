@@ -727,19 +727,13 @@ def bench_metadata() -> dict:
             timeout=10).stdout.strip() or "unknown"
     except (OSError, subprocess.SubprocessError):
         sha = "unknown"
-    try:
-        import jax
-        dev = jax.devices()[0]
-        jax_version = jax.__version__
-        device_kind = dev.device_kind
-        platform = dev.platform
-    except Exception:      # pragma: no cover - jax is a hard dep in-tree
-        jax_version = device_kind = platform = "unknown"
+    import jax
+    dev = jax.devices()[0]
     return {
         "schema_version": SCHEMA_VERSION,
         "git_sha": sha,
-        "jax_version": jax_version,
-        "device_kind": device_kind,
-        "platform": platform,
+        "jax_version": jax.__version__,
+        "device_kind": dev.device_kind,
+        "platform": dev.platform,
         "generated_utc": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
     }

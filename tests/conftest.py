@@ -1,6 +1,8 @@
 """Shared test helpers.
 
-The in-process suite runs with 8 fake CPU devices (the flag below is set
+The suite is a CPU suite: JAX_PLATFORMS defaults to cpu (so it stays one
+on a machine with a TPU; `chip_smoke.py` is what runs there), and the
+in-process suite runs with 8 fake CPU devices (the flag below is set
 before any test module imports jax, which is what makes it stick): mesh
 tests build real 2-8 way `jax.sharding.Mesh`es without subprocess
 machinery, and everything else just sees extra idle devices — arrays
@@ -15,6 +17,7 @@ import sys
 
 import pytest
 
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=8"

@@ -18,6 +18,7 @@ from repro.kernels import ops, ref as ref_k
 from repro.models import api
 from repro.serving import kvcache as kv
 from repro.serving.engine import GenConfig, ServingEngine
+from tolerances import f32_reorder_tol
 
 ENGINE = SalPimEngine.create(SalPimConfig())
 KEY = jax.random.PRNGKey(0)
@@ -186,18 +187,24 @@ def test_prefill_chunk_matches_dense_prefill(arch, splits):
         logits_c, kp, vp = api.prefill_chunk(
             params, prompt[:, a:b], row, jnp.asarray([a], jnp.int32),
             kp, vp, cfg, ENGINE)
+    # Chunks of 4-13 rows and the 13-row dense prefill sum their f32
+    # contractions in shape-dependent orders (tests/tolerances.py).
+    tol = f32_reorder_tol(cfg)
     np.testing.assert_allclose(np.asarray(logits_c), np.asarray(logits_d),
-                               rtol=1e-5, atol=1e-5)
+                               rtol=tol, atol=tol)
+    # The pool K/V go through the same reordered contractions: at 1e-6
+    # one element in 1664 missed by 1.18e-6 (gpt2_medium smoke, splits1,
+    # CPU, xdist -n 2), so these checks take the derived bound too.
     gk = jnp.moveaxis(kp[:, pages], 1, 2).reshape(
         cfg.n_layers, cfg.n_kv_heads, -1, cfg.head_dim)[:, :, :S]
     gv = jnp.moveaxis(vp[:, pages], 1, 2).reshape(
         cfg.n_layers, cfg.n_kv_heads, -1, cfg.head_dim)[:, :, :S]
     np.testing.assert_allclose(np.asarray(gk),
                                np.asarray(cache_d.k[:, 0, :, :S]),
-                               rtol=1e-6, atol=1e-6)
+                               rtol=tol, atol=tol)
     np.testing.assert_allclose(np.asarray(gv),
                                np.asarray(cache_d.v[:, 0, :, :S]),
-                               rtol=1e-6, atol=1e-6)
+                               rtol=tol, atol=tol)
 
 
 # ---------------------------------------------------------------------------

@@ -152,6 +152,32 @@ def test_hardware_classification_and_ridge():
     assert detect_hardware().name in HARDWARE_SPECS
 
 
+def test_hardware_specs_keyed_by_device_kind_with_sources():
+    for key, spec in HARDWARE_SPECS.items():
+        assert spec.name == key and spec.source, key
+    v5e = HARDWARE_SPECS["TPU v5 lite"]      # v5e's jax device_kind
+    assert (v5e.peak_flops, v5e.peak_bytes_per_sec) == (197e12, 819e9)
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("tpu", "TPU v5 lite", "TPU v5 lite"),
+    ("tpu", "TPU v4", "TPU v4"),
+    ("cpu", "Intel Xeon", "cpu"),
+    ("tpu", "TPU v99", None),
+    ("gpu", "NVIDIA H100 80GB HBM3", None),
+])
+def test_detect_hardware_by_device_kind(monkeypatch, platform, kind, want):
+    """An accelerator kind without a table entry raises; the nominal
+    CPU roof is only for the CPU platform."""
+    dev = type("Dev", (), {"platform": platform, "device_kind": kind})()
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [dev])
+    if want is None:
+        with pytest.raises(ValueError, match="no roofline peaks"):
+            detect_hardware()
+    else:
+        assert detect_hardware().name == want
+
+
 def test_engine_config_hardware_validation():
     cfg, _ = _setup()
     with pytest.raises(ValueError, match="unknown hardware"):

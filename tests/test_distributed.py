@@ -155,7 +155,7 @@ def test_compressed_psum_and_softmax_merge(subproc):
     code = """
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.distributed.collectives import compressed_psum, merge_partial_softmax
 

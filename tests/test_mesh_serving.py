@@ -48,12 +48,12 @@ def _workload(cfg, seed=0, n=4, shared_prefix=0):
     return prompts, new
 
 
-def _drain(params, cfg, prompts, new, priorities=None, **kw):
+def _drain(params, cfg, prompts, new, priorities=None, engine=ENGINE, **kw):
     kw.setdefault("gen", GenConfig(temperature=0.0, stop_on_eos=False))
     kw.setdefault("slots", 2)
     kw.setdefault("max_len", 32)
     kw.setdefault("page_size", 8)
-    eng = ServingEngine(params, cfg, ENGINE,
+    eng = ServingEngine(params, cfg, engine,
                         EngineConfig(paged=True, **kw))
     prios = priorities or [0] * len(prompts)
     uids = [eng.submit(p.copy(), max_new_tokens=n, priority=pr)
@@ -115,8 +115,9 @@ def test_paged_pool_pspecs_shard_kv_head_axis():
     assert specs["pools"] == P(None, None, "model", None, None)
     assert specs["lengths"] == P() and specs["block_tables"] == P()
     assert specs["scales"] is None
+    # Scale rows (L, P, Hkv, 1, page) shard exactly like their pools.
     assert paged_pool_pspecs(mesh, quantized=True)["scales"] \
-        == P(None, None, "model", None)
+        == P(None, None, "model", None, None)
 
 
 # ---------------------------------------------------------------------------
@@ -149,7 +150,7 @@ def test_int8_scale_rows_shard_with_their_pools():
     cache = api.init_paged_cache(cfg, batch=2, num_pages=8, page_size=4,
                                  max_pages=8, kv_dtype="int8",
                                  kv_scale_dtype="bfloat16", mesh=mesh)
-    want = NamedSharding(mesh, P(None, None, "model", None))
+    want = NamedSharding(mesh, P(None, None, "model", None, None))
     assert cache.k_scale.sharding == want
     assert cache.v_scale.sharding == want
 
@@ -186,6 +187,24 @@ def test_mesh_decode_bit_identical(mesh_env, width):
     cfg, params, prompts, new, ref = mesh_env
     out, _ = _drain(params, cfg, prompts, new, mesh=_mesh(width))
     assert out == ref
+
+
+@needs2
+def test_mesh_kernels_bit_identical():
+    """Through the Pallas kernels (interpret mode here, compiled on a
+    TPU): the paged attention runs in the pools' shard_map and the GEMV
+    in a replicated one of its own (Mosaic kernels cannot be partitioned
+    automatically), with the same greedy tokens as one device."""
+    cfg, params = _setup()
+    prompts, new = _workload(cfg, seed=7, n=3)
+    engine = SalPimEngine.create(SalPimConfig(nonlinear_mode="lut",
+                                              impl="interpret"))
+    ref, _ = _drain(params, cfg, prompts, new, engine=engine,
+                    prefill_chunk_tokens=5)
+    out, eng = _drain(params, cfg, prompts, new, engine=engine,
+                      prefill_chunk_tokens=5, mesh=_mesh(2))
+    assert out == ref
+    assert eng.allocator.used_pages == 0
 
 
 @needs2

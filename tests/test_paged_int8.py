@@ -38,6 +38,12 @@ def test_quantize_vec_roundtrip_error_bound():
     assert (err <= bound).all(), (err.max(), bound.max())
 
 
+def _rows(sc):
+    """(P, Hkv, page) per-vector scales -> the pools' (P, Hkv, 1, page)
+    scale-row layout."""
+    return sc[:, :, None]
+
+
 def _paged_int8_setup(B, H, Hkv, D, page, npg, lengths, key=KEY):
     """fp pools + their quantized twins behind one shuffled block table."""
     ks = jax.random.split(key, 3)
@@ -49,7 +55,7 @@ def _paged_int8_setup(B, H, Hkv, D, page, npg, lengths, key=KEY):
     vp = jax.random.normal(ks[2], (P, Hkv, page, D), jnp.float32)
     kq, ksc = quantize_vec(kp)
     vq, vsc = quantize_vec(vp)
-    return (q, kp, vp, kq, ksc, vq, vsc, jnp.asarray(tables),
+    return (q, kp, vp, kq, _rows(ksc), vq, _rows(vsc), jnp.asarray(tables),
             jnp.asarray(lengths, jnp.int32))
 
 
@@ -122,6 +128,7 @@ def test_int8_prefill_kernel_matches_ref(Sq, starts, lengths):
         rng.permutation(np.arange(1, P)).reshape(B, npg).astype(np.int32))
     kq, ksc = quantize_vec(jax.random.normal(ks[0], (P, Hkv, page, D)))
     vq, vsc = quantize_vec(jax.random.normal(ks[1], (P, Hkv, page, D)))
+    ksc, vsc = _rows(ksc), _rows(vsc)
     q = jax.random.normal(ks[2], (B, Sq, H, D), jnp.float32)
     st = jnp.asarray(starts, jnp.int32)
     lens = jnp.asarray(lengths, jnp.int32)
@@ -141,8 +148,8 @@ def test_append_kv_pages_quantizes_at_write():
     page, Hkv, D = 4, 2, 8
     kp = jnp.zeros((5, Hkv, page, D), jnp.int8)
     vp = jnp.zeros((5, Hkv, page, D), jnp.int8)
-    ksc = jnp.zeros((5, Hkv, page))
-    vsc = jnp.zeros((5, Hkv, page))
+    ksc = jnp.zeros((5, Hkv, 1, page))
+    vsc = jnp.zeros((5, Hkv, 1, page))
     tbl = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     lens = jnp.asarray([3, 4], jnp.int32)
     k_new = jax.random.normal(KEY, (2, Hkv, D))
@@ -152,11 +159,13 @@ def test_append_kv_pages_quantizes_at_write():
     assert nk.dtype == jnp.int8
     # Slot 0 landed at page 1 offset 3; slot 1 at page 4 offset 0.
     for slot, (pg, off) in enumerate([(1, 3), (4, 0)]):
-        deq = dequantize_vec(nk[pg, :, off], nks[pg, :, off], jnp.float32)
+        deq = dequantize_vec(nk[pg, :, off], nks[pg, :, 0, off],
+                             jnp.float32)
         np.testing.assert_allclose(np.asarray(deq),
                                    np.asarray(k_new[slot]),
                                    rtol=0, atol=2e-2)
-        deq_v = dequantize_vec(nv[pg, :, off], nvs[pg, :, off], jnp.float32)
+        deq_v = dequantize_vec(nv[pg, :, off], nvs[pg, :, 0, off],
+                               jnp.float32)
         np.testing.assert_allclose(np.asarray(deq_v),
                                    np.asarray(v_new[slot]),
                                    rtol=0, atol=4e-2)
@@ -167,8 +176,8 @@ def test_append_chunk_kv_pages_quantizes_at_write():
     page, Hkv, D, S = 4, 2, 8, 5
     kp = jnp.zeros((6, Hkv, page, D), jnp.int8)
     vp = jnp.zeros((6, Hkv, page, D), jnp.int8)
-    ksc = jnp.zeros((6, Hkv, page))
-    vsc = jnp.zeros((6, Hkv, page))
+    ksc = jnp.zeros((6, Hkv, 1, page))
+    vsc = jnp.zeros((6, Hkv, 1, page))
     tbl = jnp.asarray([[1, 2, 3]], jnp.int32)
     start = jnp.asarray([3], jnp.int32)
     k_new = jax.random.normal(KEY, (1, S, Hkv, D))
@@ -176,7 +185,8 @@ def test_append_chunk_kv_pages_quantizes_at_write():
         kp, vp, tbl, start, k_new, 0.5 * k_new, ksc, vsc)
     # Tokens land at positions 3..7 -> page 1 off 3, page 2 off 0..3.
     for i, (pg, off) in enumerate([(1, 3), (2, 0), (2, 1), (2, 2), (2, 3)]):
-        deq = dequantize_vec(nk[pg, :, off], nks[pg, :, off], jnp.float32)
+        deq = dequantize_vec(nk[pg, :, off], nks[pg, :, 0, off],
+                             jnp.float32)
         np.testing.assert_allclose(np.asarray(deq),
                                    np.asarray(k_new[0, i]),
                                    rtol=0, atol=2e-2, err_msg=f"token {i}")
@@ -433,6 +443,7 @@ def test_bf16_scale_ref_equals_fp_ref_on_bf16_roundtrip():
     q = jax.random.normal(ks[2], (B, H, D), jnp.float32)
     kq, ksc = quantize_vec(kp, scale_dtype=jnp.bfloat16)
     vq, vsc = quantize_vec(vp, scale_dtype=jnp.bfloat16)
+    ksc, vsc = _rows(ksc), _rows(vsc)
     lens = jnp.asarray([9, 26], jnp.int32)
     got = ref_k.paged_attention_ref(q, kq, vq, tbl, lens, ksc, vsc)
     want = ref_k.paged_attention_ref(
@@ -449,6 +460,7 @@ def test_bf16_scale_kernels_match_ref(entry):
         B=2, H=4, Hkv=2, D=128, page=16, npg=2, lengths=[13, 32])
     kq, ksc = quantize_vec(kp, scale_dtype=jnp.bfloat16)
     vq, vsc = quantize_vec(vp, scale_dtype=jnp.bfloat16)
+    ksc, vsc = _rows(ksc), _rows(vsc)
     if entry == "decode":
         want = ops.pim_paged_attention(q, kq, vq, tbl, lens, ksc, vsc,
                                        impl="reference")
@@ -499,14 +511,14 @@ def test_init_paged_cache_bf16_scale_pools_and_appends():
         cache.k_scale[0], cache.v_scale[0])
     assert ksc.dtype == jnp.bfloat16
     _, want_sc = quantize_vec(k_new, scale_dtype=jnp.bfloat16)
-    np.testing.assert_array_equal(np.asarray(ksc[1, :, 0]),
+    np.testing.assert_array_equal(np.asarray(ksc[1, :, 0, 0]),
                                   np.asarray(want_sc[0]))
     kp2, vp2, ksc2, _ = kv.append_chunk_kv_pages(
         cache.k_pages[0], cache.v_pages[0], tables,
         jnp.zeros((1,), jnp.int32), k_new[:, None], k_new[:, None],
         cache.k_scale[0], cache.v_scale[0])
     assert ksc2.dtype == jnp.bfloat16
-    np.testing.assert_array_equal(np.asarray(ksc2[1, :, 0]),
+    np.testing.assert_array_equal(np.asarray(ksc2[1, :, 0, 0]),
                                   np.asarray(want_sc[0]))
 
 

@@ -15,6 +15,7 @@ from repro.serving.engine import GenConfig, ServingEngine
 from repro.serving.kvcache import TRASH_PAGE, BlockAllocator
 from repro.serving.speculative import (DraftModelDrafter, NgramDrafter,
                                        SpecConfig, greedy_accept)
+from tolerances import f32_reorder_tol
 
 ENGINE = SalPimEngine.create(SalPimConfig())
 KEY = jax.random.PRNGKey(0)
@@ -457,16 +458,21 @@ def test_verify_logits_match_sequential_decode(arch):
         params, chunk, pc.block_tables, jnp.full((B,), 6, jnp.int32),
         pc.k_pages, pc.v_pages, cfg, ENGINE)
     vlog = np.asarray(vlog)
+    # The 3-row verify pass and the 1-row decode steps sum their f32
+    # contractions in shape-dependent orders (tests/tolerances.py).
+    tol = f32_reorder_tol(cfg)
     for j in range(3):
         np.testing.assert_allclose(vlog[:, j], seq_logits[j],
-                                   rtol=1e-4, atol=1e-5, err_msg=f"j={j}")
+                                   rtol=tol, atol=tol, err_msg=f"j={j}")
         np.testing.assert_array_equal(vlog[:, j].argmax(-1),
                                       seq_logits[j].argmax(-1))
-    # And the KV the verify pass wrote equals the decode-written KV.
+    # And the KV the verify pass wrote equals the decode-written KV, to
+    # the same bound: at 1e-6 one element missed by 1.25e-6 (qwen2 and
+    # gpt2 smoke, CPU, xdist -n 2).
     np.testing.assert_allclose(np.asarray(vk), np.asarray(pca.k_pages),
-                               rtol=1e-6, atol=1e-6)
+                               rtol=tol, atol=tol)
     np.testing.assert_allclose(np.asarray(vv), np.asarray(pca.v_pages),
-                               rtol=1e-6, atol=1e-6)
+                               rtol=tol, atol=tol)
 
 
 def test_spec_per_request_counters(spec_env):

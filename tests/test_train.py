@@ -10,6 +10,7 @@ import pytest
 from repro.configs import get_config
 from repro.core.salpim import SalPimConfig, SalPimEngine
 from repro.data import tokens as data_lib
+from repro.kernels import ops
 from repro.models import api
 from repro.runtime import optimizer as opt
 from repro.runtime.train_loop import StragglerWatch, make_train_step
@@ -86,6 +87,25 @@ def test_loss_decreases_on_tiny_model():
         params, state, metrics = step(params, state, batch)
         losses.append(float(metrics["loss"]))
     assert losses[-1] < losses[0] - 0.5, (losses[0], losses[-1])
+
+
+def test_train_step_differentiates_kernel_engine(monkeypatch):
+    """A default-config engine on a TPU resolves to the Pallas kernels,
+    which have no gradient rule; the train step must still run, on the
+    jnp path. Interpret mode stands in for the TPU backend here."""
+    monkeypatch.setattr(ops, "default_impl", lambda: "interpret")
+    engine = SalPimEngine.create(SalPimConfig())
+    assert engine.config.impl == "interpret"
+    cfg = get_config("gpt2_medium", smoke=True)
+    params = api.init_params(jax.random.PRNGKey(0), cfg)
+    ocfg = opt.AdamWConfig()
+    batch = data_lib.batch_at(data_lib.DataConfig(
+        vocab=cfg.vocab, seq_len=16, global_batch=2), 0)
+    state = opt.init_opt_state(params)
+    got = jax.jit(make_train_step(cfg, engine, ocfg))(params, state, batch)
+    want = jax.jit(make_train_step(cfg, ENGINE, ocfg))(params, state, batch)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_straggler_watch_flags_outlier():
