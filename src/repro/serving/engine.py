@@ -456,21 +456,24 @@ class ServingEngine:
             self.params = jax.device_put(params, replicated)
             self.last_logits = jax.device_put(self.last_logits, replicated)
 
+        # The jits wrap named functions so device traces name their
+        # programs (`jit_decode_step`, `jit_prefill_chunk`,
+        # `jit_verify_tokens`, `jit_dense_admit`), not `jit__lambda`.
         # The cache is donated: decode and chunk-prefill steps update the
         # KV arena / page pools in place instead of copying the whole
         # buffer every step (the engine never touches the stale pytree —
         # it rebinds self.cache from each call's result).
-        self._decode = jax.jit(
-            lambda p, tok, cache: model_api.decode_step(
-                p, tok, cache, model_cfg, engine),
-            donate_argnums=(2,))
+        def decode_step(p, tok, cache):
+            return model_api.decode_step(p, tok, cache, model_cfg, engine)
+
+        self._decode = jax.jit(decode_step, donate_argnums=(2,))
 
         # Per-slot dense admission (batch-of-1 prefill + slot scatter) —
         # compiled once per prompt length. The engine-wide cache and
         # last_logits are donated, so admitting a request updates the
         # dense arena in place like the paged decode/chunk jits instead
         # of copying every slot's KV to write one slot's rows.
-        def _dense_admit_fn(p, toks, slot, cache, last_logits):
+        def dense_admit(p, toks, slot, cache, last_logits):
             logits1, cache1 = model_api.prefill(
                 p, {"tokens": toks}, model_cfg, engine, max_len=max_len)
 
@@ -485,20 +488,25 @@ class ServingEngine:
                                  is_leaf=lambda x: x is None)
             return cache, last_logits.at[slot].set(logits1[0])
 
-        self._dense_admit = jax.jit(_dense_admit_fn, donate_argnums=(3, 4))
+        self._dense_admit = jax.jit(dense_admit, donate_argnums=(3, 4))
+
         # Paged prefill chunk: writes K/V straight into pool pages (and,
         # in int8 mode, their scale rows — donated alongside).
-        self._prefill_chunk = jax.jit(
-            lambda p, toks, bt, st, kp, vp, ksc, vsc: model_api.prefill_chunk(
-                p, toks, bt, st, kp, vp, model_cfg, engine, ksc, vsc),
-            donate_argnums=(4, 5, 6, 7))
+        def prefill_chunk(p, toks, bt, st, kp, vp, ksc, vsc):
+            return model_api.prefill_chunk(p, toks, bt, st, kp, vp,
+                                           model_cfg, engine, ksc, vsc)
+
+        self._prefill_chunk = jax.jit(prefill_chunk,
+                                      donate_argnums=(4, 5, 6, 7))
+
         # Speculative verify pass: score each slot's k+1 candidate
         # tokens in one prefill-chunk-shaped forward returning logits at
         # every position; pools donated exactly like _prefill_chunk.
-        self._verify = jax.jit(
-            lambda p, toks, bt, st, kp, vp, ksc, vsc: model_api.verify_tokens(
-                p, toks, bt, st, kp, vp, model_cfg, engine, ksc, vsc),
-            donate_argnums=(4, 5, 6, 7))
+        def verify_tokens(p, toks, bt, st, kp, vp, ksc, vsc):
+            return model_api.verify_tokens(p, toks, bt, st, kp, vp,
+                                           model_cfg, engine, ksc, vsc)
+
+        self._verify = jax.jit(verify_tokens, donate_argnums=(4, 5, 6, 7))
         if self.mesh is not None:
             # Trace-time mesh activation: the attention layer keys its
             # shard_map dispatch off distributed.api.current_mesh(), so
@@ -801,60 +809,67 @@ class ServingEngine:
         # precisely the registered content later sharers mapped.
         borrowed = req.shared_prompt_tokens // ps
         fork_range = range(start // ps, min((end - 1) // ps + 1, borrowed))
-        if self.scheduler.preemptive:
-            # Optimistic admission reserves nothing ahead: make sure the
-            # free list covers this chunk's COW forks before issuing
-            # them, preempting victims (never this slot) if dry.
-            forks = sum(
-                1 for logical in fork_range
-                if self.allocator.refcount(
-                    self.allocator.pages_of(req.uid)[logical]) > 1)
-            if forks > self.allocator.free_pages:
-                self.allocator.reclaim_pinned(
-                    forks - self.allocator.free_pages)
-            while forks > self.allocator.free_pages:
-                victim = self.scheduler.pick_victim(
-                    self, None, protect=frozenset((slot,)))
-                if victim is None:
-                    return   # retry next step
-                self._preempt(victim)
-        for logical in fork_range:
-            page = self.allocator.pages_of(req.uid)[logical]
-            if self.allocator.refcount(page) > 1:
-                old, new = self.allocator.fork_page(req.uid, logical)
-                self.cache = self._kv.copy_page(self.cache, old, new)
-        pages = self.allocator.pages_of(req.uid)
-        row = np.full((self.cache.block_tables.shape[1],), kv.TRASH_PAGE,
-                      np.int32)
-        row[:len(pages)] = pages
         tel = self.telemetry
+        with tel.annotation("prefill_prep"):
+            if self.scheduler.preemptive:
+                # Optimistic admission reserves nothing ahead: make sure
+                # the free list covers this chunk's COW forks before
+                # issuing them, preempting victims (never this slot) if
+                # dry.
+                forks = sum(
+                    1 for logical in fork_range
+                    if self.allocator.refcount(
+                        self.allocator.pages_of(req.uid)[logical]) > 1)
+                if forks > self.allocator.free_pages:
+                    self.allocator.reclaim_pinned(
+                        forks - self.allocator.free_pages)
+                while forks > self.allocator.free_pages:
+                    victim = self.scheduler.pick_victim(
+                        self, None, protect=frozenset((slot,)))
+                    if victim is None:
+                        return   # retry next step
+                    self._preempt(victim)
+            for logical in fork_range:
+                page = self.allocator.pages_of(req.uid)[logical]
+                if self.allocator.refcount(page) > 1:
+                    old, new = self.allocator.fork_page(req.uid, logical)
+                    self.cache = self._kv.copy_page(self.cache, old, new)
+            pages = self.allocator.pages_of(req.uid)
+            row = np.full((self.cache.block_tables.shape[1],), kv.TRASH_PAGE,
+                          np.int32)
+            row[:len(pages)] = pages
+            toks = jnp.asarray(req.prompt[start:end])[None]
+            rows = jnp.asarray(row)[None]
+            starts = jnp.asarray([start], jnp.int32)
         t0c = tel.now() if tel.enabled else 0.0
         with tel.annotation("prefill_chunk"):
             res = self._prefill_chunk(
-                self.params, jnp.asarray(req.prompt[start:end])[None],
-                jnp.asarray(row)[None], jnp.asarray([start], jnp.int32),
+                self.params, toks, rows, starts,
                 self.cache.k_pages, self.cache.v_pages,
                 self.cache.k_scale, self.cache.v_scale)
-        if self.cache.quantized:
-            logits1, nk, nv, nks, nvs = res
-        else:
-            (logits1, nk, nv), nks, nvs = res, None, None
-        lengths, tables = self.cache.lengths, self.cache.block_tables
-        req.prefill_cursor = end
-        self.prefill_tokens += end - start
-        if not req.prefilling:
-            # Activate: only now does the slot become visible to the
-            # shared decode program (row + device length + first logits).
-            lengths = lengths.at[slot].set(end)
-            tables = tables.at[slot].set(jnp.asarray(row))
-            self.last_logits = self.last_logits.at[slot].set(logits1[0])
-            self._host_len[slot] = end
-        self.cache = self._kv.PagedCache(lengths, tables, nk, nv, nks, nvs)
-        self.peak_pages = max(self.peak_pages, self.allocator.used_pages)
-        if self._shape is not None:
-            self._shape.chunk = (start, end - start)
-        if tel.enabled:
-            tel.chunk(req.uid, t0c, tel.now(), end - start)
+        with tel.annotation("prefill_commit"):
+            if self.cache.quantized:
+                logits1, nk, nv, nks, nvs = res
+            else:
+                (logits1, nk, nv), nks, nvs = res, None, None
+            lengths, tables = self.cache.lengths, self.cache.block_tables
+            req.prefill_cursor = end
+            self.prefill_tokens += end - start
+            if not req.prefilling:
+                # Activate: only now does the slot become visible to the
+                # shared decode program (row + device length + first
+                # logits).
+                lengths = lengths.at[slot].set(end)
+                tables = tables.at[slot].set(jnp.asarray(row))
+                self.last_logits = self.last_logits.at[slot].set(logits1[0])
+                self._host_len[slot] = end
+            self.cache = self._kv.PagedCache(lengths, tables, nk, nv, nks,
+                                             nvs)
+            self.peak_pages = max(self.peak_pages, self.allocator.used_pages)
+            if self._shape is not None:
+                self._shape.chunk = (start, end - start)
+            if tel.enabled:
+                tel.chunk(req.uid, t0c, tel.now(), end - start)
 
     def _release(self, slot: int, req: Request):
         req.done = True
@@ -902,7 +917,8 @@ class ServingEngine:
         Phase wall time (admit / chunk prefill / draft / verify /
         decode) accumulates into stats(); with telemetry enabled each
         step additionally records its phase split and the pool/queue
-        gauges at the step boundary."""
+        gauges at the step boundary, and with `annotate` each phase is
+        a profiler span (docs/observability.md)."""
         tel = self.telemetry
         t_start = time.perf_counter()
         self._step_idx += 1
@@ -945,7 +961,8 @@ class ServingEngine:
     def _step_inner(self) -> int:
         tel = self.telemetry
         t = time.perf_counter()
-        self.scheduler.schedule_admissions(self)
+        with tel.annotation("admit"):
+            self.scheduler.schedule_admissions(self)
         self._admit_sec += time.perf_counter() - t
         if self.paged:
             t = time.perf_counter()
@@ -954,7 +971,8 @@ class ServingEngine:
             if self.scheduler.preemptive:
                 # Optimistic admission: the pool must cover this round's
                 # page extends/forks before sampling (may preempt).
-                self._ensure_decode_capacity()
+                with tel.annotation("admit"):
+                    self._ensure_decode_capacity()
         n_prefilling = sum(1 for r in self.active
                            if r is not None and r.prefilling)
         ready = [i for i, r in enumerate(self.active)
@@ -965,22 +983,26 @@ class ServingEngine:
         if self.spec is not None:
             return self._spec_round(ready) + n_prefilling + parked
         t_dec = time.perf_counter()
-        self._key, step_key = jax.random.split(self._key)
-        toks = sample(self.last_logits, step_key,
-                      temperature=self.gen.temperature, top_k=self.gen.top_k)
-        mask = np.zeros((self.slots,), bool)
-        host_toks = np.asarray(toks)
-        t_emit = tel.now() if tel.enabled else 0.0
-        for i in ready:
-            req = self.active[i]
-            req.generated.append(int(host_toks[i]))
-            tel.tokens(req.uid, t_emit)
-            if (len(req.generated) >= req.max_new_tokens
-                    or (self.gen.stop_on_eos
-                        and host_toks[i] == self.gen.eos_id)):
-                self._release(i, req)
-            else:
-                mask[i] = True
+        with tel.annotation("sample"):
+            self._key, step_key = jax.random.split(self._key)
+            toks = sample(self.last_logits, step_key,
+                          temperature=self.gen.temperature,
+                          top_k=self.gen.top_k)
+        with tel.annotation("token_sync"):
+            host_toks = np.asarray(toks)
+        with tel.annotation("emit"):
+            mask = np.zeros((self.slots,), bool)
+            t_emit = tel.now() if tel.enabled else 0.0
+            for i in ready:
+                req = self.active[i]
+                req.generated.append(int(host_toks[i]))
+                tel.tokens(req.uid, t_emit)
+                if (len(req.generated) >= req.max_new_tokens
+                        or (self.gen.stop_on_eos
+                            and host_toks[i] == self.gen.eos_id)):
+                    self._release(i, req)
+                else:
+                    mask[i] = True
         if self.paged:
             # Decode-step boundary: map a fresh page wherever the next
             # write position falls off the end of a slot's mapped pages
@@ -989,13 +1011,14 @@ class ServingEngine:
             # so the append cannot leak into other sequences. Mid-prefill
             # slots are skipped — their device length is 0, so the decode
             # append lands in the trash page.
-            for i in range(self.slots):
-                req = self.active[i]
-                if req is None or req.prefilling:
-                    continue
-                self._map_write_range(i, req, int(self._host_len[i]), 1)
-            self.peak_pages = max(self.peak_pages,
-                                  self.allocator.used_pages)
+            with tel.annotation("page_map"):
+                for i in range(self.slots):
+                    req = self.active[i]
+                    if req is None or req.prefilling:
+                        continue
+                    self._map_write_range(i, req, int(self._host_len[i]), 1)
+                self.peak_pages = max(self.peak_pages,
+                                      self.allocator.used_pages)
         with tel.annotation("decode_step"):
             self.last_logits, self.cache = self._decode(
                 self.params, toks, self.cache)
@@ -1041,34 +1064,36 @@ class ServingEngine:
         # Greedy t0 per ready slot (speculative mode is greedy-only, so
         # no PRNG key is consumed — matching the spec-off greedy path,
         # where sample() ignores its key at temperature 0).
-        host_logits = np.asarray(self.last_logits)
+        with tel.annotation("token_sync"):
+            host_logits = np.asarray(self.last_logits)
         survivors: list[tuple[int, Request, int, np.ndarray]] = []
-        for i in ready:
-            req = self.active[i]
-            t0 = int(np.argmax(host_logits[i]))
-            req.generated.append(t0)
-            if (len(req.generated) >= req.max_new_tokens
-                    or (self.gen.stop_on_eos and t0 == self.gen.eos_id)):
-                tel.tokens(req.uid, t_round0)
-                self._release(i, req)
-                continue
-            # KV positions this request may still occupy are bounded by
-            # the watermark reservation (prompt + max_new - 1): with G
-            # tokens generated and KV resident through position L-1
-            # (L = prompt + G - 1 before t0's write), at most
-            # max_new - G - 1 draft writes fit after t0's. Slots out of
-            # draft room still verify — a 1-token verify row is exactly
-            # a decode step run through the verify program.
-            room = req.max_new_tokens - len(req.generated) - 1
-            k_i = min(k, room)
-            context = np.concatenate(
-                [req.prompt, np.asarray(req.generated, np.int64)])
-            drafts = (np.asarray(self.drafter.propose(i, context, k_i))
-                      if k_i > 0 else np.zeros((0,), np.int64))
-            drafts = drafts[:k_i]
-            req.proposed += len(drafts)
-            self.spec_proposed += len(drafts)
-            survivors.append((i, req, t0, drafts))
+        with tel.annotation("draft"):
+            for i in ready:
+                req = self.active[i]
+                t0 = int(np.argmax(host_logits[i]))
+                req.generated.append(t0)
+                if (len(req.generated) >= req.max_new_tokens
+                        or (self.gen.stop_on_eos and t0 == self.gen.eos_id)):
+                    tel.tokens(req.uid, t_round0)
+                    self._release(i, req)
+                    continue
+                # KV positions this request may still occupy are bounded by
+                # the watermark reservation (prompt + max_new - 1): with G
+                # tokens generated and KV resident through position L-1
+                # (L = prompt + G - 1 before t0's write), at most
+                # max_new - G - 1 draft writes fit after t0's. Slots out of
+                # draft room still verify — a 1-token verify row is exactly
+                # a decode step run through the verify program.
+                room = req.max_new_tokens - len(req.generated) - 1
+                k_i = min(k, room)
+                context = np.concatenate(
+                    [req.prompt, np.asarray(req.generated, np.int64)])
+                drafts = (np.asarray(self.drafter.propose(i, context, k_i))
+                          if k_i > 0 else np.zeros((0,), np.int64))
+                drafts = drafts[:k_i]
+                req.proposed += len(drafts)
+                self.spec_proposed += len(drafts)
+                survivors.append((i, req, t0, drafts))
         # Drafting is host-side work (argmaxes + drafter.propose); its
         # cost must not be charged to the decode/verify phase.
         self._draft_sec += time.perf_counter() - t_draft0
@@ -1079,26 +1104,27 @@ class ServingEngine:
         # (empty, mid-prefill, or just released) keep all-trash block
         # table rows, so their padded rows scribble into the trash page
         # and their logits are ignored.
-        tokens = np.zeros((self.slots, k + 1), np.int32)
-        starts = np.zeros((self.slots,), np.int32)
-        for i, req, t0, drafts in survivors:
-            L = int(self._host_len[i])
-            tokens[i, 0] = t0
-            tokens[i, 1:1 + len(drafts)] = drafts
-            starts[i] = L
-            if self._shape is not None:
-                self._shape.verify.append((L, 1 + len(drafts)))
-                if self.spec.mode == "draft-model":
-                    # One draft forward per proposed token (the k-th
-                    # draft is free; catch-up forwards roughly cover
-                    # it — the weight stream is the dominant term).
-                    self._shape.draft_forwards += len(drafts)
-            # Map pages for every candidate write (t0 + drafts); padded
-            # positions past the drafts either land in the tail of an
-            # already-mapped page (dead data past the rewind length) or
-            # fall off mapped pages into the trash page.
-            self._map_write_range(i, req, L, 1 + len(drafts))
-        self.peak_pages = max(self.peak_pages, self.allocator.used_pages)
+        with tel.annotation("page_map"):
+            tokens = np.zeros((self.slots, k + 1), np.int32)
+            starts = np.zeros((self.slots,), np.int32)
+            for i, req, t0, drafts in survivors:
+                L = int(self._host_len[i])
+                tokens[i, 0] = t0
+                tokens[i, 1:1 + len(drafts)] = drafts
+                starts[i] = L
+                if self._shape is not None:
+                    self._shape.verify.append((L, 1 + len(drafts)))
+                    if self.spec.mode == "draft-model":
+                        # One draft forward per proposed token (the k-th
+                        # draft is free; catch-up forwards roughly cover
+                        # it — the weight stream is the dominant term).
+                        self._shape.draft_forwards += len(drafts)
+                # Map pages for every candidate write (t0 + drafts); padded
+                # positions past the drafts either land in the tail of an
+                # already-mapped page (dead data past the rewind length) or
+                # fall off mapped pages into the trash page.
+                self._map_write_range(i, req, L, 1 + len(drafts))
+            self.peak_pages = max(self.peak_pages, self.allocator.used_pages)
         with tel.annotation("verify_tokens"):
             res = self._verify(
                 self.params, jnp.asarray(tokens), self.cache.block_tables,
@@ -1114,43 +1140,45 @@ class ServingEngine:
         self.spec_rounds += len(survivors)
         # Acceptance needs only the argmaxes: reduce on device and move
         # a (slots, k+1) int array to host instead of the full logits.
-        greedy = np.asarray(jnp.argmax(vlogits, axis=-1))
-        live = 0
-        t_acc = tel.now() if tel.enabled else 0.0
-        updates: list[tuple[int, int]] = []          # (slot, accepted)
-        for i, req, t0, drafts in survivors:
-            a, hit_eos = greedy_accept(
-                drafts, greedy[i], eos_id=self.gen.eos_id,
-                stop_on_eos=self.gen.stop_on_eos)
-            for tok in drafts[:a]:
-                req.generated.append(int(tok))
-            req.accepted += a
-            self.spec_accepted += a
-            if tel.enabled:
-                # 1 + a tokens commit together — a genuine burst, so the
-                # intra-round inter-token deltas are recorded as zeros.
-                tel.tokens(req.uid, t_acc, 1 + a)
-                tel.spec_round(req.uid, t_round0, t_acc, len(drafts), a)
-            new_len = int(starts[i]) + 1 + a
-            if hit_eos:
-                self._release(i, req)
-                continue
-            # In-pool rollback of the rejected tail: host/device lengths
-            # rewind to the accepted frontier and tail pages that are
-            # now empty return to the free list + reservation.
-            self.allocator.rewind(req.uid, new_len)
-            keep = len(self.allocator.pages_of(req.uid))
-            self.cache = self._kv.rewind_slot(self.cache, i, new_len, keep)
-            self._host_len[i] = new_len
-            updates.append((i, a))
-            live += 1
-        if updates:
-            # One scatter: each live slot's next-round logits are the
-            # verify logits after its last accepted token.
-            rows = jnp.asarray([i for i, _ in updates])
-            cols = jnp.asarray([a for _, a in updates])
-            self.last_logits = self.last_logits.at[rows].set(
-                vlogits[rows, cols])
+        with tel.annotation("token_sync"):
+            greedy = np.asarray(jnp.argmax(vlogits, axis=-1))
+        with tel.annotation("emit"):
+            live = 0
+            t_acc = tel.now() if tel.enabled else 0.0
+            updates: list[tuple[int, int]] = []          # (slot, accepted)
+            for i, req, t0, drafts in survivors:
+                a, hit_eos = greedy_accept(
+                    drafts, greedy[i], eos_id=self.gen.eos_id,
+                    stop_on_eos=self.gen.stop_on_eos)
+                for tok in drafts[:a]:
+                    req.generated.append(int(tok))
+                req.accepted += a
+                self.spec_accepted += a
+                if tel.enabled:
+                    # 1 + a tokens commit together — a genuine burst, so the
+                    # intra-round inter-token deltas are recorded as zeros.
+                    tel.tokens(req.uid, t_acc, 1 + a)
+                    tel.spec_round(req.uid, t_round0, t_acc, len(drafts), a)
+                new_len = int(starts[i]) + 1 + a
+                if hit_eos:
+                    self._release(i, req)
+                    continue
+                # In-pool rollback of the rejected tail: host/device lengths
+                # rewind to the accepted frontier and tail pages that are
+                # now empty return to the free list + reservation.
+                self.allocator.rewind(req.uid, new_len)
+                keep = len(self.allocator.pages_of(req.uid))
+                self.cache = self._kv.rewind_slot(self.cache, i, new_len, keep)
+                self._host_len[i] = new_len
+                updates.append((i, a))
+                live += 1
+            if updates:
+                # One scatter: each live slot's next-round logits are the
+                # verify logits after its last accepted token.
+                rows = jnp.asarray([i for i, _ in updates])
+                cols = jnp.asarray([a for _, a in updates])
+                self.last_logits = self.last_logits.at[rows].set(
+                    vlogits[rows, cols])
         self._verify_sec += time.perf_counter() - t_ver0
         return live
 

@@ -37,9 +37,12 @@ layer, three pieces behind one `Telemetry` façade:
     telemetry on or off and serving outputs are bit-identical.
 
 With `annotate=True` (requires `enabled=True`) the engine additionally
-wraps its donated jitted steps in `jax.profiler.TraceAnnotation` /
-`StepTraceAnnotation` scopes, so a device trace captured with
-`jax.profiler.trace()` lines up with the engine phases recorded here.
+wraps each step in a `jax.profiler.StepTraceAnnotation` and each phase
+of it (admission, chunk set-up, the jitted calls, sampling, the token
+sync, releasing tokens, page mapping) in a `TraceAnnotation`, so a
+device trace captured with `jax.profiler.trace()` names the host phase
+behind every device gap; the step span's `tel_t` maps this module's
+clock onto the trace's (docs/observability.md).
 
 `snapshot()` / `reset()` give long-running servers a windowed view:
 snapshot returns everything observed since the last reset; reset zeroes
@@ -474,8 +477,8 @@ class Telemetry:
 
     # -- jax.profiler integration -------------------------------------------
     def annotation(self, name: str):
-        """Device-trace scope for one jitted call (no-op unless
-        annotate=True)."""
+        """Profiler span for one phase of an engine step (no-op unless
+        annotate=True). docs/observability.md lists the spans."""
         if not self.annotate:
             return _NULL_CTX
         import jax.profiler
@@ -483,12 +486,16 @@ class Telemetry:
 
     def step_annotation(self, step_num: int):
         """StepTraceAnnotation for a whole engine step, so device traces
-        group kernels under the same step numbers as `self.steps`."""
+        group kernels under the same step numbers as `self.steps`. It
+        carries `tel_t`, this clock's reading at the step's entry: the
+        span's start minus `tel_t` is the offset from this clock to the
+        profiler's, which puts request stamps and step records on the
+        device trace's timeline."""
         if not self.annotate:
             return _NULL_CTX
         import jax.profiler
-        return jax.profiler.StepTraceAnnotation("serve_step",
-                                                step_num=step_num)
+        return jax.profiler.StepTraceAnnotation(
+            "serve_step", step_num=step_num, tel_t=self.now())
 
     # -- windowed views -------------------------------------------------------
     def snapshot(self) -> dict:

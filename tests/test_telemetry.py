@@ -333,3 +333,112 @@ def test_snapshot_golden_keys():
     # Roofline achieved-bandwidth gauges land in the registry too.
     assert any(k.startswith("roofline.") and k.endswith(".achieved_gbps")
                for k in snap["gauges"])
+
+
+# -- phase spans on the profiler clock ---------------------------------------
+
+# The plain step's phases in step order (docs/observability.md).
+PHASES = ("admit", "prefill_prep", "prefill_chunk", "prefill_commit",
+          "sample", "token_sync", "emit", "page_map", "decode_step")
+
+
+def _phase_engine(telemetry):
+    cfg, params = _setup()
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=3, max_len=32, gen=GenConfig(temperature=0.0,
+                                           stop_on_eos=False),
+        paged=True, page_size=8, prefill_chunk_tokens=8,
+        telemetry=telemetry))
+    return cfg, eng
+
+
+def _phase_prompts(cfg, seed):
+    rng = np.random.RandomState(seed)
+    return [(rng.randint(2, cfg.vocab, size=8), 4) for _ in range(3)]
+
+
+def _host_spans(logdir):
+    """(name, start_s, dur_s, stats) of the Python thread's events, on
+    the profiler's clock."""
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            if line.name.startswith("python"):
+                spans += [(e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9,
+                           dict(e.stats)) for e in line.events]
+    return sorted(spans, key=lambda s: s[1])
+
+
+@pytest.fixture(scope="module")
+def profiled_steps(tmp_path_factory):
+    """Three profiled engine steps, each running one 8-token chunk (one
+    per admitted request) and a decode step; a drain of the same shapes
+    compiles everything first, so the trace holds no compilation."""
+    cfg, eng = _phase_engine(Telemetry(enabled=True, annotate=True))
+    _drain(eng, _phase_prompts(cfg, 0))
+    for p, n in _phase_prompts(cfg, 1):
+        eng.submit(p, max_new_tokens=n)
+    logdir = str(tmp_path_factory.mktemp("profile"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(logdir, profiler_options=opts)
+    try:
+        for _ in range(3):
+            eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    return _host_spans(logdir)
+
+
+def _steps(spans):
+    return [s for s in spans if s[0] == "serve_step"]
+
+
+def test_phase_spans_in_order_without_overlap(profiled_steps):
+    steps = _steps(profiled_steps)
+    assert len(steps) == 3
+    for _, t, d, _ in steps:
+        inner = [s for s in profiled_steps
+                 if s[0] in PHASES and t <= s[1] <= t + d]
+        assert tuple(s[0] for s in inner) == PHASES
+        for (_, a, da, _), (_, b, _, _) in zip(inner, inner[1:]):
+            assert a + da <= b                       # siblings, in order
+        assert inner[-1][1] + inner[-1][2] <= t + d  # inside serve_step
+
+
+def test_step_span_anchors_the_telemetry_clock(profiled_steps):
+    offsets = [t - stats["tel_t"]
+               for _, t, _, stats in _steps(profiled_steps)]
+    assert len(offsets) == 3
+    assert max(offsets) - min(offsets) < 1e-3
+
+
+def test_engine_programs_are_named(profiled_steps):
+    names = {s[0] for s in profiled_steps}
+    assert {"PjitFunction(decode_step)",
+            "PjitFunction(prefill_chunk)"} <= names
+
+
+def test_no_profiler_annotation_built_when_off(monkeypatch):
+    def refuse(*args, **kwargs):
+        raise AssertionError("profiler annotation built with annotate off")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", refuse)
+    for tel in (None, Telemetry(enabled=True)):
+        cfg, eng = _phase_engine(tel)
+        assert len(_drain(eng, _phase_prompts(cfg, 0))) == 3
+
+
+def test_greedy_outputs_identical_with_annotations():
+    outs = []
+    for annotate in (False, True):
+        cfg, eng = _phase_engine(Telemetry(enabled=True, annotate=annotate))
+        outs.append(_drain(eng, _phase_prompts(cfg, 2)))
+    assert outs[0] == outs[1]
