@@ -125,13 +125,18 @@ def pim_paged_attention(q, k_pages, v_pages, block_tables, length,
                         softcap=None, window=None, kv_splits=None,
                         impl: str) -> jax.Array:
     """Decode attention over a paged KV pool (see serving/kvcache.py).
-    int8/int4 pools pass their (P, Hkv, 1, page) scale rows as
-    k_scales/v_scales; the kernel dequantizes (int4: unpacks) in VMEM,
-    the oracle after the gather. `kv_splits` > 1 engages the KV-split
-    (flash-decode) path above KV_SPLIT_MIN_CONTEXT resident tokens:
-    per-split online-softmax partials merged by
-    `merge_partial_softmax_stacked` (same log-sum-exp math, so results
-    track the unsplit path to float-associativity tolerance)."""
+    The kernel runs one grid step per row and walks only that row's
+    live pages inside it: from the page of its first in-window position
+    through the page of position length - 1, a block of pages at a
+    time, each page one DMA of every KV head; table entries outside
+    that range are never read. int8/int4 pools pass their
+    (P, Hkv, 1, page) scale rows as k_scales/v_scales; the kernel
+    dequantizes (int4: unpacks) in VMEM, the oracle after the gather.
+    `kv_splits` > 1 engages the KV-split (flash-decode) path above
+    KV_SPLIT_MIN_CONTEXT resident tokens: per-split online-softmax
+    partials merged by `merge_partial_softmax_stacked` (same log-sum-exp
+    math, so results track the unsplit path to float-associativity
+    tolerance)."""
     if impl == "reference":
         splits = paged_k.effective_kv_splits(
             kv_splits, block_tables.shape[1], k_pages.shape[2])

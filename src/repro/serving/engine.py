@@ -16,6 +16,7 @@ Two drivers:
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import time
 from typing import Optional
@@ -26,6 +27,7 @@ import numpy as np
 
 from repro.core.salpim import SalPimEngine
 from repro.distributed import api as dist_api
+from repro.kernels import paged_attention as paged_k
 from repro.models import api as model_api
 from repro.serving import kvcache as kv
 from repro.models.config import ModelConfig
@@ -329,6 +331,8 @@ class ServingEngine:
         paged = config.paged
         self.params = params
         self.cfg = model_cfg
+        self._layer_windows = collections.Counter(
+            model_cfg.window_for_layer(i) for i in range(model_cfg.n_layers))
         # The KV-split autotune knob rides the SalPim engine config so
         # it reaches paged_decode_attention with zero model-layer
         # signature changes (the engine closes over it inside jit).
@@ -1019,6 +1023,17 @@ class ServingEngine:
                     self._map_write_range(i, req, int(self._host_len[i]), 1)
                 self.peak_pages = max(self.peak_pages,
                                       self.allocator.used_pages)
+            if tel.enabled:
+                # Pages the decode kernel's walk DMAs, over every layer
+                # and its window (idle and mid-prefill slots sit at
+                # length 0: one page), against the pages the tables hold.
+                lens, ps = self._host_len + 1, self.allocator.page_size
+                tel.count("decode.pages_walked", sum(
+                    n * paged_k.walk_pages(lens, w, ps)
+                    for w, n in self._layer_windows.items()))
+                tel.count("decode.pages_tabled",
+                          self.cfg.n_layers * self.slots
+                          * self.cache.block_tables.shape[1])
         with tel.annotation("decode_step"):
             self.last_logits, self.cache = self._decode(
                 self.params, toks, self.cache)

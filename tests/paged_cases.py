@@ -1,0 +1,45 @@
+"""Shared cases for the paged decode walk's tests: row lengths at every
+edge of its block walk, both benchmark cells' head shapes, and pools
+whose pages outside each row's walk hold NaN."""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+PAGE, N_PAGES = 16, 16
+# With 16-token pages the walk reads 8-page (128-position) blocks. One
+# batch holds an empty slot (cache length 0, so kernel length 1), a row
+# ending on a page boundary, one a position short of a full block, and
+# a full table.
+LENGTHS = [1, PAGE, 8 * PAGE - 1, N_PAGES * PAGE]
+SHAPES = {"mha16x64": (16, 16, 64), "gqa12:2x128": (12, 2, 128)}
+WINDOW = 40      # starts mid-page in every row longer than it
+
+
+def pools(H, Hkv, D, key=jax.random.PRNGKey(0)):
+    """(q, k_pages, v_pages, tables, lengths): f32 pools behind a
+    shuffled block table, one row per LENGTHS entry."""
+    B = len(LENGTHS)
+    P = 1 + B * N_PAGES
+    ks = jax.random.split(key, 3)
+    q = jax.random.normal(ks[0], (B, H, D), jnp.float32)
+    kp = jax.random.normal(ks[1], (P, Hkv, PAGE, D), jnp.float32)
+    vp = jax.random.normal(ks[2], (P, Hkv, PAGE, D), jnp.float32)
+    tables = np.random.RandomState(0).permutation(np.arange(1, P))
+    return (q, kp, vp, jnp.asarray(tables.reshape(B, N_PAGES), jnp.int32),
+            jnp.asarray(LENGTHS, jnp.int32))
+
+
+def poison(pool, tables, window=None):
+    """`pool` (pages first) with NaN in every page a row's table maps
+    outside the pages its walk reads: past the page holding its last
+    position, and before the page holding its first in-window one."""
+    out = np.array(pool)
+    for row, n in zip(np.asarray(tables), LENGTHS):
+        first = max(n - window, 0) // PAGE if window is not None else 0
+        last = -(-n // PAGE)
+        for i, page in enumerate(row):
+            if not first <= i < last:
+                out[page] = np.nan
+    return jnp.asarray(out)

@@ -16,6 +16,8 @@ from repro.models import api
 from repro.serving import kvcache as kv
 from repro.serving.engine import GenConfig, ServingEngine, generate
 
+import paged_cases
+
 ENGINE = SalPimEngine.create(SalPimConfig())
 KEY = jax.random.PRNGKey(0)
 
@@ -165,6 +167,71 @@ def test_unmapped_pages_are_masked():
     got = ref_k.paged_attention_ref(q, kp, vp, trashed, lens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The decode walk: each row's live pages, every head per block
+# ---------------------------------------------------------------------------
+
+def test_walk_block_fills_a_score_row():
+    from repro.kernels.paged_attention import walk_block_pages
+    assert walk_block_pages(n_pages=24, page_size=16) == 8
+    assert walk_block_pages(n_pages=544, page_size=16) == 8
+    assert walk_block_pages(n_pages=3, page_size=16) == 3      # capped
+    assert walk_block_pages(n_pages=9, page_size=128) == 1
+    assert walk_block_pages(n_pages=9, page_size=48) == 3
+
+
+@pytest.mark.parametrize("window", [None, paged_cases.WINDOW, 1])
+def test_walk_pages_counts_the_pages_it_reads(window):
+    """`walk_pages` (the engine's `decode.pages_walked`) equals the
+    table entries the poison case leaves readable: per row, the pages
+    from its first in-window position through position length - 1."""
+    from repro.kernels.paged_attention import walk_pages
+    n = len(paged_cases.LENGTHS)
+    tbl = np.arange(1, 1 + n * paged_cases.N_PAGES).reshape(n, -1)
+    pool = paged_cases.poison(np.zeros((1 + tbl.size, 1)), tbl, window)
+    readable = int(np.isfinite(np.asarray(pool)[tbl]).sum())
+    assert walk_pages(paged_cases.LENGTHS, window,
+                      paged_cases.PAGE) == readable
+    if window is None:
+        assert readable == sum(-(-n // paged_cases.PAGE)
+                               for n in paged_cases.LENGTHS)
+
+
+@pytest.mark.parametrize("shape", paged_cases.SHAPES)
+@pytest.mark.parametrize("mode", ["exact", "lut", "window"])
+def test_walk_matches_ref_at_block_edges(shape, mode):
+    """Empty slot, page boundary, one short of a block and a full table
+    in one batch, at both cells' head shapes; the window starts
+    mid-page."""
+    q, kp, vp, tbl, lens = paged_cases.pools(*paged_cases.SHAPES[shape])
+    kw = {"exact": {}, "lut": {"exp_table": L.LutBank.create(64).exp},
+          "window": {"window": paged_cases.WINDOW}}[mode]
+    want = ops.pim_paged_attention(q, kp, vp, tbl, lens, impl="reference",
+                                   **kw)
+    got = ops.pim_paged_attention(q, kp, vp, tbl, lens, impl="interpret",
+                                  **kw)
+    tol = 3e-3 if mode == "lut" else 1e-4
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("shape", paged_cases.SHAPES)
+@pytest.mark.parametrize("window", [None, paged_cases.WINDOW])
+def test_walk_reads_no_page_outside_it(shape, window):
+    """Poison: NaN in every page the table maps past a row's length or
+    before its window. A walk that read one would carry the NaN through
+    p.V (0 * NaN); the live-page walk never loads it."""
+    q, kp, vp, tbl, lens = paged_cases.pools(*paged_cases.SHAPES[shape])
+    want = ref_k.paged_attention_ref(q, kp, vp, tbl, lens, window=window)
+    got = ops.pim_paged_attention(
+        q, paged_cases.poison(kp, tbl, window),
+        paged_cases.poison(vp, tbl, window), tbl, lens, window=window,
+        impl="interpret")
+    assert bool(jnp.all(jnp.isfinite(got)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
 
 
 # ---------------------------------------------------------------------------

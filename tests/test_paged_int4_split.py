@@ -36,6 +36,8 @@ from repro.serving.engine import GenConfig, ServingEngine
 from repro.serving.quantize import (dequantize_vec_int4, pack_int4,
                                     quantize_vec_int4, unpack_int4)
 
+import paged_cases
+
 KEY = jax.random.PRNGKey(0)
 ENGINE = SalPimEngine.create()
 
@@ -111,6 +113,27 @@ def test_int4_decode_kernel_matches_ref(lengths):
     out = ops.pim_paged_attention(q, kq, vq, tbl, lens, ksc, vsc,
                                   impl="interpret")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=0, atol=1e-4)
+
+
+@pytest.mark.parametrize("shape", paged_cases.SHAPES)
+@pytest.mark.parametrize("window", [None, paged_cases.WINDOW])
+def test_int4_walk_at_block_edges_reads_no_page_outside_it(shape, window):
+    """The decode walk over nibble-packed pools with bf16 scale rows at
+    its block edges, with NaN in every scale row the table maps outside
+    a row's walk."""
+    q, kp, vp, tbl, lens = paged_cases.pools(*paged_cases.SHAPES[shape])
+    kq, ksc = quantize_vec_int4(kp, scale_dtype=jnp.bfloat16)
+    vq, vsc = quantize_vec_int4(vp, scale_dtype=jnp.bfloat16)
+    ksc, vsc = ksc[:, :, None], vsc[:, :, None]
+    want = ref_k.paged_attention_ref(q, kq, vq, tbl, lens, ksc, vsc,
+                                     window=window)
+    got = ops.pim_paged_attention(
+        q, kq, vq, tbl, lens, paged_cases.poison(ksc, tbl, window),
+        paged_cases.poison(vsc, tbl, window), window=window,
+        impl="interpret")
+    assert bool(jnp.all(jnp.isfinite(got)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=0, atol=1e-4)
 
 

@@ -18,6 +18,8 @@ from repro.serving import kvcache as kv
 from repro.serving.engine import GenConfig, ServingEngine
 from repro.serving.quantize import dequantize_vec, quantize_vec
 
+import paged_cases
+
 ENGINE = SalPimEngine.create(SalPimConfig())
 KEY = jax.random.PRNGKey(0)
 
@@ -112,6 +114,26 @@ def test_int8_decode_kernel_softcap_window_and_lut():
         tol = 3e-3 if "exp_table" in kw else 1e-4
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=tol, atol=tol, err_msg=str(kw))
+
+
+@pytest.mark.parametrize("shape", paged_cases.SHAPES)
+@pytest.mark.parametrize("window", [None, paged_cases.WINDOW])
+def test_int8_walk_at_block_edges_reads_no_page_outside_it(shape, window):
+    """The decode walk over int8 pools at its block edges (empty slot,
+    page boundary, one short of a block, full table), with NaN in every
+    scale row the table maps outside a row's walk."""
+    q, kp, vp, tbl, lens = paged_cases.pools(*paged_cases.SHAPES[shape])
+    (kq, ksc), (vq, vsc) = quantize_vec(kp), quantize_vec(vp)
+    ksc, vsc = _rows(ksc), _rows(vsc)
+    want = ref_k.paged_attention_ref(q, kq, vq, tbl, lens, ksc, vsc,
+                                     window=window)
+    got = ops.pim_paged_attention(
+        q, kq, vq, tbl, lens, paged_cases.poison(ksc, tbl, window),
+        paged_cases.poison(vsc, tbl, window), window=window,
+        impl="interpret")
+    assert bool(jnp.all(jnp.isfinite(got)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("Sq,starts,lengths", [

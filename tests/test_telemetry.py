@@ -279,6 +279,35 @@ def test_engine_telemetry_zero_cost_and_exact(tmp_path):
     assert max(t[1] for t in snap["pool"]["occupancy_timeline"]) > 0
 
 
+def test_decode_walk_page_counters():
+    """`decode.pages_walked` counts, in every layer, each slot's pages
+    through the token its decode step appends, ceil((len + 1) / page)
+    under global attention, and `decode.pages_tabled` the table's pages,
+    slots x n_pages a layer: a hand-built batch of two prompts (5 and 9
+    tokens, 4-token pages, one chunk per step) in three slots with
+    8-page tables."""
+    cfg, params = _setup()
+    tel = Telemetry(enabled=True)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=3, max_len=32, gen=GenConfig(stop_on_eos=False), paged=True,
+        page_size=4, prefill_chunk_tokens=16, telemetry=tel))
+    rng = np.random.RandomState(0)
+    for n in (5, 9):
+        eng.submit(rng.randint(2, cfg.vocab, size=n), max_new_tokens=6)
+    walked = []
+    for _ in range(3):
+        eng.step()
+        c = tel.snapshot()["counters"]
+        walked.append(c["decode.pages_walked"])
+    # Step 1: 5-token row (ceil(6/4) = 2), the 9-token prompt waiting
+    # for its chunk and an empty slot (1 each). Step 2: lengths 6 and 9
+    # (2 + 3) and the empty slot. Step 3: lengths 7 and 10 (2 + 3 + 1).
+    L = cfg.n_layers
+    assert cfg.window_for_layer(0) is None
+    assert walked == [L * 4, L * (4 + 6), L * (4 + 6 + 6)]
+    assert c["decode.pages_tabled"] == L * 3 * 3 * 8
+
+
 def test_bench_metadata_keys():
     meta = bench_metadata()
     for k in ("schema_version", "git_sha", "jax_version", "device_kind",
