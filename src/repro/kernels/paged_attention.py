@@ -143,12 +143,7 @@ def kv_vector_bytes(head_dim: int, kv_dtype: str = "model",
     return head_dim * jnp.dtype(payload_dtype).itemsize
 
 
-def _page_tile(x_ref, packed):
-    """One page payload block -> f32 (page_size, D)."""
-    return _widen(x_ref[0, 0], packed)
-
-
-def _widen(x, packed):
+def widen_payload(x, packed):
     """Page payload rows (..., Dp) -> f32 (..., D). int4 pools unpack
     in VMEM: the bytes widen to int32 first (Mosaic has no 8-bit
     shifts), then arithmetic shifts sign-extend the low and high nibbles
@@ -162,33 +157,34 @@ def _widen(x, packed):
     return x.astype(jnp.float32)
 
 
-def _scale_row(sc_ref):
-    """The (1, page_size) f32 dequant scales of this (page, head), or
-    None for fp pools; DMA'd in storage dtype, widened in VMEM."""
-    return None if sc_ref is None else sc_ref[0, 0].astype(jnp.float32)
-
-
-def attend_page(q, k_ref, v_ref, ksc_ref, vsc_ref, expwb_ref,
-                m_ref, l_ref, acc_ref, *, mask_fn, scale, softcap,
-                packed, use_lut, lut):
-    """Fold one K/V page into the running (m, l, acc) of `q` (rows, D):
-    the body shared by the paged decode (single-walk and split) and
-    paged prefill kernels, which differ only in `mask_fn(shape)` — the
-    validity mask of the (rows, page_size) score tile."""
+def attend_block(q, k, v, k_scale, v_scale, mask, expwb_ref, m_ref, l_ref,
+                 acc_ref, *, scale, softcap, use_lut, lut, n_live=None):
+    """Fold one block of keys into the running (m, l, acc) of `q` (rows,
+    D): the body shared by the KV-split decode and chunk prefill
+    kernels, which differ only in `mask`, the validity of each score of
+    the (rows, block) tile. k and v are the f32 (block, D) page rows,
+    k_scale and v_scale their (1, block) f32 dequant scales (int8/int4
+    pools) or None. Only the block's first `n_live` positions hold
+    fetched pages (None: all): the others' V rows and V scales are
+    zeroed, so stale buffer rows never reach p . V."""
     # Direction 1: contract head_dim (Q x K^T) — same layout, no transpose.
-    scores = jnp.dot(q, _page_tile(k_ref, packed).T,
-                     preferred_element_type=jnp.float32) * scale
-    k_scale = _scale_row(ksc_ref)
+    scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
     if k_scale is not None:
         scores = scores * k_scale
     if softcap is not None:
         scores = softcap * jnp.tanh(scores / softcap)
-    mask = mask_fn(scores.shape)
     scores = jnp.where(mask, scores, NEG_INF)
-    online_softmax_update(scores, mask, _page_tile(v_ref, packed),
-                          m_ref, l_ref, acc_ref, use_lut=use_lut,
-                          expwb_ref=expwb_ref, lut=lut,
-                          v_scale=_scale_row(vsc_ref))
+    if n_live is not None:
+        def live(shape, axis):
+            return jax.lax.broadcasted_iota(jnp.int32, shape, axis) < n_live
+
+        bt = k.shape[0]
+        v = jnp.where(live((bt, 1), 0), v, 0.0)
+        if v_scale is not None:
+            v_scale = jnp.where(live((1, bt), 1), v_scale, 0.0)
+    online_softmax_update(scores, mask, v, m_ref, l_ref, acc_ref,
+                          use_lut=use_lut, expwb_ref=expwb_ref, lut=lut,
+                          v_scale=v_scale)
 
 
 def split_refs(refs, quantized: bool, n_out: int):
@@ -248,7 +244,7 @@ def _walk_kernel(
                                               sem.at[slot]))
 
     def kv_tile(buf, slot):     # (ppb, page, lanes) -> f32 (bt, D lanes)
-        return _widen(buf[slot], packed).reshape(bt, -1)
+        return widen_payload(buf[slot], packed).reshape(bt, -1)
 
     def scale_tile(buf, slot):  # (ppb, R, C) -> f32 (rows, bt)
         x = buf[slot].astype(jnp.float32)
@@ -375,9 +371,9 @@ def _paged_attn_split_kernel(
     tbl_ref,   # scalar prefetch: (B, kv_splits * pps) int32, trash-padded
     *refs,     # q, k, v, [ksc, vsc,] expwb, m_out, l_out, acc_out,
                # then m/l/acc scratch
-    pps, page_size, quantized, **page_kw,
+    pps, page_size, quantized, packed, **block_kw,
 ):
-    """KV-split body: `attend_page`'s page math over one head and only
+    """KV-split body: `attend_block`'s math over one head and only
     this split's `pps` pages of the table; the writeback emits raw
     partials (m, l, un-normalized acc) for the host-side
     `merge_partial_softmax_stacked` combine. A split whose pages are all
@@ -397,16 +393,19 @@ def _paged_attn_split_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     length = len_ref[b]
+    q = q_ref[0, 0].astype(jnp.float32)
+    # Absolute position: this split's run starts sp_idx * pps pages in.
+    pos = ((sp_idx * pps + p_idx) * page_size
+           + jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], page_size), 1))
+    mask = jnp.logical_and(pos < length, pos >= length - win_ref[0])
 
-    def mask_fn(shape):
-        # Absolute position: this split's run starts sp_idx * pps pages in.
-        pos = ((sp_idx * pps + p_idx) * page_size
-               + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
-        return jnp.logical_and(pos < length, pos >= length - win_ref[0])
+    def scales(ref):  # (1, page_size) row, DMA'd in its storage dtype
+        return None if ref is None else ref[0, 0].astype(jnp.float32)
 
-    attend_page(q_ref[0, 0].astype(jnp.float32), k_ref, v_ref, ksc_ref,
-                vsc_ref, expwb_ref, m_ref, l_ref, acc_ref, mask_fn=mask_fn,
-                **page_kw)
+    attend_block(q, widen_payload(k_ref[0, 0], packed),
+                 widen_payload(v_ref[0, 0], packed), scales(ksc_ref),
+                 scales(vsc_ref), mask, expwb_ref, m_ref, l_ref, acc_ref,
+                 **block_kw)
 
     @pl.when(p_idx == pps - 1)
     def _writeback():

@@ -28,6 +28,7 @@ import numpy as np
 from repro.core.salpim import SalPimEngine
 from repro.distributed import api as dist_api
 from repro.kernels import paged_attention as paged_k
+from repro.kernels import paged_prefill as paged_pf_k
 from repro.models import api as model_api
 from repro.serving import kvcache as kv
 from repro.models.config import ModelConfig
@@ -881,6 +882,13 @@ class ServingEngine:
                 self._shape.chunk = (start, end - start)
             if tel.enabled:
                 tel.chunk(req.uid, t0c, tel.now(), end - start)
+                # Table entries the chunk kernel's walk reads, over every
+                # layer and its window, against the entries the row holds.
+                tel.count("prefill.pages_walked", sum(
+                    n * paged_pf_k.prefill_walk_pages(end, start, w, ps)
+                    for w, n in self._layer_windows.items()))
+                tel.count("prefill.pages_tabled",
+                          self.cfg.n_layers * len(row))
 
     def _release(self, slot: int, req: Request):
         req.done = True

@@ -6,6 +6,8 @@ several chunk sizes, with prefix sharing on and off, while long prompts
 no longer stall resident decodes."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,10 +16,13 @@ import pytest
 from repro.configs import get_config
 from repro.core import lut as L
 from repro.core.salpim import SalPimConfig, SalPimEngine
-from repro.kernels import ops, ref as ref_k
+from repro.kernels import ops, paged_prefill as pf_k, ref as ref_k
+from repro.kernels.decode_attention import GLOBAL_WINDOW
 from repro.models import api
 from repro.serving import kvcache as kv
 from repro.serving.engine import GenConfig, ServingEngine
+import paged_cases
+from repro.serving.quantize import quantize_vec, quantize_vec_int4
 from tolerances import f32_reorder_tol
 
 ENGINE = SalPimEngine.create(SalPimConfig())
@@ -125,6 +130,147 @@ def test_single_query_chunk_matches_decode_oracle():
     want = ref_k.paged_attention_ref(q[:, 0], kp, vp, tbl, lens)
     np.testing.assert_allclose(np.asarray(got[:, 0]), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The chunk walk: each row tile's live pages, in multi-page blocks
+# ---------------------------------------------------------------------------
+
+def test_prefill_walk_block_pages_fill_a_score_row():
+    """At least a 128-lane score row, at most MAX_BLOCK_KEYS positions,
+    the (rows, block) f32 tile within SCORE_TILE_BYTES, capped at the
+    table's width."""
+    bp = pf_k.prefill_walk_block_pages
+    assert bp(page_size=16, head_dim=128, rows=3072, n_pages=544) == 32
+    assert bp(page_size=16, head_dim=128, rows=4096, n_pages=544) == 30
+    assert bp(page_size=16, head_dim=128, rows=256, n_pages=96) == 32
+    assert bp(page_size=16, head_dim=128, rows=32768, n_pages=544) == 8
+    assert bp(page_size=16, head_dim=64, rows=64, n_pages=24) == 24
+    assert bp(page_size=128, head_dim=128, rows=4096, n_pages=9) == 3
+    for rows in (1, 6, 192, 1024, 3072, 4096):
+        ppb = bp(16, 128, rows, 544)
+        assert 128 <= ppb * 16 <= pf_k.MAX_BLOCK_KEYS
+        assert ppb * 16 == 128 or (
+            4 * (rows + 256) * ppb * 16 <= pf_k.SCORE_TILE_BYTES)
+
+
+def test_prefill_row_tile_splits_only_past_the_cap():
+    assert pf_k.prefill_row_tile(3072) == 3072   # Qwen2's 512-token chunk
+    assert pf_k.prefill_row_tile(256) == 256     # OLMoE's
+    assert pf_k.prefill_row_tile(4097) == 2064   # two tiles, 16-row multiple
+    assert pf_k.prefill_row_tile(12288) == 4096  # a 2,048-token chunk
+    assert pf_k.prefill_row_tile(7) == 7
+
+
+@pytest.mark.parametrize("window", [None, paged_cases.CHUNK_WINDOW, 1])
+def test_prefill_walk_pages_counts_the_pages_it_reads(window):
+    """`prefill_walk_pages` (the engine's `prefill.pages_walked`) equals
+    the table entries the chunk poison case leaves readable: per chunk,
+    the pages from its first query's first in-window key through
+    position length - 1."""
+    starts = [s for _, st in paged_cases.CHUNKS.values() for s in st]
+    lengths = [s + sq for sq, st in paged_cases.CHUNKS.values() for s in st]
+    n = len(starts)
+    tbl = np.arange(1, 1 + n * paged_cases.CHUNK_PAGES).reshape(n, -1)
+    pool = paged_cases.poison(np.zeros((1 + tbl.size, 1)), tbl, window,
+                              lengths, starts)
+    readable = int(np.isfinite(np.asarray(pool)[tbl]).sum())
+    assert pf_k.prefill_walk_pages(lengths, starts, window,
+                                   paged_cases.PAGE) == readable
+    if window is None:
+        assert readable == sum(-(-x // paged_cases.PAGE) for x in lengths)
+
+
+def test_pipeline_route_maps_no_page_outside_the_walk():
+    """The BlockSpec route (head_dim 64, quantized pools) clamps each
+    grid step's table entry into its row tile's [lo, hi): the steps
+    outside it repeat a block index, which the pipeline does not DMA."""
+    page, n_pages = paged_cases.PAGE, paged_cases.CHUNK_PAGES
+    for rows, g in ((24, 1), (144, 6), (48, 6)):
+        for start in (0, 21, 512):
+            for window in (GLOBAL_WINDOW, paged_cases.CHUNK_WINDOW, 1):
+                length = start + 24
+                for t in range(-(-24 * g // rows)):
+                    lo, hi = pf_k._bounds(t, rows, g, page, length, start,
+                                          window)
+                    seen = {int(pf_k._pipeline_entry(s, lo, hi, n_pages))
+                            for s in range(n_pages)}
+                    assert seen == set(range(int(lo), int(hi)))
+
+
+def _walk_case(shape, case, window=None):
+    H, Hkv, D = paged_cases.SHAPES[shape]
+    Sq, starts = paged_cases.CHUNKS[case]
+    return paged_cases.chunk_pools(H, Hkv, D, Sq, starts)
+
+
+@pytest.mark.parametrize("shape", paged_cases.SHAPES)
+@pytest.mark.parametrize("case", paged_cases.CHUNKS)
+@pytest.mark.parametrize("mode", ["exact", "window", "softcap-lut"])
+def test_chunk_walk_matches_ref_and_reads_no_page_outside_it(shape, case,
+                                                             mode):
+    """Both cells' head shapes over a table far wider than the live
+    range, with NaN in every page the table maps outside each chunk's
+    walk (a walk that read one would carry it through p.V). Chunks start
+    at 0, at 512 and mid-page; the window makes lo > 0 mid-page."""
+    q, kp, vp, tbl, lens, st = _walk_case(shape, case)
+    window = paged_cases.CHUNK_WINDOW if mode == "window" else None
+    kw = ({"softcap": 30.0, "exp_table": L.LutBank.create(64).exp}
+          if mode == "softcap-lut" else {"window": window})
+    want = ops.pim_paged_prefill_attention(q, kp, vp, tbl, lens, st,
+                                           impl="reference", **kw)
+    poison = functools.partial(paged_cases.poison, tables=tbl,
+                               window=window, lengths=np.asarray(lens),
+                               starts=np.asarray(st))
+    got = ops.pim_paged_prefill_attention(q, poison(kp), poison(vp), tbl,
+                                          lens, st, impl="interpret", **kw)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    # The LUT exp's interpolation error (1e-3 relative) against exact
+    # exp in the reference; f32 reordering otherwise.
+    tol = 3e-3 if mode == "softcap-lut" else 1e-4
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("case", ["first", "mid-page", "wide-table"])
+def test_chunk_walk_row_tiles(case, monkeypatch):
+    """Row tiles of 32 of the GQA shape's 6 * Sq rows, each walking to
+    its own causal frontier; 144 rows leave a partial last tile."""
+    monkeypatch.setattr(pf_k, "MAX_TILE_ROWS", 32)
+    q, kp, vp, tbl, lens, st = _walk_case("gqa12:2x128", case)
+    want = ops.pim_paged_prefill_attention(q, kp, vp, tbl, lens, st,
+                                           impl="reference")
+    got = pf_k.paged_prefill_attention(
+        q, paged_cases.poison(kp, tbl, None, np.asarray(lens)),
+        paged_cases.poison(vp, tbl, None, np.asarray(lens)), tbl, lens, st,
+        interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("shape", paged_cases.SHAPES)
+@pytest.mark.parametrize("kv", ["int8", "int4"])
+def test_quantized_chunk_walk_reads_no_scale_outside_it(shape, kv):
+    """int8 (f32 scale rows) and int4 (bf16 scale rows, nibble-packed)
+    pools, a chunk at 512 in a 64-entry table, with NaN in every scale
+    row the table maps outside the walk."""
+    q, kp, vp, tbl, lens, st = _walk_case(shape, "wide-table")
+    if kv == "int8":
+        (kq, ksc), (vq, vsc) = quantize_vec(kp), quantize_vec(vp)
+    else:
+        (kq, ksc), (vq, vsc) = (
+            quantize_vec_int4(x, scale_dtype=jnp.bfloat16) for x in (kp, vp))
+    ksc, vsc = ksc[:, :, None], vsc[:, :, None]
+    want = ref_k.paged_prefill_attention_ref(q, kq, vq, tbl, lens, st,
+                                             ksc, vsc)
+    got = ops.pim_paged_prefill_attention(
+        q, kq, vq, tbl, lens, st,
+        paged_cases.poison(ksc, tbl, None, np.asarray(lens)),
+        paged_cases.poison(vsc, tbl, None, np.asarray(lens)),
+        impl="interpret")
+    assert bool(jnp.all(jnp.isfinite(got)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=0, atol=1e-4)
 
 
 # ---------------------------------------------------------------------------

@@ -308,6 +308,35 @@ def test_decode_walk_page_counters():
     assert c["decode.pages_tabled"] == L * 3 * 3 * 8
 
 
+@pytest.mark.parametrize("arch", ["gpt2_medium", "gemma2_2b"])
+def test_prefill_walk_page_counters(arch):
+    """`prefill.pages_walked` counts, in every layer, the table entries
+    the chunk kernel's walk reads: from the page of the chunk's first
+    query's first in-window key through the page of its last position;
+    `prefill.pages_tabled` the row's entries a layer. One 40-token
+    prompt in 8-token chunks (starts 0, 8, 16, 24, 32), 4-token pages,
+    12-entry tables. Global layers read ceil(end / 4) pages a chunk:
+    2 + 4 + 6 + 8 + 10 = 30. gemma2's local layers (window 16) start at
+    page max(start - 15, 0) // 4 = 0, 0, 0, 2, 4: 2 + 4 + 6 + 6 + 6 =
+    24."""
+    cfg, params = _setup(arch)
+    tel = Telemetry(enabled=True)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=48, gen=GenConfig(stop_on_eos=False), paged=True,
+        page_size=4, prefill_chunk_tokens=8, telemetry=tel))
+    eng.submit(np.random.RandomState(0).randint(2, cfg.vocab, size=40),
+               max_new_tokens=2)
+    for _ in range(5):
+        eng.step()
+    c = tel.snapshot()["counters"]
+    windows = [cfg.window_for_layer(i) for i in range(cfg.n_layers)]
+    assert set(windows) <= {None, 16}
+    assert c["prefill.chunks"] == 5
+    assert c["prefill.pages_walked"] == sum(30 if w is None else 24
+                                            for w in windows)
+    assert c["prefill.pages_tabled"] == cfg.n_layers * 5 * 12
+
+
 def test_bench_metadata_keys():
     meta = bench_metadata()
     for k in ("schema_version", "git_sha", "jax_version", "device_kind",

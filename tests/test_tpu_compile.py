@@ -124,6 +124,37 @@ def test_paged_prefill_compiles(one_chip, kv, lut):
         chunk, q, kp, vp, tbl, lens, start, ksc, vsc, win, table)
 
 
+# The chunk walk's DMA route at the head_dim-128 cells' chunk shapes:
+# (Sq, H, Hkv, table entries, slots) of Qwen2-1.5B's 512-token and
+# OLMoE's 256-token chunks.
+PREFILL_WALK_CASES = {
+    "qwen2-chunk512": (512, 12, 2, 544, 16),
+    "olmoe-chunk256": (256, 16, 16, 96, 32),
+}
+
+
+@pytest.mark.parametrize("case", PREFILL_WALK_CASES)
+def test_paged_prefill_walk_compiles(one_chip, case):
+    """Each page arrives by a DMA out of the pool as it is stored: the
+    compiled call holds no temporary the size of a pool (a relayout or
+    copy of it)."""
+    sq, h, hkv, n_pages, slots = PREFILL_WALK_CASES[case]
+    s, d = one_chip, 128
+    pool = _sds(s, (1 + slots * n_pages, hkv, PAGE, d), BF16)
+    one = _sds(s, (1,), I32)
+
+    def chunk(q, kp, vp, tbl, lens, start, win):
+        return ops.pim_paged_prefill_attention(
+            q, kp, vp, tbl, lens, start, window=win, impl="pallas")
+
+    compiled = jax.jit(chunk).lower(
+        _sds(s, (1, sq, h, d), BF16), pool, pool, _sds(s, (1, n_pages), I32),
+        one, one, _sds(s, (), I32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    pool_bytes = pool.size * pool.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 4
+
+
 def test_dense_decode_with_window_operand_compiles(one_chip):
     s = one_chip
     q = _sds(s, (B, H, D), BF16)
