@@ -1,13 +1,12 @@
 #!/usr/bin/env python
-"""Dense-slot vs paged continuous batching, prefix sharing, chunked
-prefill decode-latency jitter, int8/int4 KV pages, and KV-split
-flash-decode attention.
+"""Paged continuous batching, prefix sharing, chunked prefill
+decode-latency jitter, int8/int4 KV pages, and KV-split flash-decode
+attention.
 
-Part 1 — mixed lengths: the dense `ServingEngine` gives every decode
-slot a `max_len` KV arena, so a workload with mixed prompt/output
-lengths pins worst-case memory per slot. The paged engine shares one
-page pool: short requests release their pages the moment they finish,
-so the same KV memory budget admits more concurrent work.
+Part 1 — mixed lengths: the engine serves a workload of mixed
+prompt/output lengths from one shared page pool; short requests release
+their pages the moment they finish. It reports tokens/s and the pool's
+KV bytes.
 
 Part 2 — shared prefixes: requests that repeat a system-prompt-style
 prefix are served twice on the paged engine, with prefix sharing off
@@ -226,17 +225,15 @@ def _engine_state_dump(eng):
                       "prefill_cursor": r.prefill_cursor,
                       "generated": len(r.generated),
                       "max_new_tokens": r.max_new_tokens})
+    a = eng.allocator
     dump = {
         "queue": [{"uid": r.uid, "prompt_tokens": len(r.prompt),
                    "max_new_tokens": r.max_new_tokens} for r in eng.queue],
         "slots": slots,
+        "pool": {"num_pages": a.num_pages, "used_pages": a.used_pages,
+                 "free_pages": a.free_pages,
+                 "available_pages": a.available_pages},
     }
-    if eng.allocator is not None:
-        a = eng.allocator
-        dump["pool"] = {"num_pages": a.num_pages,
-                        "used_pages": a.used_pages,
-                        "free_pages": a.free_pages,
-                        "available_pages": a.available_pages}
     if eng.telemetry.enabled:
         snap = eng.telemetry.snapshot()
         dump["telemetry"] = {"counters": snap["counters"],
@@ -278,12 +275,9 @@ def _drain(eng, reqs, max_steps=10_000):
             "tok_per_sec": (toks - warm_toks) / max(dt, 1e-9)}
 
 
-def _kv_bytes(cfg, eng):
-    if eng.paged:
-        # page_bytes includes the int8 mode's scale rows.
-        return eng.page_bytes * eng.allocator.num_pages
-    k = eng.cache.k
-    return 2 * k.size * k.dtype.itemsize
+def _kv_bytes(eng):
+    # page_bytes includes the int8 mode's scale rows.
+    return eng.page_bytes * eng.allocator.num_pages
 
 
 def _report(mode, eng, stats):
@@ -353,7 +347,7 @@ def _part4(params, cfg, engine, gen, *, slots, max_len, requests,
     hists = {}
     for label, kv_dtype in [("paged-fp", "model"), ("paged-int8", "int8")]:
         eng = ServingEngine(params, cfg, engine, EngineConfig(
-            slots=slots, max_len=max_len, gen=gen, paged=True,
+            slots=slots, max_len=max_len, gen=gen,
             page_size=page_size, kv_cache_dtype=kv_dtype))
         for p, n in reqs:
             eng.submit(p.copy(), max_new_tokens=n)
@@ -469,7 +463,7 @@ def _part5(params, cfg, engine, gen, *, slots, max_len, requests,
         ("spec-on", SpecConfig(mode="ngram", k=spec_k)),
     ]:
         eng = ServingEngine(params, cfg, engine, EngineConfig(
-            slots=slots, max_len=max_len, gen=gen, paged=True,
+            slots=slots, max_len=max_len, gen=gen,
             page_size=page_size, speculative=spec,
             **_kv_opts(kv_cache_dtype)))
         # Warmup drain: every compile lands here. Its outputs feed the
@@ -618,7 +612,7 @@ def _part6(params, cfg, engine, gen, *, slots, max_len, requests,
     for label, t in [("telemetry-off", None), ("telemetry-on", tel)]:
         engines[label] = ServingEngine(params, cfg, engine, EngineConfig(
             slots=slots, max_len=max_len, gen=gen,
-            paged=True, page_size=page_size, prefix_sharing=True,
+            page_size=page_size, prefix_sharing=True,
             prefill_chunk_tokens=chunk, telemetry=t,
             **_kv_opts(kv_cache_dtype)))
 
@@ -801,7 +795,7 @@ def _part7(params, cfg, engine, gen, *, slots, max_len, requests,
                             ("slo", SloScheduler(), tel)]:
         eng = ServingEngine(params, cfg, engine, EngineConfig(
             slots=slots, max_len=max_len, gen=gen,
-            paged=True, page_size=page_size, num_pages=num_pages,
+            page_size=page_size, num_pages=num_pages,
             prefix_sharing=True, scheduler=sched, telemetry=t,
             **_kv_opts(kv_cache_dtype)))
         infos[label] = _drain_stepwise(eng, arrivals, max_steps)
@@ -897,7 +891,7 @@ def _part3(cfg, engine, gen, *, max_len, page_size, seed, max_steps, smoke,
     outs = {}
     for label, chunk_tokens in modes:
         engines[label] = ServingEngine(params, cfg, engine, EngineConfig(
-            slots=slots, max_len=max_len, gen=gen, paged=True,
+            slots=slots, max_len=max_len, gen=gen,
             page_size=page_size, prefill_chunk_tokens=chunk_tokens,
             **_kv_opts(kv_cache_dtype)))
         # Warm every jit shape (prefill chunks, decode) on this engine.
@@ -999,7 +993,7 @@ def _part8(params, cfg, engine, gen, *, slots, max_len, requests,
 
     def build_and_drain(mesh):
         eng = ServingEngine(params, cfg, engine, EngineConfig(
-            slots=slots, max_len=max_len, gen=gen, paged=True,
+            slots=slots, max_len=max_len, gen=gen,
             page_size=page_size, mesh=mesh, **_kv_opts(kv_cache_dtype)))
         stats = _drain(eng, [(p.copy(), n) for p, n in reqs],
                        max_steps=max_steps)
@@ -1115,7 +1109,7 @@ def _part9(params, cfg, engine, gen, *, smoke, seed):
     stats, outs = {}, {}
     for label, kv_dtype in [("paged-fp", "model"), ("paged-int4", "int4")]:
         eng = ServingEngine(params, cfg, engine, EngineConfig(
-            slots=2, max_len=32, gen=gen, paged=True, page_size=4,
+            slots=2, max_len=32, gen=gen, page_size=4,
             **_kv_opts(kv_dtype)))
         st = _drain(eng, [(p.copy(), n) for p, n in reqs],
                     max_steps=2_000)
@@ -1192,7 +1186,7 @@ def _part10(params, cfg, engine, gen, *, slots, max_len, requests,
     for label, kv_dtype in [("fp", "model"), ("int8", "int8"),
                             ("int4", "int4")]:
         eng = ServingEngine(params, cfg, engine, EngineConfig(
-            slots=slots, max_len=max_len, gen=gen, paged=True,
+            slots=slots, max_len=max_len, gen=gen,
             page_size=page_size, **_kv_opts(kv_dtype)))
         assert eng.cost_model.page_bytes == eng.page_bytes, (
             f"cost model and pool disagree on {label} page bytes: "
@@ -1235,7 +1229,7 @@ def _part10(params, cfg, engine, gen, *, slots, max_len, requests,
     # -- (b) achieved bandwidth + boundedness from a telemetry drain --------
     tel = Telemetry(enabled=True)
     eng = ServingEngine(params, cfg, engine, EngineConfig(
-        slots=slots, max_len=max_len, gen=gen, paged=True,
+        slots=slots, max_len=max_len, gen=gen,
         page_size=page_size, telemetry=tel))
     _drain(eng, [(p.copy(), n) for p, n in reqs], max_steps=max_steps)
     roof = tel.snapshot()["roofline"]
@@ -1258,7 +1252,7 @@ def _part10(params, cfg, engine, gen, *, slots, max_len, requests,
     mods, outs = {}, {}
     for label, splits in [("nosplit", None), ("split", 4)]:
         eng2 = ServingEngine(params, cfg, engine, EngineConfig(
-            slots=slots, max_len=max_len, gen=gen, paged=True,
+            slots=slots, max_len=max_len, gen=gen,
             page_size=page_size, kv_splits=splits))
         _drain(eng2, [(p.copy(), n) for p, n in reqs],
                max_steps=max_steps)
@@ -1316,28 +1310,17 @@ def run(arch="gpt2_medium", slots=4, max_len=64, requests=12,
     shared_reqs = _shared_prefix_workload(rng, cfg.vocab, requests, max_len,
                                           prefix_len)
 
-    # -- part 1: dense vs paged on mixed lengths ----------------------------
+    # -- part 1: mixed lengths ----------------------------------------------
     if 1 in parts:
-        for mode, kwargs in [
-            ("dense", {}),
-            ("paged", {"paged": True, "page_size": page_size,
-                       **_kv_opts(kv_cache_dtype)}),
-        ]:
-            eng = ServingEngine(params, cfg, engine, EngineConfig(
-                slots=slots, max_len=max_len, gen=gen, **kwargs))
-            stats = _drain(eng, [(p.copy(), n) for p, n in reqs],
-                           max_steps=max_steps)
-            stats["kv_bytes"] = _kv_bytes(cfg, eng)
-            rows.append((mode, stats))
-            _report(mode, eng, stats)
-
-        dense, paged = rows[0][1], rows[1][1]
-        assert dense["tokens"] == paged["tokens"], (dense["tokens"],
-                                                    paged["tokens"])
-        print(f"paged/dense wall-clock ratio: "
-              f"{paged['sec'] / dense['sec']:.2f}x "
-              f"(same {dense['tokens']} tokens)")
-        summary["tokens_per_sec"] = paged["tok_per_sec"]
+        eng = ServingEngine(params, cfg, engine, EngineConfig(
+            slots=slots, max_len=max_len, gen=gen, page_size=page_size,
+            **_kv_opts(kv_cache_dtype)))
+        stats = _drain(eng, [(p.copy(), n) for p, n in reqs],
+                       max_steps=max_steps)
+        stats["kv_bytes"] = _kv_bytes(eng)
+        rows.append(("paged", stats))
+        _report("paged", eng, stats)
+        summary["tokens_per_sec"] = stats["tok_per_sec"]
 
     # -- part 2: prefix sharing on a shared-prefix workload -----------------
     if 2 in parts:
@@ -1346,12 +1329,12 @@ def run(arch="gpt2_medium", slots=4, max_len=64, requests=12,
         for mode, sharing in [("paged-noshare", False),
                               ("paged-share", True)]:
             eng = ServingEngine(params, cfg, engine, EngineConfig(
-                slots=slots, max_len=max_len, gen=gen, paged=True,
+                slots=slots, max_len=max_len, gen=gen,
                 page_size=page_size, prefix_sharing=sharing,
                 **_kv_opts(kv_cache_dtype)))
             stats = _drain(eng, [(p.copy(), n) for p, n in shared_reqs],
                            max_steps=max_steps)
-            stats["kv_bytes"] = _kv_bytes(cfg, eng)
+            stats["kv_bytes"] = _kv_bytes(eng)
             stats["prefill_tokens"] = eng.prefill_tokens
             stats["peak_pages"] = eng.peak_pages
             outs[mode] = {r.uid: list(r.generated) for r in eng.finished}

@@ -85,10 +85,10 @@ def make_prompts(vocab: int, lens, n: int, seed: int) -> list:
 
 
 def build_engine(params, cfg, engine, **engine_kw):
-    """The engine `examples/serve_batched.py --paged` builds, greedy."""
+    """The engine `examples/serve_batched.py` builds, greedy."""
     from repro.serving import EngineConfig, GenConfig, ServingEngine
     return ServingEngine(params, cfg, engine, EngineConfig(
-        paged=True, page_size=PAGE_SIZE, slots=4, max_len=256,
+        page_size=PAGE_SIZE, slots=4, max_len=256,
         prefill_chunk_tokens=CHUNK,
         gen=GenConfig(temperature=0.0, stop_on_eos=False), **engine_kw))
 

@@ -3,13 +3,13 @@
 slots (slot-based admission, per-request lengths, EOS release).
 
     PYTHONPATH=src python examples/serve_batched.py
-    PYTHONPATH=src python examples/serve_batched.py --paged --page-size 16
-    PYTHONPATH=src python examples/serve_batched.py --paged \
+    PYTHONPATH=src python examples/serve_batched.py --page-size 8
+    PYTHONPATH=src python examples/serve_batched.py \
         --telemetry --trace-out trace.json
-    PYTHONPATH=src python examples/serve_batched.py --paged \
+    PYTHONPATH=src python examples/serve_batched.py \
         --scheduler slo --priority --num-pages 12
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        PYTHONPATH=src python examples/serve_batched.py --paged --mesh 2
+        PYTHONPATH=src python examples/serve_batched.py --mesh 2
 """
 import argparse
 import time
@@ -27,25 +27,22 @@ from repro.serving import (EngineConfig, FifoScheduler, GenConfig,
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--paged", action="store_true",
-                    help="use the paged KV cache (shared page pool + "
-                         "block tables) instead of dense per-slot arenas")
     ap.add_argument("--page-size", type=int, default=16,
-                    help="tokens per KV page (paged mode)")
+                    help="tokens per KV page")
     ap.add_argument("--num-pages", type=int, default=None,
-                    help="page pool size (paged mode; default: dense-equal)")
+                    help="page pool size (default: every slot at max_len)")
     ap.add_argument("--no-prefix-sharing", action="store_true",
                     help="disable copy-on-write prompt prefix sharing "
-                         "(paged mode; shared by default)")
+                         "(shared by default)")
     ap.add_argument("--shared-prefix", type=int, default=0,
                     help="prepend a common prefix of this many tokens to "
                          "every prompt (exercises prefix sharing)")
     ap.add_argument("--prefill-chunk-tokens", type=int, default=None,
-                    help="chunked paged prefill budget per engine step "
-                         "(paged mode; default: whole prompt in one chunk)")
+                    help="chunked prefill budget per engine step "
+                         "(default: whole prompt in one chunk)")
     ap.add_argument("--kv-cache-dtype", default=None,
                     choices=["model", "int8", "int4"],
-                    help="paged pool storage: int8 stores pages as int8 "
+                    help="page pool storage: int8 stores pages as int8 "
                          "+ per-(token, head) scale rows (write-time amax "
                          "quantization, in-kernel dequant) — ~2x KV bytes "
                          "saved, ~2x pages at the same HBM budget; int4 "
@@ -59,11 +56,11 @@ def main():
     ap.add_argument("--kv-splits", type=int, default=None,
                     help="flash-decode KV-split factor: split each "
                          "slot's page walk into this many online-softmax "
-                         "partials merged by one combine pass (paged "
-                         "mode; engages above 1024-token contexts)")
+                         "partials merged by one combine pass "
+                         "(engages above 1024-token contexts)")
     ap.add_argument("--speculative", default="off",
                     choices=["off", "ngram", "draft-model"],
-                    help="speculative decoding (paged + greedy): a "
+                    help="speculative decoding (greedy only): a "
                          "drafter proposes --spec-k tokens, one verify "
                          "pass scores them all, rejected tails roll back "
                          "in-pool — greedy outputs are bit-identical, "
@@ -77,7 +74,7 @@ def main():
     ap.add_argument("--scheduler", default="fifo", choices=["fifo", "slo"],
                     help="admission/preemption policy: 'fifo' is the "
                          "historical strict-FIFO watermark admission "
-                         "(never preempts); 'slo' (paged only) admits "
+                         "(never preempts); 'slo' admits "
                          "optimistically, serves higher-priority classes "
                          "first, and preempts-and-swaps lower classes to "
                          "host RAM under page pressure — greedy outputs "
@@ -96,9 +93,9 @@ def main():
                     help="write a Chrome trace_event timeline of the run "
                          "(implies --telemetry; open at ui.perfetto.dev)")
     ap.add_argument("--mesh", type=int, default=0,
-                    help="shard the paged page pools over this many "
-                         "devices (tensor-parallel 'model' axis; paged "
-                         "mode, must divide the model's KV heads). Run "
+                    help="shard the page pools over this many devices "
+                         "(tensor-parallel 'model' axis; must divide "
+                         "the model's KV heads). Run "
                          "under XLA_FLAGS=--xla_force_host_platform_"
                          "device_count=8 to demo on a CPU-only host; "
                          "greedy outputs stay bit-identical")
@@ -147,7 +144,7 @@ def main():
     eng = ServingEngine(params, cfg, engine, EngineConfig(
         slots=args.slots, max_len=args.max_len,
         gen=GenConfig(temperature=0.0, stop_on_eos=False),
-        paged=args.paged, page_size=args.page_size,
+        page_size=args.page_size,
         num_pages=args.num_pages,
         prefix_sharing=not args.no_prefix_sharing,
         prefill_chunk_tokens=args.prefill_chunk_tokens,
@@ -168,10 +165,8 @@ def main():
         prio = (0 if i % 3 == 0 else 1) if args.priority else 0
         uids.append(eng.submit(prompt, max_new_tokens=int(rng.randint(5, 15)),
                                priority=prio))
-    mode = (f"paged (page_size={args.page_size}, "
-            f"{eng.allocator.num_pages} pages, kv {eng.kv_cache_dtype})"
-            if args.paged else "dense")
-    mode += f", scheduler {args.scheduler}"
+    mode = (f"page_size={args.page_size}, {eng.allocator.num_pages} pages, "
+            f"kv {eng.kv_cache_dtype}, scheduler {args.scheduler}")
     if mesh is not None:
         mode += f", mesh model={args.mesh}"
     if speculative is not None:
@@ -193,13 +188,12 @@ def main():
           "compiles included)")
     print(f"finished {len(eng.finished)}/{len(uids)} requests, "
           f"{toks} tokens generated")
-    if args.paged:
-        a = eng.allocator
-        print(f"page pool: {a.used_pages} in use / {a.num_pages - 1} usable "
-              f"(all should be free after drain: {a.free_pages}), "
-              f"peak {eng.peak_pages} pages")
-        print(f"prefill: {eng.prefill_tokens} tokens computed, "
-              f"{eng.prefill_tokens_saved} skipped via shared prefix pages")
+    a = eng.allocator
+    print(f"page pool: {a.used_pages} in use / {a.num_pages - 1} usable "
+          f"(all should be free after drain: {a.free_pages}), "
+          f"peak {eng.peak_pages} pages")
+    print(f"prefill: {eng.prefill_tokens} tokens computed, "
+          f"{eng.prefill_tokens_saved} skipped via shared prefix pages")
     if speculative is not None:
         st = eng.stats()
         print(f"speculative: {st['accepted']}/{st['proposed']} drafts "

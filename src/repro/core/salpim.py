@@ -32,7 +32,7 @@ def _replicated_on_mesh(fn, *args):
     """`fn(*args)`, run whole on every device of the active mesh.
 
     XLA cannot partition a Mosaic kernel itself, so under a mesh
-    (`ServingEngine(mesh=...)` shards only the page pools) a kernel
+    (`EngineConfig(mesh=...)` shards only the page pools) a kernel
     outside the paged-attention shard_map runs in a shard_map of its own
     with every operand replicated: each device computes the full result
     from its replicated weights, as the one-device engine would."""

@@ -6,7 +6,7 @@ The public surface — import from here, not from the submodules:
     from repro.serving import EngineConfig, ServingEngine
 
     eng = ServingEngine(params, cfg, engine, EngineConfig(
-        slots=4, max_len=64, paged=True))
+        slots=4, max_len=64))
 
 Submodules stay importable for the internals (kvcache allocators,
 drafters, sampling), but engine construction, configuration, policy

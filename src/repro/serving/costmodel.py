@@ -35,8 +35,9 @@ What each phase streams (one jitted program launch each):
                   once per draft forward (its dense per-slot KV cache
                   is negligible against the weight stream and is not
                   modeled). The n-gram drafter is host-side: 0 bytes.
-  admit         — dense backend only: a whole-prompt prefill
-                  (paged admission is host-side bookkeeping: 0 bytes).
+
+Admission is host-side bookkeeping and moves no device bytes, so the
+`admit` phase keeps only its wall time.
 
 KV bytes are dtype-aware through the kernel's own DMA contract
 (`kernels/paged_attention.kv_vector_bytes`): fp Dh*itemsize, int8
@@ -199,9 +200,6 @@ class StepShape:
     # Draft-model forwards this step (0 for the host-side n-gram
     # drafter, ~proposed tokens for draft-model mode).
     draft_forwards: int = 0
-    # Dense admission: whole-prompt prefill token count (paged
-    # admission is host-side only and costs 0 bytes).
-    admit_prompt_tokens: int = 0
 
 
 class CostModel:
@@ -274,7 +272,7 @@ class CostModel:
             hw = HARDWARE_SPECS[name] if name else None
         return cls(
             model_cfg,
-            page_size=engine_cfg.page_size if engine_cfg.paged else 1,
+            page_size=engine_cfg.page_size,
             kv_dtype=engine_cfg.resolved_kv_dtype(model_cfg),
             kv_scale_dtype=engine_cfg.kv_scale_dtype,
             tensor_parallel=engine_cfg.tensor_parallel(),
@@ -351,10 +349,6 @@ class CostModel:
         """The full per-step picture: {phase: PhaseCost} for the phases
         that ran (keys are a subset of telemetry's `_PHASES`)."""
         costs: dict[str, PhaseCost] = {}
-        if shape.admit_prompt_tokens > 0:
-            n = shape.admit_prompt_tokens
-            costs["admit"] = self._forward(
-                n, n * (n + 1) / 2.0, 0.0)
         if shape.chunk is not None:
             costs["chunk_prefill"] = self.chunk_prefill(*shape.chunk)
         if shape.draft_forwards > 0:

@@ -35,8 +35,8 @@ from repro.models.config import ModelConfig
 from repro.serving.config import EngineConfig, GenConfig
 from repro.serving.costmodel import CostModel, StepShape
 from repro.serving.sampling import sample
-from repro.serving.scheduler import FifoScheduler, Scheduler, SwappedRequest
-from repro.serving.speculative import SpecConfig, greedy_accept, make_drafter
+from repro.serving.scheduler import FifoScheduler, SwappedRequest
+from repro.serving.speculative import greedy_accept, make_drafter
 from repro.serving.telemetry import NULL_TELEMETRY, Telemetry
 
 Array = jax.Array
@@ -161,8 +161,8 @@ class Request:
     # (continuous batching: a request decodes only once the cursor has
     # walked the whole prompt, one chunk per engine step).
     prefill_cursor: int = 0
-    # Tokens covered by prefix-cache pages mapped at admission (paged
-    # mode). Distinguishes pages this request *borrowed* (COW-fork before
+    # Tokens covered by prefix-cache pages mapped at admission.
+    # Distinguishes pages this request *borrowed* (COW-fork before
     # any write) from fresh pages it registered itself — the donor keeps
     # writing its registered pages even after a sharer raises their
     # refcount, since that write *is* the content sharers mapped.
@@ -184,49 +184,44 @@ class Request:
 class ServingEngine:
     """Slot-based continuous batching over a fixed decode batch width.
 
-    Two cache backends behind one decode_step interface:
+    Slots share a page pool (kvcache.py). Admission is gated on the
+    allocator's watermark: a request is admitted only when its
+    worst-case page count can be reserved, so decode never runs out of
+    pages mid-sequence. Pages are physically allocated at decode-step
+    boundaries and freed the moment a request completes, so mixed
+    prompt/output lengths never each pin a full `max_len` of KV.
 
-      * dense (default) — every slot owns a `max_len` KV arena;
-      * paged (`paged=True`) — slots share a page pool (kvcache.py).
-        Admission is gated on the allocator's watermark: a request is
-        admitted only when its worst-case page count can be reserved,
-        so decode never runs out of pages mid-sequence. Pages are
-        physically allocated at decode-step boundaries and freed the
-        moment a request completes — mixed prompt/output lengths no
-        longer each pin a full `max_len` arena.
-
-    Paged prefill is *chunked*: prompts are written directly into pool
+    Prefill is *chunked*: prompts are written directly into pool
     pages, `prefill_chunk_tokens` tokens per engine step (None = the
     whole prompt in one chunk), with earlier chunks' KV read back
-    through the block table — no dense per-slot prefill arena, no
-    scatter pass. Admission only reserves pages; each step then runs at
-    most one prompt chunk *alongside* the regular decode batch, so a
-    long prompt no longer stalls resident decodes (continuous batching).
-    A mid-prefill slot keeps device length 0 and an all-trash block-table
-    row, so the shared decode program cannot touch its pages; the slot
-    is activated (row + length + first logits) when the cursor reaches
-    the end of the prompt.
+    through the block table. Admission only reserves pages; each step
+    then runs at most one prompt chunk *alongside* the regular decode
+    batch, so a long prompt does not stall resident decodes (continuous
+    batching). A mid-prefill slot keeps device length 0 and an
+    all-trash block-table row, so the shared decode program cannot
+    touch its pages; the slot is activated (row + length + first
+    logits) when the cursor reaches the end of the prompt.
 
-    Paged mode additionally shares prompt prefixes (`prefix_sharing`,
-    on by default): admission walks the allocator's content-addressed
-    prefix cache, maps the longest cached run of full pages into the new
-    slot, and the chunked prefill simply starts at the shared offset.
-    Shared pages are copy-on-write: a KV write that would land in a page
-    with refcount > 1 first forks it into a private physical page.
-    Greedy outputs are bit-identical with sharing on or off and at any
-    chunk size — both only remove redundant work and pool pressure.
+    Prompt prefixes are shared (`prefix_sharing`, on by default):
+    admission walks the allocator's content-addressed prefix cache,
+    maps the longest cached run of full pages into the new slot, and
+    the chunked prefill simply starts at the shared offset. Shared
+    pages are copy-on-write: a KV write that would land in a page with
+    refcount > 1 first forks it into a private physical page. Greedy
+    outputs are bit-identical with sharing on or off and at any chunk
+    size — both only remove redundant work and pool pressure.
 
-    `kv_cache_dtype="int8"` (paged mode) stores the page pools as int8
-    with per-(token, head) f32 scale rows: every KV write — chunk
-    prefill and decode append — amax-quantizes at write time and the
-    paged kernels dequantize in VMEM, halving the HBM bytes a decode
-    step streams. With `num_pages=None` the pool keeps the *byte*
-    budget of the fp cache, so it holds ~2x the pages (double resident
-    capacity at fixed HBM). COW forks copy scale rows with their pages.
-    Outputs match the fp engine's greedy outputs up to quantization
-    noise (~1/127 per K/V vector) — exact on the repo's test prompts.
-    int8 scale rows default to f32; `kv_scale_dtype="bfloat16"` stores
-    them in bf16 — (Dh + 2) instead of (Dh + 4) bytes per vector.
+    `kv_cache_dtype="int8"` stores the page pools as int8 with
+    per-(token, head) f32 scale rows: every KV write — chunk prefill
+    and decode append — amax-quantizes at write time and the paged
+    kernels dequantize in VMEM, halving the HBM bytes a decode step
+    streams. With `num_pages=None` the pool keeps the *byte* budget of
+    the fp pool, so it holds ~2x the pages (double resident capacity at
+    fixed HBM). COW forks copy scale rows with their pages. Outputs
+    match the fp engine's greedy outputs up to quantization noise
+    (~1/127 per K/V vector) — exact on the repo's test prompts. int8
+    scale rows default to f32; `kv_scale_dtype="bfloat16"` stores them
+    in bf16 — (Dh + 2) instead of (Dh + 4) bytes per vector.
 
     `kv_cache_dtype="int4"` packs two KV values per byte ((Dh/2 + 2)
     bytes per vector — half of int8 again; requires
@@ -236,30 +231,29 @@ class ServingEngine:
     vector — still greedy-exact on the repo's smoke workloads, but
     validate on your own.
 
-    `kv_splits=K` (paged mode) turns long-context decode attention into
-    the KV-split (flash-decode) form: the block-table walk is split
-    into K online-softmax partials merged by
-    `merge_partial_softmax_stacked`. Engaged only above
-    `KV_SPLIT_MIN_CONTEXT` resident tokens; outputs match the single
-    walk to float tolerance (~1e-6), not bit-exactly.
+    `kv_splits=K` turns long-context decode attention into the KV-split
+    (flash-decode) form: the block-table walk is split into K
+    online-softmax partials merged by `merge_partial_softmax_stacked`.
+    Engaged only above `KV_SPLIT_MIN_CONTEXT` resident tokens; outputs
+    match the single walk to float tolerance (~1e-6), not bit-exactly.
 
-    `speculative=SpecConfig(...)` (paged + greedy only) turns decode
-    steps into draft-verify rounds (serving/speculative.py): a drafter
-    proposes k tokens, one verify pass scores all of them against the
-    pool, the accepted prefix commits and the rejected tail rolls back
-    in-pool. Greedy outputs stay bit-identical with speculation on or
-    off; mid-prefill slots never speculate (they are not in the decode
-    batch until their prompt cursor finishes).
+    `speculative=SpecConfig(...)` (greedy only) turns decode steps into
+    draft-verify rounds (serving/speculative.py): a drafter proposes k
+    tokens, one verify pass scores all of them against the pool, the
+    accepted prefix commits and the rejected tail rolls back in-pool.
+    Greedy outputs stay bit-identical with speculation on or off;
+    mid-prefill slots never speculate (they are not in the decode batch
+    until their prompt cursor finishes).
 
     `scheduler=` (serving/scheduler.py) selects the admission /
     prefill-ordering / preemption policy. The default `FifoScheduler`
-    reproduces the historical engine bit-identically: strict FIFO under
-    watermark admission, no preemption. `SloScheduler` adds priority
-    classes (`submit(priority=...)`), optimistic (non-worst-case)
-    admission, and preempt-and-swap over the host tier
-    (`kvcache.HostSwapTier`) when the pool runs dry — swapped-then-
-    restored slots continue bit-identically, and any schedule that
-    never preempts keeps greedy outputs bit-identical to FIFO.
+    is strict FIFO under watermark admission, no preemption.
+    `SloScheduler` adds priority classes (`submit(priority=...)`),
+    optimistic (non-worst-case) admission, and preempt-and-swap over
+    the host tier (`kvcache.HostSwapTier`) when the pool runs dry —
+    swapped-then-restored slots continue bit-identically, and any
+    schedule that never preempts keeps greedy outputs bit-identical to
+    FIFO.
 
     `telemetry=Telemetry(enabled=True)` (serving/telemetry.py) attaches
     the observability layer: per-step phase records (admit / chunk
@@ -271,65 +265,28 @@ class ServingEngine:
     bit-identical with telemetry on or off — instrumentation lives at
     step boundaries only, never inside the jitted programs.
 
-    `mesh=jax.sharding.Mesh(devices, ("model",))` (paged only) serves
-    the page pools sharded across devices: payload and scale pools
-    shard their KV-head axis over the mesh axis behind the logical
-    "model" name, weights/block tables/lengths replicate, and the
-    decode/prefill kernels run inside `shard_map` on per-shard head
-    slices with an exact concatenation merge (collectives.gather_heads)
-    — greedy outputs stay bit-identical to the single-device engine
-    while each device holds 1/tp of the pool bytes. Admission,
-    scheduling, COW forks, rewind and preempt-swap stay host-side and
-    global, so every paged feature works unchanged on a mesh.
+    `mesh=jax.sharding.Mesh(devices, ("model",))` serves the page pools
+    sharded across devices: payload and scale pools shard their
+    KV-head axis over the mesh axis behind the logical "model" name,
+    weights/block tables/lengths replicate, and the decode/prefill
+    kernels run inside `shard_map` on per-shard head slices with an
+    exact concatenation merge (collectives.gather_heads) — greedy
+    outputs stay bit-identical to the single-device engine while each
+    device holds 1/tp of the pool bytes. Admission, scheduling, COW
+    forks, rewind and preempt-swap stay host-side and global, so every
+    feature works unchanged on a mesh.
 
-    Construction: pass one `EngineConfig` (serving/config.py) —
-    `ServingEngine(params, cfg, engine, EngineConfig(slots=4,
-    max_len=64, paged=True))`. The historical per-feature kwargs still
-    work through a deprecation shim (warns once per process).
+    Construction: `ServingEngine(params, cfg, engine,
+    EngineConfig(slots=4, max_len=64))` (serving/config.py).
     """
 
     def __init__(self, params: dict, model_cfg: ModelConfig,
-                 engine: SalPimEngine,
-                 config: Optional[EngineConfig] = None, *,
-                 slots: Optional[int] = None,
-                 max_len: Optional[int] = None,
-                 gen: Optional[GenConfig] = None,
-                 paged: Optional[bool] = None,
-                 page_size: Optional[int] = None,
-                 num_pages: Optional[int] = None,
-                 prefix_sharing: Optional[bool] = None,
-                 prefill_chunk_tokens: Optional[int] = None,
-                 kv_cache_dtype: Optional[str] = None,
-                 kv_scale_dtype: Optional[str] = None,
-                 speculative: Optional[SpecConfig] = None,
-                 scheduler: Optional[Scheduler] = None,
-                 telemetry: Optional[Telemetry] = None,
-                 seed: Optional[int] = None, mesh=None):
-        # Deprecation shim: the historical per-feature kwargs fold into
-        # an EngineConfig (serving/config.py) and warn once per process;
-        # new call sites pass `config=` and nothing else.
-        legacy = {"slots": slots, "max_len": max_len, "gen": gen,
-                  "paged": paged, "page_size": page_size,
-                  "num_pages": num_pages, "prefix_sharing": prefix_sharing,
-                  "prefill_chunk_tokens": prefill_chunk_tokens,
-                  "kv_cache_dtype": kv_cache_dtype,
-                  "kv_scale_dtype": kv_scale_dtype,
-                  "speculative": speculative, "scheduler": scheduler,
-                  "telemetry": telemetry, "seed": seed, "mesh": mesh}
-        if config is None:
-            config = EngineConfig.from_legacy_kwargs(**legacy)
-        else:
-            given = sorted(k for k, v in legacy.items() if v is not None)
-            if given:
-                raise TypeError(
-                    "pass either config=EngineConfig(...) or the legacy "
-                    f"keyword arguments, not both (got {given})")
-        # One place for every feature-interaction rule (preemptive
-        # requires paged, spec is paged+greedy, mesh divides KV heads...)
+                 engine: SalPimEngine, config: EngineConfig):
+        # One place for every feature-interaction rule (spec is
+        # greedy-only, mesh divides KV heads, int4 needs bf16 scales...)
         config.validate(model_cfg)
         self.config = config
         slots, max_len, gen = config.slots, config.max_len, config.gen
-        paged = config.paged
         self.params = params
         self.cfg = model_cfg
         self._layer_windows = collections.Counter(
@@ -403,7 +360,6 @@ class ServingEngine:
         if self.telemetry.enabled:
             self.telemetry.attach_roofline(self.cost_model.describe())
 
-        self.paged = paged
         self.prefill_chunk_tokens = config.prefill_chunk_tokens
         # KV pool storage: "model" (compute dtype), "int8" (int8 pages
         # + scale rows, quantized at write time, dequantized in the
@@ -416,40 +372,33 @@ class ServingEngine:
         self.drafter = (make_drafter(config.speculative, engine, max_len,
                                      telemetry=self.telemetry)
                         if config.speculative is not None else None)
-        if paged:
-            self._kv = kv
-            page_size, num_pages = config.page_size, config.num_pages
-            max_pages = -(-max_len // page_size)
-            self.page_bytes = kv.page_kv_bytes(model_cfg, page_size,
-                                               resolved_kv,
-                                               config.kv_scale_dtype)
-            if num_pages is None:
-                # Same *byte* budget as the dense cache (plus the trash
-                # page): int8 pages cost ~half the bytes, so the same
-                # HBM holds ~2x the pages — double the resident-request
-                # capacity at fixed memory, which is the point of the
-                # int8 mode.
-                budget = slots * max_pages * kv.page_kv_bytes(
-                    model_cfg, page_size, "model")
-                num_pages = budget // self.page_bytes + 1
-            self.allocator = kv.BlockAllocator(
-                num_pages, page_size,
-                prefix_sharing=config.prefix_sharing,
-                telemetry=self.telemetry,
-                pin_budget_pages=self.scheduler.pin_budget_pages)
-            # With a mesh, the pools come back PartitionSpec-sharded
-            # over their KV-head axis (kvcache.shard_cache wires
-            # distributed.api.resolve_spec into the paged path); the
-            # block tables and lengths live replicated so admission,
-            # COW forks, rewind and swap stay host-side and global.
-            self.cache = model_api.init_paged_cache(
-                model_cfg, slots, num_pages, page_size, max_pages,
-                kv_dtype=resolved_kv, kv_scale_dtype=config.kv_scale_dtype,
-                mesh=self.mesh)
-        else:
-            self.allocator = None
-            self.page_bytes = None
-            self.cache = model_api.init_cache(model_cfg, slots, max_len)
+        page_size, num_pages = config.page_size, config.num_pages
+        max_pages = -(-max_len // page_size)
+        self.page_bytes = kv.page_kv_bytes(model_cfg, page_size, resolved_kv,
+                                           config.kv_scale_dtype)
+        if num_pages is None:
+            # The byte budget of every slot holding max_len tokens of
+            # model-dtype KV (plus the trash page): int8 pages cost ~half
+            # the bytes, so the same HBM holds ~2x the pages — double the
+            # resident-request capacity at fixed memory, which is the
+            # point of the int8 mode.
+            budget = slots * max_pages * kv.page_kv_bytes(
+                model_cfg, page_size, "model")
+            num_pages = budget // self.page_bytes + 1
+        self.allocator = kv.BlockAllocator(
+            num_pages, page_size,
+            prefix_sharing=config.prefix_sharing,
+            telemetry=self.telemetry,
+            pin_budget_pages=self.scheduler.pin_budget_pages)
+        # With a mesh, the pools come back PartitionSpec-sharded over
+        # their KV-head axis (kvcache.shard_cache wires
+        # distributed.api.resolve_spec into the paged path); the block
+        # tables and lengths live replicated so admission, COW forks,
+        # rewind and swap stay host-side and global.
+        self.cache = model_api.init_paged_cache(
+            model_cfg, slots, num_pages, page_size, max_pages,
+            kv_dtype=resolved_kv, kv_scale_dtype=config.kv_scale_dtype,
+            mesh=self.mesh)
         if self.mesh is not None:
             # Weights and sampling state replicate across the mesh: only
             # the KV pools shard (the decode stream they gate is the
@@ -463,15 +412,15 @@ class ServingEngine:
 
         # The jits wrap named functions so device traces name their
         # programs (`jit_decode_step`, `jit_prefill_chunk`,
-        # `jit_verify_tokens`, `jit_dense_admit`), not `jit__lambda`.
+        # `jit_verify_tokens`), not `jit__lambda`.
         # The cache is donated: decode and chunk-prefill steps update the
-        # KV arena / page pools in place instead of copying the whole
-        # buffer every step (the engine never touches the stale pytree —
-        # it rebinds self.cache from each call's result).
-        # A paged MoE decode program also returns, per layer, how many
-        # experts its live rows hit (a few bytes, fetched with the next
-        # step's tokens for the `moe.*` counters).
-        self._moe = paged and model_cfg.family == "moe"
+        # page pools in place instead of copying the whole buffer every
+        # step (the engine never touches the stale pytree — it rebinds
+        # self.cache from each call's result).
+        # A MoE decode program also returns, per layer, how many experts
+        # its live rows hit (a few bytes, fetched with the next step's
+        # tokens for the `moe.*` counters).
+        self._moe = model_cfg.family == "moe"
         self._expert_hits = None
 
         def decode_step(p, tok, cache):
@@ -479,28 +428,6 @@ class ServingEngine:
                                          expert_hits=self._moe)
 
         self._decode = jax.jit(decode_step, donate_argnums=(2,))
-
-        # Per-slot dense admission (batch-of-1 prefill + slot scatter) —
-        # compiled once per prompt length. The engine-wide cache and
-        # last_logits are donated, so admitting a request updates the
-        # dense arena in place like the paged decode/chunk jits instead
-        # of copying every slot's KV to write one slot's rows.
-        def dense_admit(p, toks, slot, cache, last_logits):
-            logits1, cache1 = model_api.prefill(
-                p, {"tokens": toks}, model_cfg, engine, max_len=max_len)
-
-            def put(dst, src):
-                if dst is None:
-                    return None
-                if dst.ndim == 1:  # lengths
-                    return dst.at[slot].set(src[0])
-                return dst.at[:, slot].set(src[:, 0])
-
-            cache = jax.tree.map(put, cache, cache1,
-                                 is_leaf=lambda x: x is None)
-            return cache, last_logits.at[slot].set(logits1[0])
-
-        self._dense_admit = jax.jit(dense_admit, donate_argnums=(3, 4))
 
         # Paged prefill chunk: writes K/V straight into pool pages (and,
         # in int8 mode, their scale rows — donated alongside).
@@ -547,12 +474,11 @@ class ServingEngine:
         prompt = np.asarray(prompt)
         if priority < 0:
             raise ValueError(f"priority must be >= 0, got {priority}")
-        # Both backends size their cache (arena / block-table width) for
-        # max_len tokens; writes past it would be silently dropped. The
-        # chunked prefill path makes no difference to the worst case —
-        # chunks land in the same reserved pages — so validate the full
-        # footprint here, before the request is queued and long before
-        # any pages are reserved.
+        # The block-table width covers max_len tokens; writes past it
+        # would be silently dropped. The chunked prefill path makes no
+        # difference to the worst case — chunks land in the same
+        # reserved pages — so validate the full footprint here, before
+        # the request is queued and long before any pages are reserved.
         worst = kv.BlockAllocator.worst_case_tokens(len(prompt),
                                                    max_new_tokens)
         if worst > self.max_len:
@@ -561,18 +487,17 @@ class ServingEngine:
                 f"request can occupy {worst} cache positions "
                 f"(prompt {len(prompt)}, max_new {max_new_tokens}) "
                 f"but max_len is {self.max_len}")
-        if self.paged:
-            # Gross worst-case pages must fit the pool: prefix sharing can
-            # only shrink the bill while a sharer happens to be resident,
-            # which admission cannot rely on — such a request would block
-            # the FIFO head forever once the pool drains.
-            need = self.allocator.pages_for(worst)
-            usable = self.allocator.num_pages - 1
-            if need > usable:
-                self.telemetry.count("admission.rejected.over_pool_capacity")
-                raise ValueError(
-                    f"request needs {need} pages worst case but the pool "
-                    f"has {usable}; no reservation was made")
+        # Gross worst-case pages must fit the pool: prefix sharing can
+        # only shrink the bill while a sharer happens to be resident,
+        # which admission cannot rely on — such a request would block
+        # the FIFO head forever once the pool drains.
+        need = self.allocator.pages_for(worst)
+        usable = self.allocator.num_pages - 1
+        if need > usable:
+            self.telemetry.count("admission.rejected.over_pool_capacity")
+            raise ValueError(
+                f"request needs {need} pages worst case but the pool "
+                f"has {usable}; no reservation was made")
         self._uid += 1
         self.queue.append(Request(self._uid, prompt, max_new_tokens,
                                   priority=priority))
@@ -581,8 +506,8 @@ class ServingEngine:
         return self._uid
 
     # -- placement / preemption mechanisms (policy lives in scheduler.py) ---
-    def _place_paged(self, slot: int, req: Request, shared_tokens: int):
-        """Install an admitted request into a paged slot. The allocator
+    def _place(self, slot: int, req: Request, shared_tokens: int):
+        """Install an admitted request into a slot. The allocator
         already mapped its prompt pages; the prompt's KV is produced
         chunk-by-chunk by _prefill_tick. A shared prefix just advances
         the cursor (a fully covered prompt recomputes its last token so
@@ -595,30 +520,9 @@ class ServingEngine:
         self.telemetry.request_admitted(req.uid, slot, shared_tokens)
         self.active[slot] = req
 
-    def _place_dense(self, slot: int, req: Request):
-        """Install a request into a dense slot: whole-prompt prefill in
-        one jitted program, scattered into the slot's arena rows."""
-        tel = self.telemetry
-        tel.request_admitted(req.uid, slot, 0)
-        t0c = tel.now() if tel.enabled else 0.0
-        with tel.annotation("dense_admit_prefill"):
-            self.cache, self.last_logits = self._dense_admit(
-                self.params, jnp.asarray(req.prompt[None]),
-                jnp.int32(slot), self.cache, self.last_logits)
-        if tel.enabled:
-            # Dense admission prefills the whole prompt in one program:
-            # record it as a single chunk span.
-            tel.chunk(req.uid, t0c, tel.now(), len(req.prompt))
-        self.prefill_tokens += len(req.prompt)
-        req.prefill_cursor = len(req.prompt)
-        self._host_len[slot] = len(req.prompt)
-        self.active[slot] = req
-        if self._shape is not None:
-            self._shape.admit_prompt_tokens += len(req.prompt)
-
     def _admit_queued(self, req: Request, slot: int,
                       reserve: bool = True) -> bool:
-        """Try to admit a queued request (paged) into `slot`; False when
+        """Try to admit a queued request into `slot`; False when
         the pool refuses. Used by skip-capable schedulers — `req` need
         not be the queue head."""
         res = self.allocator.admit_tokens(
@@ -626,7 +530,7 @@ class ServingEngine:
         if res is None:
             return False
         self.queue.remove(req)
-        self._place_paged(slot, req, res[1])
+        self._place(slot, req, res[1])
         return True
 
     def _preemptable(self, slot: int) -> bool:
@@ -691,11 +595,11 @@ class ServingEngine:
             req.prefill_cursor = 0
             req.shared_prompt_tokens = 0
             entry = SwappedRequest(req, 0)
-            self.cache = self._kv.clear_slot(self.cache, slot)
+            self.cache = kv.clear_slot(self.cache, slot)
         else:
             n_kv = int(self._host_len[slot])
             ids = a.pages_of(req.uid)
-            self.cache, blob = self._kv.swap_out_slot(
+            self.cache, blob = kv.swap_out_slot(
                 self.cache, slot, ids, n_kv)
             self.swap_tier.put(req.uid, blob)
             entry = SwappedRequest(req, n_kv,
@@ -718,7 +622,7 @@ class ServingEngine:
     def _swap_in(self, entry: SwappedRequest, slot: int,
                  reserve: bool = True) -> bool:
         """Re-admit a preempted request. Aborted mid-prefill entries go
-        through a fresh paged admission (prefill recomputed, prefix
+        through a fresh admission (prefill recomputed, prefix
         cache may re-hit); swapped decoding entries get fresh pages and
         their exact payload restored from the host tier, resuming
         bit-identically. False when the pool refuses."""
@@ -730,7 +634,7 @@ class ServingEngine:
             if res is None:
                 return False
             self.swapped.remove(entry)
-            self._place_paged(slot, req, res[1])
+            self._place(slot, req, res[1])
             self._counters.readmit()
             return True
         n_map = a.pages_for(entry.n_kv)
@@ -740,13 +644,13 @@ class ServingEngine:
         if pages is None:
             return False
         blob = self.swap_tier.pop(req.uid)
-        self.cache = self._kv.swap_in_slot(self.cache, slot, pages, blob)
+        self.cache = kv.swap_in_slot(self.cache, slot, pages, blob)
         if self.mesh is not None:
             # Swap-in scatters a host blob into the pools eagerly;
             # shard_cache is a no-op when propagation kept the mesh
             # placement and a reshard if it drifted — the sharding
             # invariant holds without forking the swap path.
-            self.cache = self._kv.shard_cache(self.cache, self.mesh)
+            self.cache = kv.shard_cache(self.cache, self.mesh)
         self.last_logits = self.last_logits.at[slot].set(
             jnp.asarray(entry.logits))
         self._host_len[slot] = entry.n_kv
@@ -845,7 +749,7 @@ class ServingEngine:
                 page = self.allocator.pages_of(req.uid)[logical]
                 if self.allocator.refcount(page) > 1:
                     old, new = self.allocator.fork_page(req.uid, logical)
-                    self.cache = self._kv.copy_page(self.cache, old, new)
+                    self.cache = kv.copy_page(self.cache, old, new)
             pages = self.allocator.pages_of(req.uid)
             row = np.full((self.cache.block_tables.shape[1],), kv.TRASH_PAGE,
                           np.int32)
@@ -875,8 +779,7 @@ class ServingEngine:
                 tables = tables.at[slot].set(jnp.asarray(row))
                 self.last_logits = self.last_logits.at[slot].set(logits1[0])
                 self._host_len[slot] = end
-            self.cache = self._kv.PagedCache(lengths, tables, nk, nv, nks,
-                                             nvs)
+            self.cache = kv.PagedCache(lengths, tables, nk, nv, nks, nvs)
             self.peak_pages = max(self.peak_pages, self.allocator.used_pages)
             if self._shape is not None:
                 self._shape.chunk = (start, end - start)
@@ -894,14 +797,8 @@ class ServingEngine:
         req.done = True
         self.finished.append(req)
         self.active[slot] = None    # slot released; queue refills next step
-        if self.paged:
-            self.allocator.release(req.uid)
-            self.cache = self._kv.clear_slot(self.cache, slot)
-        else:
-            # Park the slot at length 0 so decode_step stops advancing
-            # it (idle lengths otherwise creep and the slot burns
-            # attention/append work on garbage every step).
-            self.cache.lengths = self.cache.lengths.at[slot].set(0)
+        self.allocator.release(req.uid)
+        self.cache = kv.clear_slot(self.cache, slot)
         if self.drafter is not None:
             self.drafter.release(slot)
         self._host_len[slot] = 0
@@ -924,7 +821,7 @@ class ServingEngine:
                 page = self.allocator.pages_of(req.uid)[logical]
                 if self.allocator.refcount(page) > 1:
                     old, new = self.allocator.fork_page(req.uid, logical)
-                    self.cache = self._kv.copy_page(self.cache, old, new)
+                    self.cache = kv.copy_page(self.cache, old, new)
                     self._repoint(slot, logical, new)
 
     def step(self) -> int:
@@ -968,9 +865,7 @@ class ServingEngine:
                     self._draft_sec - before[2],
                     self._verify_sec - before[3],
                     self._decode_sec - before[4],
-                    a.used_pages if a is not None else 0,
-                    a.free_pages if a is not None else 0,
-                    a.available_pages if a is not None else 0,
+                    a.used_pages, a.free_pages, a.available_pages,
                     len(self.queue),
                     sum(1 for r in self.active
                         if r is not None and r.prefilling),
@@ -983,15 +878,14 @@ class ServingEngine:
         with tel.annotation("admit"):
             self.scheduler.schedule_admissions(self)
         self._admit_sec += time.perf_counter() - t
-        if self.paged:
-            t = time.perf_counter()
-            self._prefill_tick()
-            self._chunk_sec += time.perf_counter() - t
-            if self.scheduler.preemptive:
-                # Optimistic admission: the pool must cover this round's
-                # page extends/forks before sampling (may preempt).
-                with tel.annotation("admit"):
-                    self._ensure_decode_capacity()
+        t = time.perf_counter()
+        self._prefill_tick()
+        self._chunk_sec += time.perf_counter() - t
+        if self.scheduler.preemptive:
+            # Optimistic admission: the pool must cover this round's
+            # page extends/forks before sampling (may preempt).
+            with tel.annotation("admit"):
+                self._ensure_decode_capacity()
         n_prefilling = sum(1 for r in self.active
                            if r is not None and r.prefilling)
         ready = [i for i, r in enumerate(self.active)
@@ -1027,33 +921,31 @@ class ServingEngine:
                     self._release(i, req)
                 else:
                     mask[i] = True
-        if self.paged:
-            # Decode-step boundary: map a fresh page wherever the next
-            # write position falls off the end of a slot's mapped pages
-            # (reservations make this infallible for admitted requests),
-            # and COW-fork any still-shared page the write would land in
-            # so the append cannot leak into other sequences. Mid-prefill
-            # slots are skipped — their device length is 0, so the decode
-            # append lands in the trash page.
-            with tel.annotation("page_map"):
-                for i in range(self.slots):
-                    req = self.active[i]
-                    if req is None or req.prefilling:
-                        continue
-                    self._map_write_range(i, req, int(self._host_len[i]), 1)
-                self.peak_pages = max(self.peak_pages,
-                                      self.allocator.used_pages)
-            if tel.enabled:
-                # Pages the decode kernel's walk DMAs, over every layer
-                # and its window (idle and mid-prefill slots sit at
-                # length 0: one page), against the pages the tables hold.
-                lens, ps = self._host_len + 1, self.allocator.page_size
-                tel.count("decode.pages_walked", sum(
-                    n * paged_k.walk_pages(lens, w, ps)
-                    for w, n in self._layer_windows.items()))
-                tel.count("decode.pages_tabled",
-                          self.cfg.n_layers * self.slots
-                          * self.cache.block_tables.shape[1])
+        # Decode-step boundary: map a fresh page wherever the next write
+        # position falls off the end of a slot's mapped pages
+        # (reservations make this infallible for admitted requests), and
+        # COW-fork any still-shared page the write would land in so the
+        # append cannot leak into other sequences. Mid-prefill slots are
+        # skipped — their device length is 0, so the decode append lands
+        # in the trash page.
+        with tel.annotation("page_map"):
+            for i in range(self.slots):
+                req = self.active[i]
+                if req is None or req.prefilling:
+                    continue
+                self._map_write_range(i, req, int(self._host_len[i]), 1)
+            self.peak_pages = max(self.peak_pages, self.allocator.used_pages)
+        if tel.enabled:
+            # Pages the decode kernel's walk DMAs, over every layer and
+            # its window (idle and mid-prefill slots sit at length 0: one
+            # page), against the pages the tables hold.
+            lens, ps = self._host_len + 1, self.allocator.page_size
+            tel.count("decode.pages_walked", sum(
+                n * paged_k.walk_pages(lens, w, ps)
+                for w, n in self._layer_windows.items()))
+            tel.count("decode.pages_tabled",
+                      self.cfg.n_layers * self.slots
+                      * self.cache.block_tables.shape[1])
         with tel.annotation("decode_step"):
             out = self._decode(self.params, toks, self.cache)
         if self._moe:
@@ -1177,7 +1069,7 @@ class ServingEngine:
             vlogits, nk, nv, nks, nvs = res
         else:
             (vlogits, nk, nv), nks, nvs = res, None, None
-        self.cache = self._kv.PagedCache(
+        self.cache = kv.PagedCache(
             self.cache.lengths, self.cache.block_tables, nk, nv, nks, nvs)
         self.verify_passes += 1
         self.spec_rounds += len(survivors)
@@ -1211,7 +1103,7 @@ class ServingEngine:
                 # now empty return to the free list + reservation.
                 self.allocator.rewind(req.uid, new_len)
                 keep = len(self.allocator.pages_of(req.uid))
-                self.cache = self._kv.rewind_slot(self.cache, i, new_len, keep)
+                self.cache = kv.rewind_slot(self.cache, i, new_len, keep)
                 self._host_len[i] = new_len
                 updates.append((i, a))
                 live += 1
@@ -1226,7 +1118,7 @@ class ServingEngine:
         return live
 
     def _repoint(self, slot: int, logical: int, page: int):
-        self.cache = self._kv.PagedCache(
+        self.cache = kv.PagedCache(
             lengths=self.cache.lengths,
             block_tables=self.cache.block_tables.at[slot, logical].set(page),
             k_pages=self.cache.k_pages,
@@ -1262,12 +1154,11 @@ class ServingEngine:
         tokens_per_pass = its inverse, 1 + the average accepted drafts
         per round. With speculation off every speculative field is 0.
 
-        Phase split (new, backward-compatible additions): step wall
-        time decomposes into admit_sec (admission incl. dense prefill),
-        chunk_prefill_sec (paged prompt chunks), draft_sec (host-side
-        drafting — argmaxes + drafter.propose), verify_sec (the verify
-        forward + acceptance/rollback), and decode_sec (the plain
-        decode path: sampling + page mapping + the decode program).
+        Phase split: step wall time decomposes into admit_sec
+        (admission), chunk_prefill_sec (prompt chunks), draft_sec
+        (host-side drafting — argmaxes + drafter.propose), verify_sec
+        (the verify forward + acceptance/rollback), and decode_sec (the
+        plain decode path: sampling + page mapping + the decode program).
         sec_per_token keeps its historical whole-step definition;
         model_sec_per_token charges only the model-stream phases
         (decode + verify), so host-side draft time no longer inflates
@@ -1319,8 +1210,7 @@ class ServingEngine:
             "swap_ins": self.swap_ins,
             "swapped": len(self.swapped),
             "swap_bytes_peak": self.swap_tier.bytes_peak,
-            "pinned_pages": (self.allocator.pinned_pages
-                             if self.paged else 0),
+            "pinned_pages": self.allocator.pinned_pages,
             "roofline": self._roofline_stats(),
         }
 

@@ -84,7 +84,6 @@ class Scheduler(Protocol):
     Attributes:
       name            short policy id (stats / bench labels)
       preemptive      True enables the engine's capacity-ensure hooks
-                      (and requires paged mode)
       reserve         allocator admission mode for this policy's
                       admissions (True = watermark, False = optimistic)
       pin_budget_pages  prefix-cache pages allowed to survive refcount 0
@@ -128,38 +127,32 @@ class FifoScheduler:
         for slot in range(eng.slots):
             if eng.active[slot] is None and eng.queue:
                 req = eng.queue[0]
-                if eng.paged:
-                    # Watermark admission: worst-case pages (net of any
-                    # shared prefix pages) must be reservable, else the
-                    # whole FIFO waits (no skip — later short requests
-                    # must not starve the head). admit_tokens mutates no
-                    # state on refusal, so a waiting head reserves
-                    # nothing.
-                    res = eng.allocator.admit_tokens(
-                        req.uid, req.prompt, req.max_new_tokens)
-                    if res is None:
-                        # One blocked-step event per engine step the
-                        # FIFO head waits at the watermark (head-of-line
-                        # blocking, visible in the snapshot).
-                        tel.count("admission.blocked_steps")
-                        if not any(r is not None for r in eng.active):
-                            # Nothing holds pages, yet the head still
-                            # doesn't fit: it never will (submit() bounds
-                            # gross worst case, so this is a safety net).
-                            worst = eng.allocator.pages_for(
-                                eng.allocator.worst_case_tokens(
-                                    len(req.prompt), req.max_new_tokens))
-                            raise ValueError(
-                                f"request {req.uid} needs {worst} pages; "
-                                f"pool has {eng.allocator.num_pages - 1}")
-                        break
+                # Watermark admission: worst-case pages (net of any shared
+                # prefix pages) must be reservable, else the whole FIFO
+                # waits (no skip — later short requests must not starve
+                # the head). admit_tokens mutates no state on refusal, so
+                # a waiting head reserves nothing.
+                res = eng.allocator.admit_tokens(
+                    req.uid, req.prompt, req.max_new_tokens)
+                if res is None:
+                    # One blocked-step event per engine step the FIFO head
+                    # waits at the watermark (head-of-line blocking,
+                    # visible in the snapshot).
+                    tel.count("admission.blocked_steps")
+                    if not any(r is not None for r in eng.active):
+                        # Nothing holds pages, yet the head still doesn't
+                        # fit: it never will (submit() bounds gross worst
+                        # case, so this is a safety net).
+                        worst = eng.allocator.pages_for(
+                            eng.allocator.worst_case_tokens(
+                                len(req.prompt), req.max_new_tokens))
+                        raise ValueError(
+                            f"request {req.uid} needs {worst} pages; "
+                            f"pool has {eng.allocator.num_pages - 1}")
+                    break
                 eng.queue.pop(0)
-                if eng.paged:
-                    eng._place_paged(slot, req, res[1])
-                else:
-                    eng._place_dense(slot, req)
-        if eng.paged:
-            eng.peak_pages = max(eng.peak_pages, eng.allocator.used_pages)
+                eng._place(slot, req, res[1])
+        eng.peak_pages = max(eng.peak_pages, eng.allocator.used_pages)
 
     def select_prefill_slot(self, eng, cand: list[tuple[int, int]]) -> int:
         # Strict admission (uid) order: the donor-before-sharer safety

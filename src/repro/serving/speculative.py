@@ -13,7 +13,7 @@ position is committed, so outputs are bit-identical to non-speculative
 greedy decoding — speculation only changes how many target forwards it
 takes to emit them.
 
-Per engine round (ServingEngine(speculative=SpecConfig(...))):
+Per engine round (EngineConfig(speculative=SpecConfig(...))):
 
   1. t0 = argmax(last_logits) — free, no model call (greedy);
   2. the drafter proposes d1..dk continuing after t0;

@@ -232,8 +232,7 @@ class RequestTrace:
     # One entry per emitted token (speculative rounds emit bursts that
     # legitimately share a timestamp).
     token_times: list[float] = dataclasses.field(default_factory=list)
-    # (t0, t1, n_tokens) per prefill chunk (dense admission records its
-    # whole-prompt prefill as one chunk).
+    # (t0, t1, n_tokens) per prefill chunk.
     chunks: list[tuple[float, float, int]] = dataclasses.field(
         default_factory=list)
     # (t0, t1, proposed, accepted) per draft-verify round.

@@ -1,7 +1,7 @@
 """Chunked paged prefill: the prefill-attention kernel vs its oracle,
 direct-to-page chunk writes, chunked prefill == dense prefill at the
 model level, and continuous batching — greedy outputs bit-identical
-across dense prefill, one-shot paged prefill, and chunked prefill at
+across dense generate(), one-shot paged prefill, and chunked prefill at
 several chunk sizes, with prefix sharing on and off, while long prompts
 no longer stall resident decodes."""
 from __future__ import annotations
@@ -20,7 +20,8 @@ from repro.kernels import ops, paged_prefill as pf_k, ref as ref_k
 from repro.kernels.decode_attention import GLOBAL_WINDOW
 from repro.models import api
 from repro.serving import kvcache as kv
-from repro.serving.engine import GenConfig, ServingEngine
+from repro.serving.engine import (EngineConfig, GenConfig, ServingEngine,
+                                  generate)
 import paged_cases
 from repro.serving.quantize import quantize_vec, quantize_vec_int4
 from tolerances import f32_reorder_tol
@@ -369,15 +370,14 @@ def _workload(cfg):
 
 def _drain_outputs(params, cfg, prompts, new, **kw):
     gen = GenConfig(temperature=0.0, stop_on_eos=False)
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen,
-                        **kw)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen, **kw))
     uids = [eng.submit(p.copy(), max_new_tokens=n)
             for p, n in zip(prompts, new)]
     done = eng.run(max_steps=600)
     assert sorted(r.uid for r in done) == sorted(uids)
     by = {r.uid: r.generated for r in done}
-    if eng.paged:
-        assert eng.allocator.used_pages == 0
+    assert eng.allocator.used_pages == 0
     return [by[u] for u in uids], eng
 
 
@@ -385,7 +385,14 @@ def _drain_outputs(params, cfg, prompts, new, **kw):
 def serving_env():
     cfg, params = _setup()
     prompts, new = _workload(cfg)
-    ref, _ = _drain_outputs(params, cfg, prompts, new)       # dense
+    # Reference: whole-batch generate() over the dense Cache, one
+    # request at a time — independent of the page pool.
+    ref = []
+    for p, n in zip(prompts, new):
+        toks, _ = generate(params, jnp.asarray(p[None]), cfg, ENGINE,
+                           GenConfig(max_new_tokens=n, temperature=0.0,
+                                     stop_on_eos=False))
+        ref.append([int(t) for t in np.asarray(toks[0])])
     return cfg, params, prompts, new, ref
 
 
@@ -393,11 +400,11 @@ def serving_env():
 @pytest.mark.parametrize("chunk", [None, 4, 8, 5])
 def test_serving_bit_identical_dense_oneshot_chunked(serving_env, sharing,
                                                      chunk):
-    """Acceptance: greedy outputs bit-identical across dense prefill,
+    """Acceptance: greedy outputs bit-identical across dense generate(),
     one-shot paged prefill (chunk=None), and chunked prefill at chunk
     sizes {page, 2*page, odd non-divisor}, with prefix sharing on/off."""
     cfg, params, prompts, new, ref = serving_env
-    out, eng = _drain_outputs(params, cfg, prompts, new, paged=True,
+    out, eng = _drain_outputs(params, cfg, prompts, new,
                               page_size=4, prefix_sharing=sharing,
                               prefill_chunk_tokens=chunk)
     assert out == ref
@@ -420,8 +427,8 @@ def test_long_prompt_does_not_stall_resident_decode():
     long_prompt = rng.randint(2, cfg.vocab, size=16)
     chunk = 4
 
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen,
-                        paged=True, page_size=4, prefill_chunk_tokens=chunk)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen, page_size=4, prefill_chunk_tokens=chunk))
     u_res = eng.submit(res_prompt.copy(), max_new_tokens=12)
     eng.step()                       # resident admitted + first token
     res = next(r for r in eng.active if r is not None and r.uid == u_res)
@@ -445,8 +452,8 @@ def test_long_prompt_does_not_stall_resident_decode():
 
     solo = {}
     for p, n, u in [(res_prompt, 12, u_res), (long_prompt, 2, u_long)]:
-        e2 = ServingEngine(params, cfg, ENGINE, slots=1, max_len=32,
-                           gen=gen, paged=True, page_size=4)
+        e2 = ServingEngine(params, cfg, ENGINE, EngineConfig(
+            slots=1, max_len=32, gen=gen, page_size=4))
         e2.submit(p.copy(), max_new_tokens=n)
         (r2,) = e2.run(max_steps=200)
         solo[u] = r2.generated
@@ -465,7 +472,7 @@ def test_sharer_admitted_during_donor_prefill_is_correct():
     prompts = [np.concatenate([prefix, rng.randint(2, cfg.vocab, size=2)]),
                np.concatenate([prefix, rng.randint(2, cfg.vocab, size=3)])]
     new = [5, 6]
-    kw = dict(paged=True, page_size=4, prefill_chunk_tokens=4)
+    kw = dict(page_size=4, prefill_chunk_tokens=4)
     out_off, _ = _drain_outputs(params, cfg, prompts, new,
                                 prefix_sharing=False, **kw)
     out_on, eng = _drain_outputs(params, cfg, prompts, new,
@@ -478,22 +485,13 @@ def test_sharer_admitted_during_donor_prefill_is_correct():
 # Admission-control regressions
 # ---------------------------------------------------------------------------
 
-def test_chunk_budget_requires_paged_backend():
-    """The dense backend cannot honor a chunk budget; silently ignoring
-    it would fake a latency bound that is not enforced."""
-    cfg, params = _setup()
-    with pytest.raises(ValueError, match="paged"):
-        ServingEngine(params, cfg, ENGINE, slots=1, max_len=32,
-                      prefill_chunk_tokens=8)
-
-
 def test_oversized_submit_leaves_engine_unscathed():
     """An oversized submit must be rejected before queueing or reserving
     anything; requests around it are unaffected."""
     cfg, params = _setup()
     gen = GenConfig(temperature=0.0, stop_on_eos=False)
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen,
-                        paged=True, page_size=4, num_pages=7)  # 6 usable
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen, page_size=4, num_pages=7))  # 6 usable
     u1 = eng.submit(np.arange(2, 8), max_new_tokens=3)
     with pytest.raises(ValueError, match="pages"):
         # 20 + 10 - 1 = 29 <= max_len but 8 pages > 6 usable.
@@ -512,8 +510,8 @@ def test_waiting_queue_head_reserves_nothing():
     shrink the pool for the resident request and deadlock the drain)."""
     cfg, params = _setup()
     gen = GenConfig(temperature=0.0, stop_on_eos=False)
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen,
-                        paged=True, page_size=4, num_pages=7)  # 6 usable
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen, page_size=4, num_pages=7))  # 6 usable
     u1 = eng.submit(np.arange(2, 10), max_new_tokens=9)   # worst 4 pages
     u2 = eng.submit(np.arange(20, 28), max_new_tokens=9)  # no shared prefix
     eng.step()

@@ -187,15 +187,12 @@ def test_engine_config_hardware_validation():
 
 def test_from_configs_resolves_hardware_and_dtype():
     cfg, _ = _setup()
-    ec = EngineConfig(slots=2, max_len=32, paged=True, page_size=8,
+    ec = EngineConfig(slots=2, max_len=32, page_size=8,
                       kv_cache_dtype="int8", hardware="salpim-hbm2")
     cm = CostModel.from_configs(cfg, ec)
     assert cm.hardware.name == "salpim-hbm2"
     assert cm.kv_dtype == "int8"
     assert cm.page_size == 8
-    # Dense engines model un-paged (page_size 1 = exact-length) reads.
-    cm_dense = CostModel.from_configs(cfg, EngineConfig(slots=2, max_len=32))
-    assert cm_dense.page_size == 1
 
 
 # -- mesh ------------------------------------------------------------------
@@ -231,7 +228,7 @@ def test_engine_accumulates_costs_telemetry_off():
     cfg, params = _setup()
     eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
         slots=2, max_len=32, gen=GenConfig(stop_on_eos=False),
-        paged=True, page_size=8))
+        page_size=8))
     assert eng.cost_model.page_bytes == eng.page_bytes
     _drain(eng, _reqs(cfg))
     # Costs accumulate with telemetry disabled (always-on, so the
@@ -248,7 +245,7 @@ def test_engine_snapshot_roofline_phases():
     tel = Telemetry(enabled=True)
     eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
         slots=2, max_len=32, gen=GenConfig(stop_on_eos=False),
-        paged=True, page_size=8, prefill_chunk_tokens=8, telemetry=tel))
+        page_size=8, prefill_chunk_tokens=8, telemetry=tel))
     _drain(eng, _reqs(cfg))
     roof = tel.snapshot()["roofline"]
     assert roof["hardware"]["name"] in HARDWARE_SPECS
@@ -269,7 +266,7 @@ def test_kv_splits_change_time_not_modeled_bytes():
     for splits in (None, 4):
         eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
             slots=2, max_len=32, gen=GenConfig(stop_on_eos=False),
-            paged=True, page_size=8, kv_splits=splits))
+            page_size=8, kv_splits=splits))
         outs[splits] = _drain(eng, _reqs(cfg))
         mods[splits] = {p: v["modeled_bytes"]
                         for p, v in eng.stats()["roofline"].items()}

@@ -1,11 +1,11 @@
-"""EngineConfig: the consolidated `ServingEngine` configuration object,
-its single-place validation, the legacy-kwargs deprecation shim
-(bit-identical drains, one warning per process), and the
+"""EngineConfig: the one `ServingEngine` configuration object, its
+single-place validation, the benchmark's call form, and the
 `repro.serving` facade exports."""
 from __future__ import annotations
 
 import dataclasses
-import warnings
+import json
+import os
 
 import jax
 import numpy as np
@@ -19,9 +19,12 @@ from repro.serving import EngineConfig, GenConfig, ServingEngine
 from repro.serving import config as config_mod
 from repro.serving import engine as engine_mod
 from repro.serving.scheduler import SloScheduler
+from repro.serving.speculative import SpecConfig
+from repro.serving.telemetry import Telemetry
 
 ENGINE = SalPimEngine.create(SalPimConfig())
 KEY = jax.random.PRNGKey(0)
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _setup(arch="gpt2_medium"):
@@ -45,19 +48,13 @@ def _drain(eng, prompts, new):
     return [by[u] for u in uids]
 
 
-@pytest.fixture
-def fresh_warning_state(monkeypatch):
-    """Reset the once-per-process deprecation latch for this test."""
-    monkeypatch.setattr(config_mod, "_legacy_warned", False)
-
-
 # ---------------------------------------------------------------------------
 # The dataclass itself
 # ---------------------------------------------------------------------------
 
 def test_config_is_frozen_value_type():
-    cfg = EngineConfig(slots=2, max_len=32, paged=True, page_size=8)
-    assert cfg == EngineConfig(slots=2, max_len=32, paged=True, page_size=8)
+    cfg = EngineConfig(slots=2, max_len=32, page_size=8)
+    assert cfg == EngineConfig(slots=2, max_len=32, page_size=8)
     with pytest.raises(dataclasses.FrozenInstanceError):
         cfg.slots = 4
     # replace() is the supported way to derive variants.
@@ -65,50 +62,18 @@ def test_config_is_frozen_value_type():
     assert small.page_size == 4 and small.slots == 2 and cfg.page_size == 8
 
 
-def test_config_defaults_match_historical_kwarg_defaults(
-        fresh_warning_state):
-    """from_legacy_kwargs with only the required args lands on the same
-    config as the bare constructor — the shim default table and the
-    dataclass defaults cannot drift apart."""
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        via_shim = EngineConfig.from_legacy_kwargs(slots=2, max_len=32)
-    assert via_shim == EngineConfig(slots=2, max_len=32)
-    assert via_shim.gen == GenConfig()
-    assert via_shim.paged is False and via_shim.page_size == 16
-    assert via_shim.kv_scale_dtype == "float32" and via_shim.seed == 0
-    assert via_shim.mesh is None
-
-
-def test_missing_slots_or_max_len_raises(fresh_warning_state):
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        with pytest.raises(TypeError, match="slots= and max_len="):
-            EngineConfig.from_legacy_kwargs(slots=2)
-        with pytest.raises(TypeError, match="slots= and max_len="):
-            EngineConfig.from_legacy_kwargs(max_len=32)
-
-
 def test_resolved_kv_dtype_defers_to_model_config():
     cfg, _ = _setup()
     assert EngineConfig(slots=1, max_len=16).resolved_kv_dtype(cfg) \
         == cfg.kv_dtype
-    assert EngineConfig(slots=1, max_len=16, paged=True,
+    assert EngineConfig(slots=1, max_len=16,
                         kv_cache_dtype="int8").resolved_kv_dtype(cfg) \
         == "int8"
 
 
 # ---------------------------------------------------------------------------
-# Validation: one place, every construction path
+# Validation and construction
 # ---------------------------------------------------------------------------
-
-def test_validate_mesh_requires_paged():
-    cfg, params = _setup()
-    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("model",))
-    with pytest.raises(ValueError, match="mesh sharding requires paged"):
-        ServingEngine(params, cfg, ENGINE, EngineConfig(
-            slots=1, max_len=16, mesh=mesh))
-
 
 @pytest.mark.skipif(len(jax.devices()) < 8, reason="needs 8 devices")
 def test_validate_mesh_width_must_divide_kv_heads():
@@ -116,83 +81,99 @@ def test_validate_mesh_width_must_divide_kv_heads():
     mesh = jax.sharding.Mesh(np.array(jax.devices()[:8]), ("model",))
     with pytest.raises(ValueError, match="must divide"):
         ServingEngine(params, cfg, ENGINE, EngineConfig(
-            slots=1, max_len=16, paged=True, mesh=mesh))
+            slots=1, max_len=16, mesh=mesh))
 
 
-def test_validation_identical_through_both_paths(fresh_warning_state):
-    """The same rule fires with the same message whether the engine is
-    built from an EngineConfig or from legacy kwargs."""
+def test_config_requires_slots_and_max_len():
+    with pytest.raises(TypeError):
+        EngineConfig()
+    with pytest.raises(TypeError):
+        EngineConfig(slots=2)
+
+
+def test_paged_false_raises():
+    """The dense per-slot arena is gone; the inert flag refuses False
+    when the config is built and is not stored."""
+    with pytest.raises(ValueError, match="dense per-slot KV arena"):
+        EngineConfig(slots=1, max_len=16, paged=False)
+    ec = EngineConfig(slots=1, max_len=16, paged=True)
+    assert ec == EngineConfig(slots=1, max_len=16)
+    assert "paged" not in {f.name for f in dataclasses.fields(ec)}
+
+
+def test_engine_takes_only_a_config():
+    """One constructor: (params, model, engine, config) and nothing else."""
     cfg, params = _setup()
-    msgs = []
-    for build in (
-        lambda: ServingEngine(params, cfg, ENGINE, EngineConfig(
-            slots=1, max_len=16, prefill_chunk_tokens=4)),
-        lambda: ServingEngine(params, cfg, ENGINE, slots=1, max_len=16,
-                              prefill_chunk_tokens=4),
-    ):
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            with pytest.raises(ValueError) as ei:
-                build()
-        msgs.append(str(ei.value))
-    assert msgs[0] == msgs[1]
-    assert "prefill_chunk_tokens requires paged=True" in msgs[0]
-
-
-def test_preemptive_scheduler_validation_via_config():
-    cfg, params = _setup()
-    with pytest.raises(ValueError, match="preemptive scheduling requires"):
-        ServingEngine(params, cfg, ENGINE, EngineConfig(
-            slots=1, max_len=16, scheduler=SloScheduler()))
-
-
-# ---------------------------------------------------------------------------
-# Deprecation shim
-# ---------------------------------------------------------------------------
-
-def test_config_and_legacy_kwargs_are_mutually_exclusive():
-    cfg, params = _setup()
-    with pytest.raises(TypeError, match="not both"):
+    with pytest.raises(TypeError):
+        ServingEngine(params, cfg, ENGINE, slots=1, max_len=16)
+    with pytest.raises(TypeError):
+        ServingEngine(params, cfg, ENGINE)
+    with pytest.raises(TypeError):
         ServingEngine(params, cfg, ENGINE,
                       EngineConfig(slots=1, max_len=16), slots=1)
 
 
-def test_legacy_kwargs_warn_exactly_once_per_process(fresh_warning_state):
+@pytest.mark.parametrize("features", [
+    {"scheduler": SloScheduler()},
+    {"prefill_chunk_tokens": 4},
+    {"kv_splits": 2},
+    {"speculative": SpecConfig(mode="ngram", k=3)},
+    {"kv_cache_dtype": "int8"},
+    {"kv_cache_dtype": "int4", "kv_scale_dtype": "bfloat16"},
+], ids=["slo", "chunked", "kv_splits", "ngram_spec", "int8", "int4"])
+def test_features_serve_from_the_default_config(features):
+    """Every feature that once needed a backend switch builds from the
+    bare config and drains to completion with the pool emptied."""
     cfg, params = _setup()
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        ServingEngine(params, cfg, ENGINE, slots=1, max_len=16)
-        ServingEngine(params, cfg, ENGINE, slots=1, max_len=16, paged=True)
-        ServingEngine(params, cfg, ENGINE,
-                      EngineConfig(slots=1, max_len=16))
-    dep = [w for w in caught if issubclass(w.category, DeprecationWarning)
-           and "EngineConfig" in str(w.message)]
-    assert len(dep) == 1, [str(w.message) for w in caught]
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32,
+        gen=GenConfig(temperature=0.0, stop_on_eos=False), **features))
+    prompts, new = _workload(cfg, n=2)
+    outs = _drain(eng, prompts, new)
+    assert [len(o) for o in outs] == new
+    assert eng.allocator.used_pages == 0
 
 
-def test_legacy_and_config_engines_drain_bit_identically(
-        fresh_warning_state):
-    """The shim folds kwargs into the exact config the new API takes:
-    both constructions serve the same workload to the same tokens."""
-    cfg, params = _setup()
-    prompts, new = _workload(cfg)
-    gen = GenConfig(temperature=0.0, stop_on_eos=False)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        legacy = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32,
-                               gen=gen, paged=True, page_size=8,
-                               kv_cache_dtype="int8",
-                               prefill_chunk_tokens=6)
-    modern = ServingEngine(params, cfg, ENGINE, EngineConfig(
-        slots=2, max_len=32, gen=gen, paged=True, page_size=8,
-        kv_cache_dtype="int8", prefill_chunk_tokens=6))
-    assert legacy.config == modern.config
-    assert _drain(legacy, prompts, new) == _drain(modern, prompts, new)
+def _bench_cell(mix):
+    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
+        bench = json.load(fh)
+    work = next(w for w in bench["workloads"] if w["traffic"] == mix)
+    conf = next(c for c in bench["configs"] if c["name"] == work["config"])
+    with open(os.path.join(REPO, conf["file"])) as fh:
+        model = json.load(fh)
+    with open(os.path.join(REPO, "bench", "traffic", mix + ".json")) as fh:
+        traffic = json.load(fh)
+    return work["config"], model["bench"]["nonlinear_mode"], traffic
+
+
+@pytest.mark.parametrize("mix", ["paper_poisson", "longdoc_closed16",
+                                 "chat_closed32"])
+def test_bench_call_form_builds_and_drains(mix):
+    """The benchmark builds its engine with exactly this call (bench/run.py
+    `Serve.__init__`): the paged flag, greedy no-EOS generation, annotated
+    telemetry and the traffic file's engine block, at the cell's published
+    max_len. Here the model is its family's smoke config and one short
+    request drains."""
+    name, mode, t = _bench_cell(mix)
+    cfg = get_config(name, smoke=True)
+    params = api.init_params(KEY, cfg)
+    engine = SalPimEngine.create(SalPimConfig(nonlinear_mode=mode))
+    eng = ServingEngine(params, cfg, engine, EngineConfig(
+        paged=True, gen=GenConfig(temperature=0.0, stop_on_eos=False),
+        telemetry=Telemetry(enabled=True, annotate=True), **t["engine"]))
+    eng.submit(np.arange(2, 10), max_new_tokens=3)
+    done = eng.run(max_steps=50)
+    assert len(done) == 1 and len(done[0].generated) == 3
+    stats = eng.stats()
+    assert stats["prefill_tokens"] == 8 and stats["tokens"] == 3
+    assert stats["peak_pages"] >= 1 and eng.allocator.used_pages == 0
+    assert not eng.queue and all(r is None for r in eng.active)
+    assert eng.last_logits.shape == (t["engine"]["slots"], cfg.vocab)
 
 
 def test_engine_exposes_its_config():
     cfg, params = _setup()
-    ec = EngineConfig(slots=2, max_len=32, paged=True, page_size=8)
+    ec = EngineConfig(slots=2, max_len=32, page_size=8)
     eng = ServingEngine(params, cfg, ENGINE, ec)
     assert eng.config is ec
     assert eng.mesh is None

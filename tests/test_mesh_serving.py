@@ -53,8 +53,7 @@ def _drain(params, cfg, prompts, new, priorities=None, engine=ENGINE, **kw):
     kw.setdefault("slots", 2)
     kw.setdefault("max_len", 32)
     kw.setdefault("page_size", 8)
-    eng = ServingEngine(params, cfg, engine,
-                        EngineConfig(paged=True, **kw))
+    eng = ServingEngine(params, cfg, engine, EngineConfig(**kw))
     prios = priorities or [0] * len(prompts)
     uids = [eng.submit(p.copy(), max_new_tokens=n, priority=pr)
             for p, n, pr in zip(prompts, new, prios)]
@@ -299,4 +298,4 @@ def test_nondividing_width_rejected_up_front():
         pytest.skip("needs >= 4 devices")
     with pytest.raises(ValueError, match="must divide"):
         ServingEngine(params, cfg, ENGINE, EngineConfig(
-            slots=1, max_len=16, paged=True, mesh=_mesh(4)))
+            slots=1, max_len=16, mesh=_mesh(4)))

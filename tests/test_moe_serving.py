@@ -82,7 +82,7 @@ def smoke():
 
 def _engine(params, cfg, slots, chunk=8, telemetry=None, **kw):
     return ServingEngine(params, cfg, ENGINE, EngineConfig(
-        slots=slots, max_len=64, paged=True, page_size=8,
+        slots=slots, max_len=64, page_size=8,
         prefill_chunk_tokens=chunk, gen=GREEDY, telemetry=telemetry, **kw))
 
 

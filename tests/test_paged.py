@@ -14,7 +14,8 @@ from repro.core.salpim import SalPimConfig, SalPimEngine
 from repro.kernels import ops, ref as ref_k
 from repro.models import api
 from repro.serving import kvcache as kv
-from repro.serving.engine import GenConfig, ServingEngine, generate
+from repro.serving.engine import (EngineConfig, GenConfig, ServingEngine,
+                                  generate)
 
 import paged_cases
 
@@ -337,8 +338,8 @@ def test_paged_continuous_batching_matches_batch_generate():
     gen = GenConfig(max_new_tokens=5, temperature=0.0, stop_on_eos=False)
     ref, _ = generate(params, jnp.asarray(prompts), cfg, ENGINE, gen)
 
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen,
-                        paged=True, page_size=4)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen, page_size=4))
     uids = [eng.submit(prompts[i], max_new_tokens=5) for i in range(3)]
     done = eng.run(max_steps=200)
     assert len(done) == 3
@@ -362,8 +363,8 @@ def test_paged_engine_under_page_pressure():
         gen)
     prompts = np.asarray(jax.random.randint(KEY, (4, 8), 2, cfg.vocab))
     # Enough pages for ~1.3 worst-case requests -> strictly serialized.
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen,
-                        paged=True, page_size=4, num_pages=6)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen, page_size=4, num_pages=6))
     uids = [eng.submit(prompts[i], max_new_tokens=4) for i in range(4)]
     done = eng.run(max_steps=400)
     assert sorted(r.uid for r in done) == sorted(uids)
@@ -382,8 +383,8 @@ def test_oversized_request_raises_instead_of_spinning():
     are reserved — instead of blocking the FIFO head forever."""
     cfg = get_config("gpt2_medium", smoke=True)
     params = api.init_params(KEY, cfg)
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32,
-                        paged=True, page_size=4, num_pages=4)  # 3 usable
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, page_size=4, num_pages=4))  # 3 usable
     # Fits max_len (10 + 10 - 1 = 19 <= 32) but needs 5 pages > pool.
     with pytest.raises(ValueError, match="pages"):
         eng.submit(np.arange(2, 12), max_new_tokens=10)
@@ -397,24 +398,22 @@ def test_exact_fit_request_is_served():
     cfg = get_config("gpt2_medium", smoke=True)
     params = api.init_params(KEY, cfg)
     gen = GenConfig(max_new_tokens=7, temperature=0.0, stop_on_eos=False)
-    for kwargs in ({}, {"paged": True, "page_size": 4}):
-        eng = ServingEngine(params, cfg, ENGINE, slots=1, max_len=16,
-                            gen=gen, **kwargs)
-        eng.submit(np.arange(2, 12), max_new_tokens=7)  # worst 16 == max_len
-        (req,) = eng.run(max_steps=100)
-        assert len(req.generated) == 7
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=1, max_len=16, gen=gen, page_size=4))
+    eng.submit(np.arange(2, 12), max_new_tokens=7)  # worst 16 == max_len
+    (req,) = eng.run(max_steps=100)
+    assert len(req.generated) == 7
 
 
 def test_submit_rejects_requests_past_max_len():
-    """Writes past max_len would be silently dropped (dense arena and
-    paged block table are both sized for max_len) — reject at submit."""
+    """Writes past max_len would be silently dropped (the block table is
+    sized for max_len) — reject at submit."""
     cfg = get_config("gpt2_medium", smoke=True)
     params = api.init_params(KEY, cfg)
-    for kwargs in ({}, {"paged": True, "page_size": 4}):
-        eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=16,
-                            **kwargs)
-        with pytest.raises(ValueError, match="max_len"):
-            eng.submit(np.arange(2, 12), max_new_tokens=10)  # 21 > 16
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=16, page_size=4))
+    with pytest.raises(ValueError, match="max_len"):
+        eng.submit(np.arange(2, 12), max_new_tokens=10)  # 21 > 16
 
 
 def test_run_returns_requests_admitted_before_call():
@@ -423,7 +422,8 @@ def test_run_returns_requests_admitted_before_call():
     cfg = get_config("gpt2_medium", smoke=True)
     params = api.init_params(KEY, cfg)
     gen = GenConfig(max_new_tokens=3, temperature=0.0, stop_on_eos=False)
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen))
     u1 = eng.submit(np.arange(2, 8), max_new_tokens=3)
     eng.step()          # admits u1 into a slot, decodes once
     u2 = eng.submit(np.arange(2, 6), max_new_tokens=3)
@@ -437,7 +437,8 @@ def test_sampling_key_advances_between_steps():
     params = api.init_params(KEY, cfg)
     gen = GenConfig(max_new_tokens=24, temperature=1.5, top_k=0,
                     stop_on_eos=False)
-    eng = ServingEngine(params, cfg, ENGINE, slots=1, max_len=64, gen=gen)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=1, max_len=64, gen=gen))
     eng.submit(np.arange(2, 10), max_new_tokens=24)
     (req,) = eng.run(max_steps=100)
     # With a frozen key the chain tok->logits->tok collapses to a cycle of

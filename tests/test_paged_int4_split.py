@@ -229,12 +229,11 @@ def test_int4_validation_rules():
     # bytes the packing saved).
     with pytest.raises(ValueError, match="bfloat16"):
         ServingEngine(params, cfg, ENGINE, config=EngineConfig(
-            slots=1, max_len=16, paged=True, kv_cache_dtype="int4"))
+            slots=1, max_len=16, kv_cache_dtype="int4"))
     # Odd head_dim cannot nibble-pack.
     odd = dataclasses.replace(cfg, head_dim=cfg.head_dim + 1)
     with pytest.raises(ValueError, match="even head_dim"):
-        EngineConfig(slots=1, max_len=16, paged=True,
-                     kv_cache_dtype="int4",
+        EngineConfig(slots=1, max_len=16, kv_cache_dtype="int4",
                      kv_scale_dtype="bfloat16").validate(odd)
     with pytest.raises(ValueError, match="even head_dim"):
         kv.init_paged_cache(odd, 1, 4, 4, 2, kv_dtype="int4",
@@ -247,9 +246,9 @@ def test_int4_default_pool_at_least_3_5x_capacity():
     cfg = get_config("gpt2_medium", smoke=True)
     params = api.init_params(KEY, cfg)
     engf = ServingEngine(params, cfg, ENGINE, config=EngineConfig(
-        slots=4, max_len=32, paged=True, page_size=4))
+        slots=4, max_len=32, page_size=4))
     eng4 = ServingEngine(params, cfg, ENGINE, config=EngineConfig(
-        slots=4, max_len=32, paged=True, page_size=4,
+        slots=4, max_len=32, page_size=4,
         kv_cache_dtype="int4", kv_scale_dtype="bfloat16"))
     usable_f = engf.allocator.num_pages - 1
     usable_4 = eng4.allocator.num_pages - 1
@@ -327,9 +326,8 @@ def test_int4_serving_greedy_exact_match(chunk):
     cfg = get_config("gpt2_medium", smoke=True)
     params = api.init_params(KEY, cfg)
     prompts, new = _int4_workload(cfg)
-    ref, _ = _drain_outputs(params, cfg, prompts, new, paged=True,
-                            page_size=4)
-    out, eng = _drain_outputs(params, cfg, prompts, new, paged=True,
+    ref, _ = _drain_outputs(params, cfg, prompts, new, page_size=4)
+    out, eng = _drain_outputs(params, cfg, prompts, new,
                               page_size=4, prefill_chunk_tokens=chunk,
                               kv_cache_dtype="int4",
                               kv_scale_dtype="bfloat16")
@@ -501,12 +499,9 @@ def test_kv_splits_validation_and_engine_threading():
     cfg = get_config("gpt2_medium", smoke=True)
     params = api.init_params(KEY, cfg)
     with pytest.raises(ValueError, match="kv_splits"):
-        EngineConfig(slots=1, max_len=16, paged=True,
-                     kv_splits=0).validate(cfg)
-    with pytest.raises(ValueError, match="paged"):
-        EngineConfig(slots=1, max_len=16, kv_splits=4).validate(cfg)
+        EngineConfig(slots=1, max_len=16, kv_splits=0).validate(cfg)
     eng = ServingEngine(params, cfg, ENGINE, config=EngineConfig(
-        slots=1, max_len=16, paged=True, kv_splits=4))
+        slots=1, max_len=16, kv_splits=4))
     assert eng.engine.config.kv_splits == 4
 
 
@@ -517,9 +512,8 @@ def test_kv_splits_engine_drain_matches_baseline():
     cfg = get_config("gpt2_medium", smoke=True)
     params = api.init_params(KEY, cfg)
     prompts, new = _int4_workload(cfg)
-    base, _ = _drain_outputs(params, cfg, prompts, new, paged=True,
-                             page_size=4)
-    out, eng = _drain_outputs(params, cfg, prompts, new, paged=True,
+    base, _ = _drain_outputs(params, cfg, prompts, new, page_size=4)
+    out, eng = _drain_outputs(params, cfg, prompts, new,
                               page_size=4, kv_splits=8)
     assert eng.engine.config.kv_splits == 8
     assert out == base

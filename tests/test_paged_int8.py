@@ -15,7 +15,7 @@ from repro.core.salpim import SalPimConfig, SalPimEngine
 from repro.kernels import ops, ref as ref_k
 from repro.models import api
 from repro.serving import kvcache as kv
-from repro.serving.engine import GenConfig, ServingEngine
+from repro.serving.engine import EngineConfig, GenConfig, ServingEngine
 from repro.serving.quantize import dequantize_vec, quantize_vec
 
 import paged_cases
@@ -283,10 +283,10 @@ def test_int8_default_pool_doubles_capacity_at_fixed_bytes():
     worst-case reservations before refusing — and still refuse then."""
     cfg = get_config("gpt2_medium", smoke=True)
     params = api.init_params(KEY, cfg)
-    engf = ServingEngine(params, cfg, ENGINE, slots=4, max_len=32,
-                         paged=True, page_size=4)
-    eng8 = ServingEngine(params, cfg, ENGINE, slots=4, max_len=32,
-                         paged=True, page_size=4, kv_cache_dtype="int8")
+    engf = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=4, max_len=32, page_size=4))
+    eng8 = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=4, max_len=32, page_size=4, kv_cache_dtype="int8"))
     usable_f = engf.allocator.num_pages - 1
     usable_8 = eng8.allocator.num_pages - 1
     assert usable_8 >= 1.8 * usable_f, (usable_8, usable_f)
@@ -318,11 +318,8 @@ def test_kv_cache_dtype_validation():
     cfg = get_config("gpt2_medium", smoke=True)
     params = api.init_params(KEY, cfg)
     with pytest.raises(ValueError, match="kv_cache_dtype"):
-        ServingEngine(params, cfg, ENGINE, slots=1, max_len=16,
-                      paged=True, kv_cache_dtype="fp4")
-    with pytest.raises(ValueError, match="paged"):
-        ServingEngine(params, cfg, ENGINE, slots=1, max_len=16,
-                      kv_cache_dtype="int8")
+        ServingEngine(params, cfg, ENGINE, EngineConfig(
+            slots=1, max_len=16, kv_cache_dtype="fp4"))
 
 
 # ---------------------------------------------------------------------------
@@ -341,14 +338,13 @@ def _workload(cfg):
 
 def _drain_outputs(params, cfg, prompts, new, **kw):
     gen = GenConfig(temperature=0.0, stop_on_eos=False)
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen,
-                        **kw)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen, **kw))
     uids = [eng.submit(p.copy(), max_new_tokens=n)
             for p, n in zip(prompts, new)]
     done = eng.run(max_steps=600)
     assert sorted(r.uid for r in done) == sorted(uids)
-    if eng.paged:
-        assert eng.allocator.used_pages == 0
+    assert eng.allocator.used_pages == 0
     by = {r.uid: r.generated for r in done}
     return [by[u] for u in uids], eng
 
@@ -362,9 +358,8 @@ def test_int8_serving_greedy_exact_match(arch):
     cfg = get_config(arch, smoke=True)
     params = api.init_params(KEY, cfg)
     prompts, new = _workload(cfg)
-    ref, _ = _drain_outputs(params, cfg, prompts, new, paged=True,
-                            page_size=4)
-    out, eng = _drain_outputs(params, cfg, prompts, new, paged=True,
+    ref, _ = _drain_outputs(params, cfg, prompts, new, page_size=4)
+    out, eng = _drain_outputs(params, cfg, prompts, new,
                               page_size=4, kv_cache_dtype="int8")
     assert eng.cache.k_pages.dtype == jnp.int8 and eng.cache.quantized
     assert out == ref
@@ -379,10 +374,10 @@ def test_int8_serving_invariants_hold(sharing, chunk):
     cfg = get_config("gpt2_medium", smoke=True)
     params = api.init_params(KEY, cfg)
     prompts, new = _workload(cfg)
-    base, _ = _drain_outputs(params, cfg, prompts, new, paged=True,
+    base, _ = _drain_outputs(params, cfg, prompts, new,
                              page_size=4, prefix_sharing=False,
                              kv_cache_dtype="int8")
-    out, eng = _drain_outputs(params, cfg, prompts, new, paged=True,
+    out, eng = _drain_outputs(params, cfg, prompts, new,
                               page_size=4, prefix_sharing=sharing,
                               prefill_chunk_tokens=chunk,
                               kv_cache_dtype="int8")
@@ -403,10 +398,10 @@ def test_int8_fork_survives_donor_release_and_page_reuse():
     rng = np.random.RandomState(11)
     prefix = rng.randint(2, cfg.vocab, size=8)       # exactly 2 pages
     other = rng.randint(2, cfg.vocab, size=9)
-    kw = dict(slots=2, max_len=32, gen=gen, paged=True, page_size=4,
+    kw = dict(slots=2, max_len=32, gen=gen, page_size=4,
               kv_cache_dtype="int8")
 
-    eng = ServingEngine(params, cfg, ENGINE, **kw)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(**kw))
     u_donor = eng.submit(prefix.copy(), max_new_tokens=2)
     u_fork = eng.submit(prefix.copy(), max_new_tokens=12)  # fully covered
     eng.step()
@@ -423,7 +418,7 @@ def test_int8_fork_survives_donor_release_and_page_reuse():
     by = {r.uid: r.generated for r in done}
     solo = {}
     for p, n, u in [(prefix, 2, u_donor), (prefix, 12, u_fork)]:
-        e2 = ServingEngine(params, cfg, ENGINE, **kw)
+        e2 = ServingEngine(params, cfg, ENGINE, EngineConfig(**kw))
         e2.submit(p.copy(), max_new_tokens=n)
         (r2,) = e2.run(max_steps=100)
         solo[u] = r2.generated
@@ -548,16 +543,15 @@ def test_kv_scale_dtype_engine_validation():
     cfg = get_config("gpt2_medium", smoke=True)
     params = api.init_params(KEY, cfg)
     with pytest.raises(ValueError, match="scale"):
-        ServingEngine(params, cfg, ENGINE, slots=1, max_len=16,
-                      paged=True, kv_scale_dtype="bfloat16")  # fp pools
-    eng = ServingEngine(params, cfg, ENGINE, slots=1, max_len=16,
-                        paged=True, kv_cache_dtype="int8",
-                        kv_scale_dtype="bfloat16")
+        ServingEngine(params, cfg, ENGINE, EngineConfig(
+            slots=1, max_len=16, kv_scale_dtype="bfloat16"))  # fp pools
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=1, max_len=16, kv_cache_dtype="int8", kv_scale_dtype="bfloat16"))
     assert eng.cache.k_scale.dtype == jnp.bfloat16
     # Byte-budget sizing sees the smaller pages: more of them fit the
     # same fp budget than with f32 scale rows.
-    engf = ServingEngine(params, cfg, ENGINE, slots=1, max_len=16,
-                         paged=True, kv_cache_dtype="int8")
+    engf = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=1, max_len=16, kv_cache_dtype="int8"))
     assert eng.allocator.num_pages >= engf.allocator.num_pages
 
 
@@ -568,9 +562,8 @@ def test_bf16_scale_serving_greedy_exact_match():
     cfg = get_config("gpt2_medium", smoke=True)
     params = api.init_params(KEY, cfg)
     prompts, new = _workload(cfg)
-    ref, _ = _drain_outputs(params, cfg, prompts, new, paged=True,
-                            page_size=4)
-    out, eng = _drain_outputs(params, cfg, prompts, new, paged=True,
+    ref, _ = _drain_outputs(params, cfg, prompts, new, page_size=4)
+    out, eng = _drain_outputs(params, cfg, prompts, new,
                               page_size=4, kv_cache_dtype="int8",
                               kv_scale_dtype="bfloat16")
     assert eng.cache.k_scale.dtype == jnp.bfloat16

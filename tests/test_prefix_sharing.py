@@ -13,7 +13,7 @@ from repro.configs import get_config
 from repro.core.salpim import SalPimConfig, SalPimEngine
 from repro.models import api
 from repro.serving import kvcache as kv
-from repro.serving.engine import GenConfig, ServingEngine
+from repro.serving.engine import EngineConfig, GenConfig, ServingEngine
 
 ENGINE = SalPimEngine.create(SalPimConfig())
 KEY = jax.random.PRNGKey(0)
@@ -142,9 +142,9 @@ def test_copy_page_duplicates_all_layers():
 def _drain_outputs(params, cfg, prompts, new_tokens, *, sharing, slots=2,
                    page_size=4, max_len=32):
     gen = GenConfig(temperature=0.0, stop_on_eos=False)
-    eng = ServingEngine(params, cfg, ENGINE, slots=slots, max_len=max_len,
-                        gen=gen, paged=True, page_size=page_size,
-                        prefix_sharing=sharing)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=slots, max_len=max_len, gen=gen, page_size=page_size,
+        prefix_sharing=sharing))
     uids = [eng.submit(p.copy(), max_new_tokens=n)
             for p, n in zip(prompts, new_tokens)]
     done = eng.run(max_steps=400)
@@ -201,8 +201,8 @@ def test_decode_boundary_cow_fork():
     block table pointing at a refcount-2 page mid-fill."""
     cfg, params = _setup()
     gen = GenConfig(temperature=0.0, stop_on_eos=False)
-    eng = ServingEngine(params, cfg, ENGINE, slots=1, max_len=32, gen=gen,
-                        paged=True, page_size=4, prefix_sharing=True)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=1, max_len=32, gen=gen, page_size=4, prefix_sharing=True))
     prompt = np.asarray(jax.random.randint(KEY, (6,), 2, cfg.vocab))
     eng.submit(prompt, max_new_tokens=4)
     eng.step()                              # admit + first decode

@@ -13,10 +13,9 @@ from repro.configs import get_config
 from repro.core.salpim import SalPimConfig, SalPimEngine
 from repro.models import api
 from repro.serving import kvcache as kv
-from repro.serving.engine import GenConfig, ServingEngine
+from repro.serving.engine import EngineConfig, GenConfig, ServingEngine
 from repro.serving.kvcache import TRASH_PAGE, BlockAllocator
-from repro.serving.scheduler import (FifoScheduler, Scheduler, SloScheduler,
-                                     SwappedRequest)
+from repro.serving.scheduler import FifoScheduler, Scheduler, SloScheduler
 from repro.serving.speculative import SpecConfig
 from repro.serving.telemetry import Telemetry
 
@@ -40,8 +39,8 @@ def _workload(cfg, seed=0, n=4):
 
 def _drain(params, cfg, prompts, new, priorities=None, **kw):
     gen = kw.pop("gen", GenConfig(temperature=0.0, stop_on_eos=False))
-    eng = ServingEngine(params, cfg, ENGINE, max_len=32, gen=gen,
-                        paged=True, page_size=4, **kw)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        max_len=32, gen=gen, page_size=4, **kw))
     prios = priorities or [0] * len(prompts)
     uids = [eng.submit(p.copy(), max_new_tokens=n, priority=pr)
             for p, n, pr in zip(prompts, new, prios)]
@@ -63,13 +62,6 @@ def test_schedulers_satisfy_protocol():
     assert isinstance(SloScheduler(), Scheduler)
     assert FifoScheduler().reserve and not FifoScheduler().preemptive
     assert SloScheduler().preemptive and not SloScheduler().reserve
-
-
-def test_preemptive_requires_paged():
-    cfg, params = _setup()
-    with pytest.raises(ValueError, match="paged"):
-        ServingEngine(params, cfg, ENGINE, slots=1, max_len=16,
-                      scheduler=SloScheduler())
 
 
 # ---------------------------------------------------------------------------
@@ -397,9 +389,9 @@ def test_slo_priority_admission_preempts_lower_class():
     still completes (restored from the swap tier) with correct output."""
     cfg, params = _setup()
     gen = GenConfig(temperature=0.0, stop_on_eos=False)
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen,
-                        paged=True, page_size=4, num_pages=64,
-                        scheduler=SloScheduler())
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen, page_size=4, num_pages=64,
+        scheduler=SloScheduler()))
     rng = np.random.RandomState(23)
     bg_prompts = [rng.randint(2, cfg.vocab, size=6) for _ in range(2)]
     bg = [eng.submit(p.copy(), max_new_tokens=16, priority=2)
@@ -454,9 +446,9 @@ def test_slo_infeasible_candidate_never_evicts():
     cannot profit from — it waits; nobody thrashes."""
     cfg, params = _setup()
     gen = GenConfig(temperature=0.0, stop_on_eos=False)
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen,
-                        paged=True, page_size=4, num_pages=7,
-                        scheduler=SloScheduler())
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen, page_size=4, num_pages=7,
+        scheduler=SloScheduler()))
     rng = np.random.RandomState(31)
     # Urgent long-runner (2 prompt pages, grows to 5) + tiny background
     # tenant (1 page): both slots busy, 3 pages free.
@@ -487,10 +479,9 @@ def test_pinning_keeps_hot_prefix_across_requests():
     cfg, params = _setup()
     tel = Telemetry(enabled=True)
     gen = GenConfig(temperature=0.0, stop_on_eos=False)
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen,
-                        paged=True, page_size=4, num_pages=64,
-                        scheduler=SloScheduler(pin_budget_pages=4),
-                        telemetry=tel)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen, page_size=4, num_pages=64,
+        scheduler=SloScheduler(pin_budget_pages=4), telemetry=tel))
     rng = np.random.RandomState(29)
     system = rng.randint(2, cfg.vocab, size=8)        # 2 full pages
     p1 = np.concatenate([system, rng.randint(2, cfg.vocab, size=2)])
@@ -516,9 +507,9 @@ def test_swapped_requests_counted_in_stats_and_step_return():
     stats() honest: it is outstanding work, not a finished drain."""
     cfg, params = _setup()
     gen = GenConfig(temperature=0.0, stop_on_eos=False)
-    eng = ServingEngine(params, cfg, ENGINE, slots=1, max_len=32, gen=gen,
-                        paged=True, page_size=4, num_pages=64,
-                        scheduler=SloScheduler())
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=1, max_len=32, gen=gen, page_size=4, num_pages=64,
+        scheduler=SloScheduler()))
     rng = np.random.RandomState(41)
     eng.submit(rng.randint(2, cfg.vocab, size=6), max_new_tokens=16,
                priority=2)

@@ -5,12 +5,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.configs import get_config
 from repro.core.salpim import SalPimConfig, SalPimEngine
 from repro.models import api
-from repro.serving.engine import GenConfig, ServingEngine, generate
+from repro.serving.engine import (EngineConfig, GenConfig, ServingEngine,
+                                  generate)
 from repro.serving.sampling import sample
 
 ENGINE = SalPimEngine.create(SalPimConfig())
@@ -69,7 +69,8 @@ def test_continuous_batching_matches_batch_generate():
     # reference via batch generate
     ref, _ = generate(params, jnp.asarray(prompts), cfg, ENGINE, gen)
 
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen))
     uids = [eng.submit(prompts[i], max_new_tokens=5) for i in range(3)]
     done = eng.run(max_steps=200)
     assert len(done) == 3
@@ -103,18 +104,16 @@ def test_generate_stats_count_only_pre_eos_tokens():
     assert stats2["tokens"] == 16
 
 
-@pytest.mark.parametrize("paged", [False, True])
-def test_released_slot_lengths_stay_frozen(paged):
+def test_released_slot_lengths_stay_frozen():
     """Regression: decode_step advanced every slot's length uncondition-
-    ally, so released/empty slots crept without bound (and paged idle
-    slots scattered garbage K/V over trash pages each step). After a
-    release the slot must park at length 0 — while the survivor's
-    output is unchanged."""
+    ally, so released/empty slots crept without bound (and idle slots
+    scattered garbage K/V over trash pages each step). After a release
+    the slot must park at length 0 — while the survivor's output is
+    unchanged."""
     cfg, params = _setup()
     gen = GenConfig(temperature=0.0, stop_on_eos=False)
-    kwargs = {"paged": True, "page_size": 4} if paged else {}
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen,
-                        **kwargs)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen, page_size=4))
     prompts = np.asarray(jax.random.randint(KEY, (2, 6), 2, cfg.vocab))
     u_short = eng.submit(prompts[0], max_new_tokens=2)
     u_long = eng.submit(prompts[1], max_new_tokens=10)
@@ -127,30 +126,12 @@ def test_released_slot_lengths_stay_frozen(paged):
     assert int(eng.cache.lengths[s]) == 0
     assert int(eng._host_len[s]) == 0
     # The survivor matches a solo run (idle slots did not perturb it).
-    eng2 = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen,
-                         **kwargs)
+    eng2 = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen, page_size=4))
     eng2.submit(prompts[1], max_new_tokens=10)
     (ref,) = eng2.run(max_steps=100)
     long_req = next(r for r in done if r.uid == u_long)
     assert long_req.generated == ref.generated
-
-
-def test_dense_release_resets_slot_length_for_reuse():
-    """A slot that finishes and is re-filled must behave exactly like a
-    fresh admission (stale lengths would offset the new request)."""
-    cfg, params = _setup()
-    gen = GenConfig(temperature=0.0, stop_on_eos=False)
-    eng = ServingEngine(params, cfg, ENGINE, slots=1, max_len=32, gen=gen)
-    prompts = np.asarray(jax.random.randint(KEY, (2, 6), 2, cfg.vocab))
-    eng.submit(prompts[0], max_new_tokens=3)
-    u2 = eng.submit(prompts[1], max_new_tokens=5)   # reuses the one slot
-    done = eng.run(max_steps=100)
-    ref, _ = generate(params, jnp.asarray(prompts[1][None]), cfg, ENGINE,
-                      GenConfig(max_new_tokens=5, temperature=0.0,
-                                stop_on_eos=False))
-    second = next(r for r in done if r.uid == u2)
-    np.testing.assert_array_equal(np.asarray(second.generated),
-                                  np.asarray(ref[0]))
 
 
 def test_serving_with_lut_engine():
@@ -163,29 +144,6 @@ def test_serving_with_lut_engine():
     assert stats["sec_per_token"] > 0
 
 
-def test_dense_admit_donation_outputs_unchanged():
-    """Regression for the donated dense admission program: admitting
-    requests of different prompt lengths into reused slots (multiple
-    compiles of the donated jit, cache rebound each time) must leave
-    outputs exactly equal to solo whole-batch generation."""
-    cfg, params = _setup()
-    gen = GenConfig(temperature=0.0, stop_on_eos=False)
-    rng = np.random.RandomState(4)
-    prompts = [rng.randint(2, cfg.vocab, size=n) for n in (5, 9, 5, 7)]
-    new = [4, 6, 5, 3]
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen)
-    uids = [eng.submit(p.copy(), max_new_tokens=n)
-            for p, n in zip(prompts, new)]
-    done = eng.run(max_steps=200)
-    by = {r.uid: r.generated for r in done}
-    for p, n, u in zip(prompts, new, uids):
-        ref, _ = generate(params, jnp.asarray(p[None]), cfg, ENGINE,
-                          GenConfig(max_new_tokens=n, temperature=0.0,
-                                    stop_on_eos=False))
-        np.testing.assert_array_equal(np.asarray(by[u]),
-                                      np.asarray(ref[0]))
-
-
 def test_engine_stats_fields():
     """ServingEngine.stats(): token accounting mirrors generate()'s
     fields (tokens, tokens_budget, sec_per_token) and the speculative
@@ -193,8 +151,8 @@ def test_engine_stats_fields():
     cfg, params = _setup()
     gen = GenConfig(temperature=0.0, stop_on_eos=False)
     prompts = np.asarray(jax.random.randint(KEY, (3, 6), 2, cfg.vocab))
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen,
-                        paged=True, page_size=4)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen, page_size=4))
     for i in range(3):
         eng.submit(prompts[i], max_new_tokens=5)
     eng.run(max_steps=200)
@@ -213,7 +171,8 @@ def test_engine_stats_counts_unfinished_budget():
     cfg, params = _setup()
     gen = GenConfig(temperature=0.0, stop_on_eos=False)
     prompts = np.asarray(jax.random.randint(KEY, (2, 5), 2, cfg.vocab))
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen))
     eng.submit(prompts[0], max_new_tokens=8)
     eng.submit(prompts[1], max_new_tokens=8)
     for _ in range(3):
@@ -231,11 +190,10 @@ def test_engine_stats_under_speculative_run():
     cfg, params = _setup()
     gen = GenConfig(temperature=0.0, stop_on_eos=False)
     prompts = np.asarray(jax.random.randint(KEY, (3, 6), 2, cfg.vocab))
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen,
-                        paged=True, page_size=4,
-                        speculative=SpecConfig(mode="draft-model", k=3,
-                                               draft_cfg=cfg,
-                                               draft_params=params))
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen, page_size=4,
+        speculative=SpecConfig(mode="draft-model", k=3, draft_cfg=cfg,
+                               draft_params=params)))
     for i in range(3):
         eng.submit(prompts[i], max_new_tokens=8)
     eng.run(max_steps=200)
@@ -258,10 +216,10 @@ def test_engine_stats_zero_token_drain_returns_zero_ratios():
     from repro.serving.speculative import SpecConfig
     cfg, params = _setup()
     for spec in (None, SpecConfig(mode="ngram", k=4)):
-        eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32,
-                            gen=GenConfig(temperature=0.0,
-                                          stop_on_eos=False),
-                            paged=True, page_size=4, speculative=spec)
+        eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+            slots=2, max_len=32,
+            gen=GenConfig(temperature=0.0, stop_on_eos=False),
+            page_size=4, speculative=spec))
         st = eng.stats()
         for field in ("sec_per_token", "model_sec_per_token",
                       "acceptance_rate", "verify_per_token",

@@ -11,7 +11,7 @@ from repro.configs import get_config
 from repro.core.salpim import SalPimConfig, SalPimEngine
 from repro.kernels import ref as ref_k
 from repro.models import api
-from repro.serving.engine import GenConfig, ServingEngine
+from repro.serving.engine import EngineConfig, GenConfig, ServingEngine
 from repro.serving.kvcache import TRASH_PAGE, BlockAllocator
 from repro.serving.speculative import (DraftModelDrafter, NgramDrafter,
                                        SpecConfig, greedy_accept)
@@ -52,17 +52,16 @@ class WrongDrafter:
 
 def _drain(params, cfg, prompts, new, **kw):
     gen = kw.pop("gen", GenConfig(temperature=0.0, stop_on_eos=False))
-    eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32, gen=gen,
-                        **kw)
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=gen, **kw))
     uids = [eng.submit(p.copy(), max_new_tokens=n)
             for p, n in zip(prompts, new)]
     done = eng.run(max_steps=600)
     assert sorted(r.uid for r in done) == sorted(uids)
     by = {r.uid: r.generated for r in done}
-    if eng.paged:
-        assert eng.allocator.used_pages == 0, "leaked pages after drain"
-        assert eng.allocator._reserved == 0, "leaked reservations"
-        assert eng.allocator.free_pages == eng.allocator.num_pages - 1
+    assert eng.allocator.used_pages == 0, "leaked pages after drain"
+    assert eng.allocator._reserved == 0, "leaked reservations"
+    assert eng.allocator.free_pages == eng.allocator.num_pages - 1
     return [by[u] for u in uids], eng
 
 
@@ -186,7 +185,7 @@ def test_engine_acceptance_matches_ref_oracle():
 def spec_env():
     cfg, params = _setup()
     prompts, new = _workload(cfg)
-    ref, _ = _drain(params, cfg, prompts, new, paged=True, page_size=4)
+    ref, _ = _drain(params, cfg, prompts, new, page_size=4)
     return cfg, params, prompts, new, ref
 
 
@@ -196,7 +195,7 @@ def test_spec_outputs_bit_identical(spec_env, sharing, kv_dtype):
     """Acceptance: greedy outputs bit-identical with speculation on/off,
     across {fp, int8} pools x {sharing on, off}."""
     cfg, params, prompts, new, ref = spec_env
-    base_kw = dict(paged=True, page_size=4, prefix_sharing=sharing,
+    base_kw = dict(page_size=4, prefix_sharing=sharing,
                    kv_cache_dtype=kv_dtype)
     off, _ = _drain(params, cfg, prompts, new, **base_kw)
     on, eng = _drain(params, cfg, prompts, new,
@@ -212,7 +211,7 @@ def test_spec_self_draft_accepts_everything(spec_env):
     rounds commit k+1 tokens whenever budget allows — while outputs
     stay bit-identical."""
     cfg, params, prompts, new, ref = spec_env
-    out, eng = _drain(params, cfg, prompts, new, paged=True, page_size=4,
+    out, eng = _drain(params, cfg, prompts, new, page_size=4,
                       speculative=_self_draft(cfg, params, k=3))
     assert out == ref
     st = eng.stats()
@@ -228,13 +227,12 @@ def test_spec_all_rejected_still_bit_identical(spec_env):
     token per verify pass — rollback every round — without changing a
     single output token or leaking a page."""
     cfg, params, prompts, new, ref = spec_env
-    out, eng = _drain(params, cfg, prompts, new, paged=True, page_size=4,
+    out, eng = _drain(params, cfg, prompts, new, page_size=4,
                       speculative=SpecConfig(mode="ngram", k=4))
     del out
-    eng2 = ServingEngine(params, cfg, ENGINE, slots=2, max_len=32,
-                         gen=GenConfig(temperature=0.0, stop_on_eos=False),
-                         paged=True, page_size=4,
-                         speculative=SpecConfig(mode="ngram", k=4))
+    eng2 = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=2, max_len=32, gen=GenConfig(temperature=0.0, stop_on_eos=False),
+        page_size=4, speculative=SpecConfig(mode="ngram", k=4)))
     eng2.drafter = WrongDrafter(cfg.vocab)
     uids = [eng2.submit(p.copy(), max_new_tokens=n)
             for p, n in zip(prompts, new)]
@@ -253,9 +251,9 @@ def test_spec_with_chunked_prefill_and_sharing(spec_env):
     speculate — they are outside the decode batch) and prefix sharing."""
     cfg, params, prompts, new, ref = spec_env
     shared = [np.concatenate([prompts[0][:8], p]) for p in prompts]
-    off, _ = _drain(params, cfg, shared, new, paged=True, page_size=4,
+    off, _ = _drain(params, cfg, shared, new, page_size=4,
                     prefill_chunk_tokens=4, prefix_sharing=True)
-    on, eng = _drain(params, cfg, shared, new, paged=True, page_size=4,
+    on, eng = _drain(params, cfg, shared, new, page_size=4,
                      prefill_chunk_tokens=4, prefix_sharing=True,
                      speculative=SpecConfig(mode="ngram", k=4))
     assert on == off
@@ -270,9 +268,8 @@ def test_spec_stops_on_eos_inside_accepted_drafts():
     rng = np.random.RandomState(11)
     prompts = [rng.randint(2, cfg.vocab, size=6) for _ in range(3)]
     new = [12, 12, 12]
-    off, _ = _drain(params, cfg, prompts, new, gen=gen, paged=True,
-                    page_size=4)
-    on, _ = _drain(params, cfg, prompts, new, gen=gen, paged=True,
+    off, _ = _drain(params, cfg, prompts, new, gen=gen, page_size=4)
+    on, _ = _drain(params, cfg, prompts, new, gen=gen,
                    page_size=4, speculative=_self_draft(cfg, params, k=4))
     assert on == off
 
@@ -342,9 +339,9 @@ def test_engine_rewind_unmaps_device_tail_pages():
     accepted frontier."""
     cfg, params = _setup()
     gen = GenConfig(temperature=0.0, stop_on_eos=False)
-    eng = ServingEngine(params, cfg, ENGINE, slots=1, max_len=32, gen=gen,
-                        paged=True, page_size=2,
-                        speculative=SpecConfig(mode="ngram", k=4))
+    eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+        slots=1, max_len=32, gen=gen, page_size=2,
+        speculative=SpecConfig(mode="ngram", k=4)))
     eng.drafter = WrongDrafter(cfg.vocab)
     rng = np.random.RandomState(13)
     eng.submit(rng.randint(2, cfg.vocab, size=5), max_new_tokens=10)
@@ -371,12 +368,12 @@ def test_spec_watermark_admission_unchanged():
     rng = np.random.RandomState(17)
     prompts = [rng.randint(2, cfg.vocab, size=8) for _ in range(4)]
     # A pool just big enough for ~2 concurrent requests.
-    kw = dict(paged=True, page_size=4, num_pages=13)
+    kw = dict(page_size=4, num_pages=13)
     outs = {}
     for label, spec in [("off", None),
                         ("on", _self_draft(cfg, params, k=3))]:
-        eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=24,
-                            gen=gen, speculative=spec, **kw)
+        eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+            slots=2, max_len=24, gen=gen, speculative=spec, **kw))
         uids = [eng.submit(p.copy(), max_new_tokens=8) for p in prompts]
         done = eng.run(max_steps=400)
         assert sorted(r.uid for r in done) == sorted(uids)
@@ -391,15 +388,12 @@ def test_spec_watermark_admission_unchanged():
 # Config validation
 # ---------------------------------------------------------------------------
 
-def test_speculative_requires_paged_and_greedy():
+def test_speculative_requires_greedy():
     cfg, params = _setup()
-    with pytest.raises(ValueError, match="paged"):
-        ServingEngine(params, cfg, ENGINE, slots=1, max_len=16,
-                      speculative=SpecConfig())
     with pytest.raises(ValueError, match="greedy"):
-        ServingEngine(params, cfg, ENGINE, slots=1, max_len=16, paged=True,
-                      gen=GenConfig(temperature=1.0),
-                      speculative=SpecConfig())
+        ServingEngine(params, cfg, ENGINE, EngineConfig(
+            slots=1, max_len=16, gen=GenConfig(temperature=1.0),
+            speculative=SpecConfig()))
 
 
 def test_spec_config_validation():
@@ -479,7 +473,7 @@ def test_spec_per_request_counters(spec_env):
     """Request.proposed/accepted sum to the engine aggregates and the
     acceptance report is consistent."""
     cfg, params, prompts, new, ref = spec_env
-    out, eng = _drain(params, cfg, prompts, new, paged=True, page_size=4,
+    out, eng = _drain(params, cfg, prompts, new, page_size=4,
                       speculative=SpecConfig(mode="ngram", k=4))
     del out
     reqs = eng.finished

@@ -231,9 +231,9 @@ def test_engine_telemetry_zero_cost_and_exact(tmp_path):
     tel = Telemetry(enabled=True)
     outs = {}
     for label, t in [("off", None), ("on", tel)]:
-        eng = ServingEngine(params, cfg, ENGINE, slots=2, max_len=24,
-                            gen=gen, paged=True, page_size=8,
-                            prefill_chunk_tokens=4, telemetry=t)
+        eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
+            slots=2, max_len=24, gen=gen, page_size=8, prefill_chunk_tokens=4,
+            telemetry=t))
         outs[label] = _drain(eng, reqs)
         if label == "off":
             # Zero-cost contract: the disabled default never touches the
@@ -289,7 +289,7 @@ def test_decode_walk_page_counters():
     cfg, params = _setup()
     tel = Telemetry(enabled=True)
     eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
-        slots=3, max_len=32, gen=GenConfig(stop_on_eos=False), paged=True,
+        slots=3, max_len=32, gen=GenConfig(stop_on_eos=False),
         page_size=4, prefill_chunk_tokens=16, telemetry=tel))
     rng = np.random.RandomState(0)
     for n in (5, 9):
@@ -322,7 +322,7 @@ def test_prefill_walk_page_counters(arch):
     cfg, params = _setup(arch)
     tel = Telemetry(enabled=True)
     eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
-        slots=2, max_len=48, gen=GenConfig(stop_on_eos=False), paged=True,
+        slots=2, max_len=48, gen=GenConfig(stop_on_eos=False),
         page_size=4, prefill_chunk_tokens=8, telemetry=tel))
     eng.submit(np.random.RandomState(0).randint(2, cfg.vocab, size=40),
                max_new_tokens=2)
@@ -358,7 +358,7 @@ def test_snapshot_golden_keys():
     tel = Telemetry(enabled=True, clock=_fake_clock(0.01))
     eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
         slots=2, max_len=32, gen=GenConfig(stop_on_eos=False),
-        paged=True, page_size=8, prefill_chunk_tokens=8, telemetry=tel))
+        page_size=8, prefill_chunk_tokens=8, telemetry=tel))
     rng = np.random.RandomState(0)
     for _ in range(2):
         eng.submit(rng.randint(2, cfg.vocab, size=6), max_new_tokens=4)
@@ -405,7 +405,7 @@ def _phase_engine(telemetry):
     eng = ServingEngine(params, cfg, ENGINE, EngineConfig(
         slots=3, max_len=32, gen=GenConfig(temperature=0.0,
                                            stop_on_eos=False),
-        paged=True, page_size=8, prefill_chunk_tokens=8,
+        page_size=8, prefill_chunk_tokens=8,
         telemetry=telemetry))
     return cfg, eng
 
